@@ -167,7 +167,6 @@ def balanced_kmeans_sharded(x, n_clusters: int, n_iters: int = 20,
     import jax.sharding
     from jax.sharding import NamedSharding, PartitionSpec as P
     from raft_tpu.comms.comms import build_comms
-    from raft_tpu.parallel.mesh import shard_map_compat
 
     if mesh is None:
         mesh = (res.mesh if res is not None and hasattr(res, "mesh")
@@ -229,8 +228,8 @@ def balanced_kmeans_sharded(x, n_clusters: int, n_iters: int = 20,
 
             return lax.fori_loop(0, n_iters, one_iter, c_init)
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P()),
             out_specs=P()))
 

@@ -2,7 +2,8 @@
 
 The reference's host-side runtime (logger core, dendrogram union-find,
 …) is C++; this module loads our C++ equivalent via ctypes. If the
-shared library is missing it is built on first use with g++ (sub-second,
+shared library is missing, or its stamp does not match the hash of the
+sources in ``_cpp/``, it is built on first use with g++ (sub-second,
 no deps); if that fails (no compiler at deploy time) every caller falls
 back to its pure-Python formulation — the C++ path is a performance/
 parity tier, not a hard dependency.
@@ -11,6 +12,7 @@ parity tier, not a hard dependency.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -37,13 +39,44 @@ def _cpp_dir() -> str:
     return os.path.join(os.path.dirname(os.path.dirname(__file__)), "_cpp")
 
 
+def _stamp_path() -> str:
+    return _lib_path() + ".stamp"
+
+
+def _source_hash() -> str:
+    """sha256 over ``_cpp/*.cpp`` and ``build.sh`` — what the built
+    library must have been compiled from."""
+    d = _cpp_dir()
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(d)):
+        if name.endswith(".cpp") or name == "build.sh":
+            h.update(name.encode())
+            with open(os.path.join(d, name), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def _is_current() -> bool:
+    """The library exists and its stamp matches the committed sources
+    (the .so is untracked, so it may come from another revision)."""
+    try:
+        with open(_stamp_path()) as f:
+            stamp = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(_lib_path()) and stamp == _source_hash()
+
+
 def _try_build() -> bool:
     script = os.path.join(_cpp_dir(), "build.sh")
     if not os.path.exists(script):
         return False
     try:
+        digest = _source_hash()
         subprocess.run(["bash", script], check=True, capture_output=True,
                        timeout=120)
+        with open(_stamp_path(), "w") as f:
+            f.write(digest + "\n")
         return True
     except (subprocess.SubprocessError, OSError):
         return False
@@ -105,7 +138,7 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_failed:
             return _lib
         path = _lib_path()
-        if not os.path.exists(path) and not _try_build():
+        if not _is_current() and not _try_build():
             _load_failed = True
             return None
 

@@ -73,8 +73,10 @@ def _fused_l2_nn(x, y, sqrt: bool):
         best_d = jnp.where(take, tile_min, best_d)
         return (best_d, best_i), None
 
-    init = (jnp.full((m,), jnp.inf, dtype=jnp.float32),
-            jnp.zeros((m,), dtype=jnp.int32))
+    # *_like(xx) carries x's varying mesh axes, so the carry types match
+    # when this runs inside shard_map (the sharded balanced k-means)
+    init = (jnp.full_like(xx, jnp.inf),
+            jnp.zeros_like(xx, dtype=jnp.int32))
     (best_d, best_i), _ = lax.scan(step, init, (y_tiles, yy_tiles, base))
     if sqrt:
         best_d = jnp.sqrt(best_d)

@@ -76,6 +76,45 @@ def device_env(index: int, platform: str = "cpu",
     return env
 
 
+def host_tpu_chips() -> int:
+    """TPU chips on this host, counted from their device files without
+    touching JAX (which would claim them for this process)."""
+    import glob
+    n = len(glob.glob("/dev/accel[0-9]*"))
+    if n == 0:
+        n = len([e for e in glob.glob("/dev/vfio/*")
+                 if os.path.basename(e).isdigit()])
+    return n
+
+
+def parent_holds_tpu() -> bool:
+    """Has this process already initialised a TPU backend? Then it holds
+    the host's chips and no child can open them."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return "tpu" in getattr(xla_bridge, "_backends", {})
+
+
+def check_one_process_per_chip(n_procs: int, devices_per_proc: int,
+                               platform: str) -> None:
+    """A chip belongs to one process at a time: refuse a TPU fleet the
+    host cannot give each daemon its own chips, or whose parent already
+    holds them."""
+    if platform != "tpu":
+        return
+    expects(not parent_holds_tpu(),
+            "ProcessFleet: this process has already initialised the TPU "
+            "backend and holds the host's chips; fleet daemons could not "
+            "open them. Start the fleet from a process that has not "
+            "touched the TPU.")
+    chips = host_tpu_chips()
+    expects(n_procs * devices_per_proc <= chips,
+            "ProcessFleet: %d daemons x %d chips each need %d TPU chips, "
+            "the host has %d (one process per chip)",
+            n_procs, devices_per_proc, n_procs * devices_per_proc, chips)
+
+
 class FleetProcess:
     """One spawned daemon: the Popen handle + its addresses + role."""
 
@@ -124,6 +163,8 @@ class ProcessFleet:
                  spawn: bool = True):
         expects(n_procs >= 1,
                 "ProcessFleet: n_procs must be >= 1, got %d", n_procs)
+        check_one_process_per_chip(int(n_procs), int(devices_per_proc),
+                                   str(platform))
         self.workdir = os.path.abspath(workdir)
         self.n_procs = int(n_procs)
         self._dataset = dict(n=int(n), dim=int(dim), seed=int(seed),

@@ -4,7 +4,7 @@ The serving stack observes everything about *speed* and *availability*
 (``raft.serve.*`` histograms, spans, ``/healthz``) but, before this
 module, nothing about *result quality*: recall was measured offline in
 ``bench_suite`` and the cheap unrescored estimator there drifts 0.13+
-from truth (BENCH_r05: 0.7159 estimated vs 0.8612 true for ivf_pq).
+from truth (0.7159 estimated vs 0.8612 true for ivf_pq in a CPU run).
 This is the always-on quality signal — the "measured signal" half of
 the self-driving loop (ROADMAP item 5), the bench yardstick
 productionized:

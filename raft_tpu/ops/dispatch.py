@@ -48,8 +48,7 @@ def pallas_enabled(backend: Optional[str] = None) -> bool:
     """Should a primitive route to its Pallas kernel? Every call counts
     the decision into ``raft.dispatch.route{path=pallas|xla}`` — the
     telemetry that says which kernel tier actually served traffic
-    (bench records embed the diff, so BENCH_r*.json rows are
-    self-describing about their code path)."""
+    (bench rows embed the diff, so they name their code path)."""
     mode = _mode()
     if mode in ("0", "never", "off"):
         use = False

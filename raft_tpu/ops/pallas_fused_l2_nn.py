@@ -75,6 +75,13 @@ def _fused_l2_nn_call(x, y, sqrt: bool, tm: int, tn: int, interpret: bool,
     kern = functools.partial(_nn_kernel, n=n, tn=tn, gn=gn, sqrt=sqrt,
                              precision=resolve_kernel_mode(
                                  kernel_precision, interpret))
+    # inside shard_map (the sharded balanced k-means: rows sharded,
+    # centers replicated) both operands and the outputs carry the union
+    # of the inputs' varying mesh axes
+    vma = jax.typeof(xp).vma | jax.typeof(yp).vma
+    xp, yp = (jax.lax.pcast(a, tuple(vma - jax.typeof(a).vma),
+                            to="varying")
+              if vma - jax.typeof(a).vma else a for a in (xp, yp))
     od, oi = pl.pallas_call(
         kern,
         grid=(gm, gn),
@@ -82,8 +89,8 @@ def _fused_l2_nn_call(x, y, sqrt: bool, tm: int, tn: int, interpret: bool,
                   pl.BlockSpec((tn, k), lambda i, j: (j, 0))],
         out_specs=[pl.BlockSpec((1, 1, tm), lambda i, j: (i, 0, 0)),
                    pl.BlockSpec((1, 1, tm), lambda i, j: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((gm, 1, tm), jnp.float32),
-                   jax.ShapeDtypeStruct((gm, 1, tm), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((gm, 1, tm), jnp.float32, vma=vma),
+                   jax.ShapeDtypeStruct((gm, 1, tm), jnp.int32, vma=vma)],
         compiler_params=None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(

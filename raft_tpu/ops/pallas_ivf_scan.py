@@ -116,10 +116,9 @@ def _list_scan_kernel(scale_ref, qsub_ref, data_ref, norms_ref, ids_ref,
 
     # lc > 1 iterates via fori_loop so the Mosaic program stays ONE
     # list-body regardless of lc — a Python loop here unrolls lc
-    # matmul+epilogue copies into the kernel, and that unbounded
-    # program growth is the prime suspect in the 2026-08-01 75-minute
-    # remote-compile hang (VERDICT r3). lc == 1 stays loop-free (the
-    # structurally simplest fallback tier).
+    # matmul+epilogue copies into the kernel, an unbounded program
+    # growth that makes compiles slow. lc == 1 stays loop-free (the
+    # structurally simplest tier).
     if lc == 1:
         one_list(0)
     else:
@@ -595,14 +594,17 @@ class _Layout:
     def __init__(self, probes, n_lists: int, max_list: int, cap: int,
                  bins: int, k: int):
         from raft_tpu.neighbors._ivf_scan import _invert_probes
-        if bins == 0:
-            bins = min(max(4 * k, 64), max_list)
+        bins = _Layout.resolve_bins(bins, k, max_list)
         self.qmap, self.inv_pos = _invert_probes(probes, n_lists, cap)
         # pad the list axis so bins divides it (pad rows: id -1 → +inf)
         self.mlp = _round_up(max_list, bins if bins > 0 else 1)
         self.bins = self.mlp if bins < 0 else bins
         self.cap = cap
         self.capp = _round_up(max(cap, 8), 8)  # lane-aligned table width
+
+    @staticmethod
+    def resolve_bins(bins: int, k: int, max_list: int) -> int:
+        return min(max(4 * k, 64), max_list) if bins == 0 else bins
 
     def pad_lists(self, arr, max_list: int, fill=0):
         if self.mlp == max_list:
@@ -1056,6 +1058,11 @@ def ivf_pq_code_scan_pallas(q_rot, centers_rot, pq_centers, codes,
     nq = q_rot.shape[0]
     n_lists, max_list, pq_dim = codes.shape
     _, n_codes, pq_len = pq_centers.shape
+    # the code scan bins along the lane axis ((cap, ML) -> (cap, w,
+    # bins)), and Mosaic refuses a reshape that splits a 128-lane tile:
+    # bins are whole lane tiles (more bins keep a superset of candidates)
+    bins = _Layout.resolve_bins(bins, k, max_list)
+    bins = _round_up(max_list if bins < 0 else bins, 128)
     lay = _Layout(probes, n_lists, max_list, cap, bins, k)
     codes = lay.pad_lists(codes, max_list)
     code_norms = lay.pad_lists(code_norms, max_list)

@@ -4,8 +4,8 @@ Reference: ``spatial/knn/detail/topk.cuh:65-83`` dispatches k≤256 to
 warp-sort (``topk/warpsort_topk.cuh:99-366``: per-warp sorted queues
 merged through registers) and larger k to multi-pass radix
 (``topk/radix_topk.cuh``). Neither maps to TPU (no warp shuffles); XLA's
-``lax.top_k`` is a full variadic sort (28 ms for 1000×4096 on v5e —
-BASELINE.md), orders of magnitude off a merge-pass budget.
+``lax.top_k`` is a full variadic sort (28 ms for 1000×4096 on v5e in
+a pre-PR-21 measurement), orders of magnitude off a merge-pass budget.
 
 TPU design — same transposed geometry as the fused kNN kernel
 (``pallas_fused_knn.py``): candidates live on sublanes, rows (queries)

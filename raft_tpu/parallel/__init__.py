@@ -6,8 +6,7 @@ brute-force k-NN (sharded DB + ring top-k merge), MNMG k-means (sharded
 data + psum'd centroid statistics), and sharded IVF search.
 """
 
-from raft_tpu.parallel.mesh import (make_mesh, shard_rows, replicate,
-                                    shard_map_compat)
+from raft_tpu.parallel.mesh import make_mesh, shard_rows, replicate
 from raft_tpu.parallel.knn import distributed_knn
 from raft_tpu.parallel.kmeans import distributed_kmeans_fit, distributed_kmeans_step
 from raft_tpu.parallel.ivf import (
@@ -30,7 +29,7 @@ from raft_tpu.parallel.ivf import (
 )
 
 __all__ = [
-    "make_mesh", "shard_rows", "replicate", "shard_map_compat",
+    "make_mesh", "shard_rows", "replicate",
     "get_comms",
     "distributed_knn",
     "distributed_kmeans_fit", "distributed_kmeans_step",

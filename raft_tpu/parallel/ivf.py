@@ -34,7 +34,6 @@ from raft_tpu.core.mdarray import as_array
 from raft_tpu.core.precision import matmul_precision
 from raft_tpu.comms.comms import build_comms
 from raft_tpu.distance.distance_types import DistanceType
-from raft_tpu.parallel.mesh import pcast_varying_compat, shard_map_compat
 from raft_tpu.util.host_sample import sample_rows
 
 
@@ -59,8 +58,8 @@ COMPILE_SURFACE_RUNGS = {
     "scale": ("scale", None, "quantization scale — fixed per index"),
     "size": ("size", None, "corpus row count — fixed per epoch"),
     "ml": ("ml", None, "max list length — fixed per index layout"),
-    "ml_shard": ("ml_shard", None,
-                 "per-shard max list length — fixed per build"),
+    "width": ("width", None,
+              "rows one shard sends one shard — fixed per build"),
     "max_iter": ("max_iter", None, "trainer bound — config"),
     "tol": ("tol", None, "trainer tolerance — config"),
 }
@@ -194,10 +193,10 @@ def _fine_scan(queries, get_probe, k: int, n_probes: int, axis: str):
         nd, sel = lax.top_k(-cat_d, k)
         return (-nd, jnp.take_along_axis(cat_i, sel, axis=1)), None
 
-    init = (pcast_varying_compat(jnp.full((nq, k), jnp.inf, jnp.float32),
-                                 (axis,)),
-            pcast_varying_compat(jnp.full((nq, k), -1, jnp.int32),
-                                 (axis,)))
+    init = (lax.pcast(jnp.full((nq, k), jnp.inf, jnp.float32), (axis,),
+                      to="varying"),
+            lax.pcast(jnp.full((nq, k), -1, jnp.int32), (axis,),
+                      to="varying"))
     (d, i), _ = lax.scan(probe_step, init, jnp.arange(n_probes))
     return d, i
 
@@ -263,8 +262,8 @@ def _flat_list_plan(mesh, axis: str, k: int, n_probes: int, kind: str,
                 d = jnp.sqrt(jnp.maximum(d, 0.0))
             return _merge_topk(comms, axis, d, i, k, merge, size)
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None, None), P(axis, None),
                       P(axis, None), P()),
             out_specs=(P(), P())))
@@ -344,8 +343,8 @@ def _pq_list_plan(mesh, axis: str, k: int, n_probes: int, kind: str,
                 d = jnp.sqrt(jnp.maximum(d, 0.0))
             return _merge_topk(comms, axis, d, i, k, merge, size)
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(),
                       P(axis, None, None), P(axis, None), P(axis, None),
                       P()),
@@ -468,8 +467,8 @@ def _label_and_agree_width(xs, ids_s, centers, mesh, axis, n_lists: int,
                                       num_segments=n_lists + 1)[:n_lists]
             return lbl.astype(jnp.int32), cnt
 
-        return jax.jit(shard_map_compat(
-            count_local, mesh, in_specs=(P(axis, None), P(axis), P()),
+        return jax.jit(jax.shard_map(
+            count_local, mesh=mesh, in_specs=(P(axis, None), P(axis), P()),
             out_specs=(P(axis), P(axis))))
 
     # keyed on everything the closure bakes in (GL002: a fresh callable
@@ -540,8 +539,8 @@ def distributed_ivf_flat_build(
                 x_loc, lbl, safe_ids, n_lists, ml)
             return data[None], idx[None], norms[None]
 
-        return jax.jit(shard_map_compat(
-            bucket_local, mesh,
+        return jax.jit(jax.shard_map(
+            bucket_local, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P(axis)),
             out_specs=(P(axis, None, None, None), P(axis, None, None),
                        P(axis, None, None))))
@@ -596,8 +595,8 @@ def distributed_ivf_flat_search_parts(
                 d = jnp.sqrt(jnp.maximum(d, 0.0))
             return _global_merge(comms, axis, d, i, k)
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(), P(axis, None, None, None),
                       P(axis, None, None), P(axis, None, None), P()),
             out_specs=(P(), P())))
@@ -740,8 +739,8 @@ def distributed_ivf_pq_build(
             norms = _code_norms(codes_b, books, idx)
             return codes_b[None], idx[None], norms[None]
 
-        return jax.jit(shard_map_compat(
-            encode_local, mesh,
+        return jax.jit(jax.shard_map(
+            encode_local, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P(axis), P(), P(), P()),
             out_specs=(P(axis, None, None, None), P(axis, None, None),
                        P(axis, None, None))))
@@ -843,8 +842,8 @@ def distributed_ivf_pq_search_parts(
 
     def build():
         local = functools.partial(_local, comms=comms)
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(axis, None, None, None),
                       P(axis, None, None), P(axis, None, None), P()),
             out_specs=(P(), P())))
@@ -956,8 +955,8 @@ def distributed_ivf_bq_build(
                                                 compute_norms=False)
             return data[None], idx[None]
 
-        return jax.jit(shard_map_compat(
-            encode_local, mesh,
+        return jax.jit(jax.shard_map(
+            encode_local, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P(axis), P(), P()),
             out_specs=(P(axis, None, None, None), P(axis, None, None))))
 
@@ -1021,8 +1020,8 @@ def distributed_ivf_bq_search_parts(
             d, i = _fine_scan(q_rep, get_probe, kk, n_probes, axis)
             return _global_merge(comms, axis, d, i, kk)
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(), P(), P(), P(axis, None, None, None),
                       P(axis, None, None), P(axis, None, None),
                       P(axis, None, None), P()),
@@ -1061,9 +1060,9 @@ def distributed_ivf_bq_search_parts(
 # centers train data-parallel (`balanced_kmeans_sharded`: per-shard
 # sufficient statistics + psum each EM sweep — the raft::comms MNMG
 # pattern); every shard labels and encodes its OWN rows; then ONE
-# all_to_all moves each list's encoded payload to the shard that serves
-# it, where peers' partial buckets are compacted into the final padded
-# list. No O(n) array ever materializes on a single device, and the build
+# all_to_all moves each row's encoded payload to the shard that serves
+# its list (`_exchange_rows`), where it lands in the final padded list.
+# No O(n) array ever materializes on a single device, and the build
 # output needs no reshard step before serving.
 # ---------------------------------------------------------------------------
 
@@ -1101,8 +1100,8 @@ def _train_coarse_sharded(x, params, mesh, axis: str, seed: int):
 def _label_and_widths(xs, ids_s, centers, mesh, axis, n_lists: int,
                       kind: str):
     """`_label_and_agree_width` extended for list-layout builds: ONE
-    host sync agrees both bucket widths — ``ml_shard`` bounds any single
-    shard's per-list count (the pre-exchange bucket), ``ml_global`` any
+    host sync agrees both widths — ``width`` bounds the rows any shard
+    sends any one shard (the exchange's send slot), ``ml_global`` any
     list's TOTAL count (the serving bucket) — and returns the global
     per-list totals (the index's ``list_sizes``)."""
     from raft_tpu.neighbors.ivf_flat import _coarse_scores
@@ -1115,48 +1114,70 @@ def _label_and_widths(xs, ids_s, centers, mesh, axis, n_lists: int,
                                       num_segments=n_lists + 1)[:n_lists]
             return lbl.astype(jnp.int32), cnt
 
-        return jax.jit(shard_map_compat(
-            count_local, mesh, in_specs=(P(axis, None), P(axis), P()),
+        return jax.jit(jax.shard_map(
+            count_local, mesh=mesh, in_specs=(P(axis, None), P(axis), P()),
             out_specs=(P(axis), P(axis))))
 
     counted = _shmap_plan(("count_widths", mesh, axis, n_lists, kind),
                           build)
     c_rep = jax.device_put(centers, NamedSharding(mesh, P()))
     labels_s, counts = counted(xs, ids_s, c_rep)
-    c = np.asarray(jax.device_get(counts)).reshape(mesh.shape[axis],
-                                                   n_lists)
-    ml_shard = max(8, -(-int(c.max()) // 8) * 8)
+    n_shards = mesh.shape[axis]
+    c = np.asarray(jax.device_get(counts)).reshape(n_shards, n_lists)
+    # lists are dealt to shards in contiguous blocks: (source, dest) runs
+    runs = c.reshape(n_shards, n_shards, n_lists // n_shards).sum(axis=2)
+    width = max(8, -(-int(runs.max()) // 8) * 8)
     totals = c.sum(axis=0)
     ml_global = max(8, -(-int(totals.max()) // 8) * 8)
-    return labels_s, ml_shard, ml_global, totals.astype(np.int32), c_rep
+    return labels_s, width, ml_global, totals.astype(np.int32), c_rep
 
 
-def _exchange_lists(data, idx, n_shards: int, axis: str, ml_global: int):
-    """Inside shard_map: exchange per-shard partial buckets
-    ((n_lists, ml_shard, D) + ids) into the list-sharded serving layout.
-    Each shard receives every peer's buckets for ITS OWN lists (one
-    all_to_all of exactly the encoded payload — the only O(n/shards)
-    wire move of the build), concatenates them along the slot axis and
-    compacts valid slots to the front, yielding
-    (nl_local, ml_global, D). ``ml_global`` ≥ every list's true total,
-    so compaction never drops a real row."""
-    n_lists, ml_shard = idx.shape
+def _exchange_rows(payload, lbl, ids, n_lists: int, n_shards: int,
+                   axis: str, width: int, ml_global: int):
+    """Inside shard_map: move each of this shard's rows to the shard
+    that serves its list and land it in the list-sharded serving layout
+    (nl_local, ml_global, ...) with its ids (-1 in empty slots).
+
+    Lists are dealt to shards in contiguous blocks, so a stable sort of
+    the rows by list makes each destination's rows one run. The send
+    buffer pads each (source, destination) run to ``width`` — about
+    rows/shards, whatever the list-size skew — and ONE all_to_all each
+    of payload, list and id moves them; only the serving layout pads to
+    the largest list. ``lbl`` is n_lists for padding rows (never sent).
+    Within a list, rows keep source-shard-major, row order."""
     nl_local = n_lists // n_shards
-    D = data.shape[-1]
-    d2 = lax.all_to_all(data.reshape(n_shards, nl_local, ml_shard, D),
-                        axis, 0, 0, tiled=False)
-    i2 = lax.all_to_all(idx.reshape(n_shards, nl_local, ml_shard),
-                        axis, 0, 0, tiled=False)
-    # (src_shard, nl_local, ml_shard, ...) → (nl_local, src·ml_shard, ...)
-    d2 = d2.transpose(1, 0, 2, 3).reshape(nl_local, n_shards * ml_shard,
-                                          D)
-    i2 = i2.transpose(1, 0, 2).reshape(nl_local, n_shards * ml_shard)
-    # compact: valid slots (id ≥ 0) first — jnp.argsort is stable, so
-    # within a list rows keep source-shard-major order
-    order = jnp.argsort((i2 < 0).astype(jnp.int32), axis=1)[:, :ml_global]
-    i2 = jnp.take_along_axis(i2, order, axis=1)
-    d2 = jnp.take_along_axis(d2, order[:, :, None], axis=1)
-    return d2, i2
+    order = jnp.argsort(lbl, stable=True)
+    s_lbl = lbl[order]
+    dest = s_lbl // nl_local                     # n_shards: padding rows
+    cnt = jax.ops.segment_sum(jnp.ones_like(dest), dest,
+                              num_segments=n_shards + 1)[:n_shards]
+    j = jnp.arange(width, dtype=jnp.int32)
+    ok = j[None, :] < cnt[:, None]
+    at = jnp.where(ok, (jnp.cumsum(cnt) - cnt)[:, None] + j[None, :], 0)
+    rows = order[at]                                         # (S, W)
+    base = jnp.arange(n_shards, dtype=jnp.int32)[:, None] * nl_local
+    send = (payload[rows],
+            jnp.where(ok, s_lbl[at] - base, nl_local),
+            jnp.where(ok, ids[rows], -1))
+    recv, r_lbl, r_ids = (
+        lax.all_to_all(a, axis, 0, 0, tiled=False).reshape(
+            (n_shards * width,) + a.shape[2:]) for a in send)
+    # slot of each received row within its list, in received
+    # (source-major) order; the serving layout gathers from it
+    order = jnp.argsort(r_lbl, stable=True)
+    s_lbl = r_lbl[order]
+    cnt = jax.ops.segment_sum(jnp.ones_like(r_lbl), r_lbl,
+                              num_segments=nl_local + 1)
+    pos = (jnp.arange(n_shards * width, dtype=jnp.int32)
+           - (jnp.cumsum(cnt) - cnt)[s_lbl])
+    slot = jnp.where((s_lbl < nl_local) & (pos < ml_global),
+                     s_lbl * ml_global + pos, nl_local * ml_global)
+    src = jnp.full((nl_local * ml_global,), n_shards * width,
+                   jnp.int32).at[slot].set(order, mode="drop")
+    data = jnp.take(recv, src, axis=0, mode="fill", fill_value=0)
+    idx = jnp.take(r_ids, src, axis=0, mode="fill", fill_value=-1)
+    return (data.reshape((nl_local, ml_global) + payload.shape[1:]),
+            idx.reshape(nl_local, ml_global))
 
 
 def sharded_ivf_flat_build(
@@ -1171,9 +1192,7 @@ def sharded_ivf_flat_build(
     standard ``ivf_flat.Index`` whose arrays are sharded over
     ``mesh[axis]``, served as-is by :func:`distributed_ivf_flat_search`
     (or gathered for single-chip serving)."""
-    from raft_tpu.neighbors.ivf_flat import (Index, IndexParams,
-                                             _bucketize_static,
-                                             _metric_kind)
+    from raft_tpu.neighbors.ivf_flat import Index, IndexParams, _metric_kind
     params = params or IndexParams()
     expects(mesh is not None, "sharded build: mesh is required")
     n_shards = mesh.shape[axis]
@@ -1207,30 +1226,25 @@ def sharded_ivf_flat_build(
         obs.counter("raft.build.sharded.rows", family="ivf_flat").inc(n)
         centers = _train_coarse_sharded(x, params, mesh, axis, seed)
         xs, ids_s = _shard_rows(x, mesh, axis)
-        labels_s, ml_shard, ml_global, totals, _ = _label_and_widths(
+        labels_s, width, ml_global, totals, _ = _label_and_widths(
             xs, ids_s, centers, mesh, axis, n_lists, kind)
 
         def build():
             def local(x_loc, lbl_loc, ids_loc):
-                lbl = jnp.where(lbl_loc < n_lists, lbl_loc, 0)
-                safe_ids = jnp.where(lbl_loc < n_lists, ids_loc, -1)
-                data, idx, _, _ = _bucketize_static(
-                    x_loc, lbl, safe_ids, n_lists, ml_shard,
-                    compute_norms=False)
-                d2, i2 = _exchange_lists(data, idx, n_shards, axis,
-                                         ml_global)
+                d2, i2 = _exchange_rows(x_loc, lbl_loc, ids_loc, n_lists,
+                                        n_shards, axis, width, ml_global)
                 norms = jnp.sum(d2 * d2, axis=2)
                 return d2, i2, jnp.where(i2 >= 0, norms, 0.0)
 
-            return jax.jit(shard_map_compat(
-                local, mesh,
+            return jax.jit(jax.shard_map(
+                local, mesh=mesh,
                 in_specs=(P(axis, None), P(axis), P(axis)),
                 out_specs=(P(axis, None, None), P(axis, None),
                            P(axis, None))))
 
         with obs.timed("raft.build.sharded.encode", family="ivf_flat"):
             fn = _shmap_plan(("flat_lbuild", mesh, axis, n_lists,
-                              ml_shard, ml_global, dim), build)
+                              width, ml_global, dim), build)
             data, idx, norms = fn(xs, labels_s, ids_s)
     return Index(centers=_shard0(centers, mesh, axis), lists_data=data,
                  lists_indices=idx, lists_norms=norms,
@@ -1250,9 +1264,7 @@ def sharded_ivf_pq_build(
     compressed payload is the only per-row wire traffic), shard-local
     decode of the reconstruction cache. Served as-is by
     :func:`distributed_ivf_pq_search`."""
-    from raft_tpu.neighbors.ivf_flat import (_bucketize_static,
-                                             _coarse_scores,
-                                             _metric_kind)
+    from raft_tpu.neighbors.ivf_flat import _coarse_scores, _metric_kind
     from raft_tpu.neighbors.ivf_pq import (
         CodebookGen, Index, IndexParams, _code_norms, _decode_lists,
         _encode, _train_codebooks_per_subspace, make_rotation_matrix)
@@ -1314,34 +1326,30 @@ def sharded_ivf_pq_build(
                 reseed_threshold=params.reseed_threshold)
 
         xs, ids_s = _shard_rows(x, mesh, axis)
-        labels_s, ml_shard, ml_global, totals, c_rep = _label_and_widths(
+        labels_s, width, ml_global, totals, c_rep = _label_and_widths(
             xs, ids_s, centers, mesh, axis, n_lists, kind)
 
         def build():
             def local(x_loc, lbl_loc, ids_loc, c, r, books):
                 lbl = jnp.where(lbl_loc < n_lists, lbl_loc, 0)
-                safe_ids = jnp.where(lbl_loc < n_lists, ids_loc, -1)
                 resid_rot = jnp.matmul(x_loc - c[lbl], r.T,
                                        precision=matmul_precision())
                 codes = _encode(resid_rot, books)        # (rows, s) u8
-                data, idx, _, _ = _bucketize_static(
-                    codes, lbl, safe_ids, n_lists, ml_shard,
-                    compute_norms=False)
-                d2, i2 = _exchange_lists(data, idx, n_shards, axis,
-                                         ml_global)
+                d2, i2 = _exchange_rows(codes, lbl_loc, ids_loc, n_lists,
+                                        n_shards, axis, width, ml_global)
                 norms = _code_norms(d2, books, i2)
                 dec = _decode_lists(d2, books, i2)
                 return d2, i2, norms, dec
 
-            return jax.jit(shard_map_compat(
-                local, mesh,
+            return jax.jit(jax.shard_map(
+                local, mesh=mesh,
                 in_specs=(P(axis, None), P(axis), P(axis), P(), P(),
                           P()),
                 out_specs=(P(axis, None, None), P(axis, None),
                            P(axis, None), P(axis, None, None))))
 
         with obs.timed("raft.build.sharded.encode", family="ivf_pq"):
-            fn = _shmap_plan(("pq_lbuild", mesh, axis, n_lists, ml_shard,
+            fn = _shmap_plan(("pq_lbuild", mesh, axis, n_lists, width,
                               ml_global, pq_dim, n_codes, kind), build)
             rep = lambda a: jax.device_put(a, NamedSharding(mesh, P()))
             codes_b, idx, norms, decoded = fn(xs, labels_s, ids_s, c_rep,
@@ -1374,7 +1382,6 @@ def sharded_ivf_bq_build(
     sharded build is the BUILD-time scaling, the multi-part search is
     the serving-time one."""
     from raft_tpu.neighbors.ivf_bq import Index, IndexParams, _pack_bits
-    from raft_tpu.neighbors.ivf_flat import _bucketize_static
     from raft_tpu.neighbors.ivf_pq import make_rotation_matrix
     params = params or IndexParams()
     expects(mesh is not None, "sharded build: mesh is required")
@@ -1399,14 +1406,13 @@ def sharded_ivf_bq_build(
         centers = _train_coarse_sharded(x, params, mesh, axis, seed)
         rot = make_rotation_matrix(dim, dim, force_random=True)
         xs, ids_s = _shard_rows(x, mesh, axis)
-        labels_s, ml_shard, ml_global, totals, c_rep = _label_and_widths(
+        labels_s, width, ml_global, totals, c_rep = _label_and_widths(
             xs, ids_s, centers, mesh, axis, n_lists, "l2")
         rot_rep = jax.device_put(rot, NamedSharding(mesh, P()))
 
         def build():
             def local(x_loc, lbl_loc, ids_loc, c, rt):
                 lbl = jnp.where(lbl_loc < n_lists, lbl_loc, 0)
-                safe_ids = jnp.where(lbl_loc < n_lists, ids_loc, -1)
                 # full-precision rotation + int32 bit payload: the
                 # ivf_bq.build contracts (sign stability, no f32
                 # bitcast canonicalization)
@@ -1420,19 +1426,16 @@ def sharded_ivf_bq_build(
                          jnp.mean(jnp.abs(r), axis=1)[:, None],
                          jnp.int32)],
                     axis=1)
-                data, idx, _, _ = _bucketize_static(
-                    payload, lbl, safe_ids, n_lists, ml_shard,
-                    compute_norms=False)
-                return _exchange_lists(data, idx, n_shards, axis,
-                                       ml_global)
+                return _exchange_rows(payload, lbl_loc, ids_loc, n_lists,
+                                      n_shards, axis, width, ml_global)
 
-            return jax.jit(shard_map_compat(
-                local, mesh,
+            return jax.jit(jax.shard_map(
+                local, mesh=mesh,
                 in_specs=(P(axis, None), P(axis), P(axis), P(), P()),
                 out_specs=(P(axis, None, None), P(axis, None))))
 
         with obs.timed("raft.build.sharded.encode", family="ivf_bq"):
-            fn = _shmap_plan(("bq_lbuild", mesh, axis, n_lists, ml_shard,
+            fn = _shmap_plan(("bq_lbuild", mesh, axis, n_lists, width,
                               ml_global, dim), build)
             payload, idx = fn(xs, labels_s, ids_s, c_rep, rot_rep)
         bits = lax.bitcast_convert_type(payload[..., :w], jnp.uint32)

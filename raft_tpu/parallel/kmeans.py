@@ -86,8 +86,6 @@ def distributed_kmeans_fit(
                         jax.random.key(params.seed), k)
 
     def build():
-        from raft_tpu.parallel.mesh import shard_map_compat
-
         def local(x_shard, valid_shard, c_init):
             def body(state):
                 c, _, it, shift = state
@@ -107,8 +105,8 @@ def distributed_kmeans_fit(
             c, inertia, n_iter, _ = lax.while_loop(cond, body, state)
             return c, inertia, n_iter
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P()),
             out_specs=(P(), P(), P())))
 

@@ -59,8 +59,6 @@ def distributed_knn(
     tile = _db_tile(q.shape[0], rows_per)
 
     def build():
-        from raft_tpu.parallel.mesh import (pcast_varying_compat,
-                                            shard_map_compat)
         comms = build_comms(mesh, axis)
 
         def local(db_shard, q_rep):
@@ -88,10 +86,10 @@ def distributed_knn(
                                          tsel, axis=1)
                 return _merge(best_d, best_i, -td, ti, k), None
 
-            init = (pcast_varying_compat(
-                        jnp.full((nq, k), jnp.inf, jnp.float32), (axis,)),
-                    pcast_varying_compat(
-                        jnp.full((nq, k), -1, jnp.int32), (axis,)))
+            init = (lax.pcast(jnp.full((nq, k), jnp.inf, jnp.float32),
+                              (axis,), to="varying"),
+                    lax.pcast(jnp.full((nq, k), -1, jnp.int32),
+                              (axis,), to="varying"))
             (d, i), _ = lax.scan(step, init, (db_tiles, offs))
             # translate to global ids; mask pad rows (global id >= n)
             offset = lax.axis_index(axis) * rows_per
@@ -127,8 +125,8 @@ def distributed_knn(
             # identical on every rank after n-1 hops; pmax proves replication
             return lax.pmax(fd, axis), lax.pmax(fi, axis)
 
-        return jax.jit(shard_map_compat(
-            local, mesh,
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(axis, None), P()),
             out_specs=(P(), P())))
 

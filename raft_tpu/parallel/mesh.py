@@ -38,34 +38,3 @@ def shard_rows(x, mesh: Mesh, axis: str = "data"):
 def replicate(x, mesh: Mesh):
     return jax.device_put(x, NamedSharding(mesh, P()))
 
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the public API when it
-    exists, else the ``jax.experimental`` spelling of older jax with the
-    replication checker relaxed (the old checker cannot prove the
-    psum/all_gather-replicated outputs the new varying-manual-axes
-    system tracks). New code that only needs shard_map + collectives
-    (the build paths) goes through this shim so it runs on BOTH the
-    virtual CPU test mesh of old-jax environments and real multi-chip
-    meshes; serving paths that use newer primitives (``lax.pcast``)
-    call ``jax.shard_map`` directly and require a current jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-def pcast_varying_compat(x, axes):
-    """``lax.pcast(x, axes, to='varying')`` when the primitive exists
-    (current jax: casts a replicated value so the varying-manual-axes
-    checker accepts it in a varying position). On older jax the
-    :func:`shard_map_compat` path already runs with ``check_rep=False``
-    — there is no replication tracking to satisfy — so the cast is the
-    identity. Lets bodies written for the new checker (the distributed
-    knn scan inits) run on old-jax CPU meshes too."""
-    from jax import lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    return x

@@ -144,66 +144,3 @@ class TestUnknownCase:
         import bench_suite
         with pytest.raises(SystemExit, match="unknown case"):
             bench_suite.run_all(["ivf_flatt"])
-
-
-class TestGreenHeadlineLookup:
-    """bench._last_green_tpu: the degraded driver-bench path promotes a
-    banked green TPU headline ONLY when its embedded measurement
-    timestamp proves it same-round (ADVICE r4 #1)."""
-
-    def _write(self, tmp_path, lines):
-        import json
-        p = tmp_path / "headline.log"
-        p.write_text("\n".join(json.dumps(o) for o in lines) + "\n")
-        return str(p)
-
-    def test_fresh_embedded_timestamp_is_same_round(self, tmp_path):
-        import time
-        import bench
-        now = time.strftime("%Y-%m-%dT%H:%M:%S")
-        path = self._write(tmp_path, [
-            {"metric": "m", "value": 1.0, "unit": "qps",
-             "vs_baseline": 2.0, "measured_at": now}])
-        entry, same_round = bench._last_green_tpu(path)
-        assert entry["metric"] == "m" and same_round
-
-    def test_no_embedded_timestamp_is_stale(self, tmp_path):
-        """Entries written before the timestamp-embedding change (or
-        with mtime-only provenance) cannot be proven same-round."""
-        import bench
-        path = self._write(tmp_path, [
-            {"metric": "m", "value": 1.0, "unit": "qps",
-             "vs_baseline": 2.0}])
-        entry, same_round = bench._last_green_tpu(path)
-        assert entry is not None and not same_round
-
-    def test_old_embedded_timestamp_is_stale(self, tmp_path):
-        import time
-        import bench
-        old = time.strftime("%Y-%m-%dT%H:%M:%S",
-                            time.localtime(time.time() - 48 * 3600))
-        path = self._write(tmp_path, [
-            {"metric": "m", "value": 1.0, "unit": "qps",
-             "vs_baseline": 2.0, "measured_at": old}])
-        entry, same_round = bench._last_green_tpu(path)
-        assert entry is not None and not same_round
-
-    def test_degraded_entries_skipped(self, tmp_path):
-        import time
-        import bench
-        now = time.strftime("%Y-%m-%dT%H:%M:%S")
-        path = self._write(tmp_path, [
-            {"metric": "green", "value": 1.0, "unit": "qps",
-             "vs_baseline": 2.0, "measured_at": now},
-            {"metric": "cpu", "value": 0.1, "unit": "qps",
-             "vs_baseline": 0.05, "degraded_platform": "cpu"},
-            {"metric": "deg", "value": 0.1, "unit": "qps",
-             "vs_baseline": 0.05, "degraded": True}])
-        entry, same_round = bench._last_green_tpu(path)
-        assert entry["metric"] == "green" and same_round
-
-    def test_missing_file(self, tmp_path):
-        import bench
-        entry, same_round = bench._last_green_tpu(
-            str(tmp_path / "nope.log"))
-        assert entry is None and not same_round

@@ -1,9 +1,8 @@
 """Compile-budget ladder (ops/compile_budget.py).
 
-The mechanism under test is the round-4 defense against the 2026-08-01
-75-minute remote-compile hang (VERDICT r3): fused searches run as a
-ladder of tiers; a tier that exceeds the compile budget is parked
-(never killed) and the next tier serves. Tier thunks here are plain
+Fused searches run as a ladder of tiers: an error from a tier is
+raised, and only a tier that exceeds the opt-in compile budget is
+parked (never killed) while the next tier serves. Tier thunks here are plain
 Python (sleep/raise) — the ladder is orthogonal to jax — plus an
 end-to-end check that the IVF searches produce identical results
 through every tier of their ladders.
@@ -111,13 +110,22 @@ class TestRunTiers:
         assert out == 8
         assert calls == ["slow"]  # not re-submitted while poisoned
 
-    def test_error_falls_through(self):
+    @pytest.mark.parametrize("budget", [0.0, 5.0])
+    def test_error_raises_without_fallthrough(self, budget):
+        """A failing first tier (e.g. a kernel Mosaic refuses) is
+        raised, inline and on the budgeted thread alike — the next tier
+        never hides it."""
+        served = []
+
         def bad():
             raise RuntimeError("boom")
 
-        out = cb.run_tiers("lad", [("bad", bad), ("ok", lambda: 3)],
-                           budget=5.0)
-        assert out == 3
+        with pytest.raises(RuntimeError, match="boom"):
+            cb.run_tiers("lad", [("bad", bad),
+                                 ("ok", lambda: served.append(1))],
+                         budget=budget)
+        assert served == []
+        assert cb.tier_state("lad", "ok") == "untried"
 
     def test_last_tier_error_raises(self):
         def bad():
@@ -157,10 +165,14 @@ class TestRunTiers:
         assert snap["lad"]["slow"] == "poisoned"
         assert snap["lad"]["fast"] == "ok"
 
-    def test_default_budget_disabled_on_cpu(self):
-        # the test mesh is CPU: budgeting must default OFF so tests
-        # and the virtual-mesh rehearsals stay single-threaded
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_default_budget_disabled_on_every_backend(self, backend,
+                                                      monkeypatch):
+        monkeypatch.delenv("RAFT_TPU_COMPILE_BUDGET_S", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
         assert cb.budget_s() == 0.0
+        monkeypatch.setenv("RAFT_TPU_COMPILE_BUDGET_S", "2.5")
+        assert cb.budget_s() == 2.5
 
 
 class TestLadderEquivalence:

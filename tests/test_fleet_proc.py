@@ -526,3 +526,27 @@ def test_loadgen_fleet_procs_chaos_grammar():
                   "--chaos", "stall_shard:0@t+1s"]):  # kill only
         with pytest.raises(SystemExit):
             loadgen.main(argv)
+
+
+class TestOneProcessPerChip:
+    """A chip belongs to one process: a TPU fleet is refused before any
+    daemon spawns when the parent holds the TPU or the host has fewer
+    chips than daemons."""
+
+    @pytest.mark.parametrize("holds,chips,match", [
+        (True, 4, "already initialised the TPU"),
+        (False, 1, "the host has 1"),
+    ])
+    def test_tpu_fleet_refused(self, tmp_path, monkeypatch, holds, chips,
+                               match):
+        from raft_tpu.core.error import LogicError
+        from raft_tpu.fleet import proc
+        monkeypatch.setattr(proc, "parent_holds_tpu", lambda: holds)
+        monkeypatch.setattr(proc, "host_tpu_chips", lambda: chips)
+        with pytest.raises(LogicError, match=match):
+            ProcessFleet(str(tmp_path), n_procs=2, platform="tpu")
+        assert not os.listdir(tmp_path)  # nothing spawned
+
+    def test_cpu_parent_is_not_a_tpu_holder(self):
+        from raft_tpu.fleet import proc
+        assert not proc.parent_holds_tpu()

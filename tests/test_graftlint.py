@@ -131,11 +131,10 @@ class TestGL002Retrace:
 
     BUG_LOCAL = (
         "import jax\n"
-        "from raft_tpu.parallel.mesh import shard_map_compat\n"
         "def serve(x, mesh):\n"
         "    def local(q):\n"
         "        return q + 1\n"
-        "    f = jax.jit(shard_map_compat(local, mesh))\n"
+        "    f = jax.jit(jax.shard_map(local, mesh=mesh))\n"
         "    return f(x)\n")
 
     BUG_CAPTURE = (

@@ -252,3 +252,41 @@ class TestNativeInterruptible:
         intr.cancel(tid)
         assert intr.yield_no_throw() is True
         assert intr.yield_no_throw() is False  # consumed, not sticky
+
+
+class TestNativeStamp:
+    """The .so is untracked: the loader rebuilds when the stamp written
+    at build time does not match the hash of the committed sources."""
+
+    def test_stale_stamp_rebuilds(self, monkeypatch, tmp_path):
+        stamp = tmp_path / "lib.stamp"
+        stamp.write_text("stale\n")
+        monkeypatch.setattr(native, "_stamp_path", lambda: str(stamp))
+        builds = []
+
+        def fake_build():
+            builds.append(1)
+            stamp.write_text(native._source_hash() + "\n")
+            return True
+
+        monkeypatch.setattr(native, "_try_build", fake_build)
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_load_failed", False)
+        assert not native._is_current()
+        assert native.load() is not None
+        assert builds == [1]
+        # a matching stamp loads without building again
+        monkeypatch.setattr(native, "_lib", None)
+        assert native._is_current()
+        assert native.load() is not None
+        assert builds == [1]
+
+    def test_source_edit_changes_hash(self, monkeypatch, tmp_path):
+        import shutil
+        src = tmp_path / "_cpp"
+        shutil.copytree(native._cpp_dir(), src)
+        monkeypatch.setattr(native, "_cpp_dir", lambda: str(src))
+        before = native._source_hash()
+        with open(src / "raft_tpu_host.cpp", "a") as f:
+            f.write("\n// edited\n")
+        assert native._source_hash() != before

@@ -133,6 +133,50 @@ class TestShardedIvfFlatBuild:
         assert sorted(real.tolist()) == list(range(x.shape[0]))
 
 
+class TestExchangeRows:
+    def test_ragged_exchange_under_list_skew(self, devices):
+        """Most rows in one list: each shard sends each shard one run
+        padded to the largest (source, destination) run, not every
+        list padded to the largest list, and every row lands in its
+        list in source-shard-major, row order."""
+        from jax.sharding import PartitionSpec as P
+        from raft_tpu.parallel.ivf import _exchange_rows
+        mesh = make_mesh(devices=devices)
+        s, rows, n_lists = len(devices), 96, 4 * len(devices)
+        rng = np.random.default_rng(0)
+        lbl = np.where(rng.random(s * rows) < 0.6, 1,
+                       rng.integers(0, n_lists, s * rows)).astype(np.int32)
+        lbl[rng.random(s * rows) < 0.1] = n_lists          # padding rows
+        ids = np.where(lbl < n_lists, np.arange(s * rows), -1)
+        ids = ids.astype(np.int32)
+        payload = np.stack([ids, 2 * ids], 1).astype(np.float32)
+        runs = np.zeros((s, s), int)
+        for src in range(s):
+            part = lbl[src * rows:(src + 1) * rows]
+            part = part[part < n_lists]
+            np.add.at(runs[src], part // (n_lists // s), 1)
+        width = int(runs.max())
+        totals = np.bincount(lbl[lbl < n_lists], minlength=n_lists)
+        ml = int(totals.max())
+        assert width < rows and ml > width  # a skew worth exchanging
+
+        fn = jax.jit(jax.shard_map(
+            lambda p, l, i: _exchange_rows(p, l, i, n_lists, s, "data",
+                                           width, ml),
+            mesh=mesh, in_specs=(P("data", None), P("data"), P("data")),
+            out_specs=(P("data", None, None), P("data", None))))
+        data, idx = map(np.asarray, fn(payload, lbl, ids))
+        assert data.shape == (n_lists, ml, 2) and idx.shape == (n_lists, ml)
+        for li in range(n_lists):
+            want = np.flatnonzero(lbl == li)     # source-major, row order
+            got = idx[li]
+            np.testing.assert_array_equal(got[:len(want)], want)
+            assert (got[len(want):] == -1).all()
+            np.testing.assert_array_equal(data[li, :len(want)],
+                                          payload[want])
+            assert (data[li, len(want):] == 0).all()
+
+
 class TestShardedIvfPqBuild:
     def test_selfhit_and_ids(self, devices):
         from raft_tpu.neighbors import ivf_pq

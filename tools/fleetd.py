@@ -301,6 +301,10 @@ def main(argv=None):
         log.error("--role follower requires --primary-url")
         return 2
 
+    # before anything touches JAX: each daemon reuses the compiles its
+    # peers (and earlier runs) already cached
+    from raft_tpu.core.compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     from raft_tpu import obs
     from raft_tpu.fleet.transport import serve_replica
     from raft_tpu.serve import SearchServer, ServeConfig

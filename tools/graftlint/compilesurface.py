@@ -11,7 +11,7 @@ a first-class object:
 
 * **site discovery** — every ``jax.jit`` call (including the AOT
   ``jit(...).lower(...).compile()`` chain), ``pallas_call``,
-  ``shard_map`` / ``shard_map_compat`` wrapper, ``_shmap_plan(key,
+  ``shard_map`` wrapper, ``_shmap_plan(key,
   builder)`` cache boundary and ``build_plan`` /
   ``compile_mutate_program`` / ``compile_tail_program`` builder call
   in the program;
@@ -126,7 +126,7 @@ STRUCTURAL_NAMES = frozenset({
 })
 
 JIT_NAMES = ("jit", "pmap")
-SHMAP_NAMES = ("shard_map", "shard_map_compat")
+SHMAP_NAMES = ("shard_map",)
 
 _MAX_DEPTH = 16
 

@@ -16,7 +16,7 @@ shapes are outright errors or silent performance cliffs:
 
 Scope: functions that are jit/shard_map targets — decorated
 (``@jax.jit``, ``@functools.partial(jax.jit, ...)``) or passed by name
-to ``jax.jit`` / ``shard_map`` / ``shard_map_compat`` anywhere in the
+to ``jax.jit`` / ``shard_map`` anywhere in the
 module — plus their lexically nested functions.  ``float()``/``int()``
 are only flagged on values the local static-ness propagation cannot
 prove static (constants, ``.shape``/``.ndim``/``len()`` chains and
@@ -34,7 +34,7 @@ from tools.graftlint.core import (FileContext, Finding, Rule,
                                   str_tuple)
 
 # dotted-name suffixes that mean "this call traces its first argument"
-JIT_WRAPPERS = ("jit", "shard_map", "shard_map_compat", "pmap")
+JIT_WRAPPERS = ("jit", "shard_map", "pmap")
 
 NP_MODULES = {"np", "numpy", "onp"}
 NP_SYNC_FUNCS = {"asarray", "array", "ascontiguousarray", "copy"}

@@ -12,7 +12,7 @@ recorder, the shipped default), and — ISSUE 14 — PROFILING ON on top
 one Bernoulli draw on the blocking path, gated < 5% too, measured on
 a BLOCKED plan call since the profiler only arms around a sync the
 caller was paying anyway). Writes the comparison to
-``docs/measurements/trace_overhead_<platform>.json``.
+``chiprun_out/trace_overhead_<platform>.json``.
 
 Method notes:
 
@@ -188,7 +188,7 @@ artifact = {
 }
 here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 out_path = os.environ.get("TRACE_OVERHEAD_OUT") or os.path.join(
-    here, "docs", "measurements",
+    here, "chiprun_out",
     f"trace_overhead_{jax.devices()[0].platform}.json")
 os.makedirs(os.path.dirname(out_path), exist_ok=True)
 with open(out_path, "w") as f:

@@ -1,5 +1,5 @@
 """100M×128 north-star COVERAGE CURVE (the recall-ceiling artifact at
-the true BASELINE.md scale, CPU-feasible): generate the bench mixture
+the true north-star scale, CPU-feasible): generate the bench mixture
 with a NUMPY-resident corpus (51 GB — device work runs on slices),
 compute exact ground truth for a query subset, train coarse centers on
 a subsample, and emit the recall ceiling for every n_probes. The
