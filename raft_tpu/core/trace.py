@@ -42,6 +42,16 @@ def range(fmt: str, *args):
         yield
 
 
+def annotation(name: str, **meta):
+    """An un-entered trace annotation carrying ``meta`` as its event
+    arguments, or None while tracing is disabled. For a range whose
+    start and end arrive in separate callbacks (the GC pause range of
+    ``raft_tpu.obs.runtime``): the caller enters and exits it."""
+    if not _enabled:
+        return None
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
 def push_range(fmt: str, *args) -> None:
     """Toggle-balance contract (pinned by tests/test_core.py
     TestTraceToggleBalance): the enable state at PUSH time decides what

@@ -487,20 +487,22 @@ def fused_list_search(queries, centers, data, norms, ids, scale, *,
     callers likewise): route the fine phase through the single-
     pallas_call scan+select kernel — the top-k state stays resident in
     VMEM and the scan → gather → select_k chain disappears (ISSUE 7)."""
-    probes = coarse_probes(queries, centers, n_probes, kind=kind,
-                           use_pallas=use_pallas)
-    if use_pallas:
-        from raft_tpu.ops.pallas_ivf_scan import ivf_list_scan_pallas
-        return ivf_list_scan_pallas(queries, data, norms, ids, probes, k,
-                                    cap, scale=scale, bins=bins,
-                                    sqrt=sqrt, metric=kind,
-                                    gather=gather,
-                                    internal_dtype=internal_dtype,
-                                    lc=lc, fused=fused)
-    # XLA tier scores the l2 core only; search() gates routing
-    chunk = _chunk_size(ids.shape[0], cap, ids.shape[1])
-    return inverted_scan(queries, data, norms, ids, probes, k, cap,
-                         chunk, scale, bins=bins, sqrt=sqrt)
+    with jax.named_scope("raft.plan.coarse"):
+        probes = coarse_probes(queries, centers, n_probes, kind=kind,
+                               use_pallas=use_pallas)
+    with jax.named_scope("raft.plan.scan"):
+        if use_pallas:
+            from raft_tpu.ops.pallas_ivf_scan import ivf_list_scan_pallas
+            return ivf_list_scan_pallas(queries, data, norms, ids, probes,
+                                        k, cap, scale=scale, bins=bins,
+                                        sqrt=sqrt, metric=kind,
+                                        gather=gather,
+                                        internal_dtype=internal_dtype,
+                                        lc=lc, fused=fused)
+        # XLA tier scores the l2 core only; search() gates routing
+        chunk = _chunk_size(ids.shape[0], cap, ids.shape[1])
+        return inverted_scan(queries, data, norms, ids, probes, k, cap,
+                             chunk, scale, bins=bins, sqrt=sqrt)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_probes", "cap",

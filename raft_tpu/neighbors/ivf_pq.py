@@ -933,14 +933,17 @@ def _fused_code_search(q, centers, centers_rot, rot, pq_centers, codes,
     round-3 QPS lever)."""
     from raft_tpu.neighbors import _ivf_scan
     from raft_tpu.ops.pallas_ivf_scan import ivf_pq_code_scan_pallas
-    probes = _ivf_scan.coarse_probes(q, centers, n_probes, kind=kind,
-                                     use_pallas=True)
-    q_rot = jnp.matmul(q, rot.T, precision=matmul_precision())
-    return ivf_pq_code_scan_pallas(
-        q_rot, centers_rot, pq_centers, codes, code_norms, lists_indices,
-        probes, k, cap, bins=bins, sqrt=sqrt, lut_dtype=lut_dtype,
-        internal_distance_dtype=internal_dtype, metric=kind,
-        per_cluster=per_cluster, gather=gather, fused=fused)
+    with jax.named_scope("raft.plan.coarse"):
+        probes = _ivf_scan.coarse_probes(q, centers, n_probes, kind=kind,
+                                         use_pallas=True)
+        q_rot = jnp.matmul(q, rot.T, precision=matmul_precision())
+    with jax.named_scope("raft.plan.scan"):
+        return ivf_pq_code_scan_pallas(
+            q_rot, centers_rot, pq_centers, codes, code_norms,
+            lists_indices, probes, k, cap, bins=bins, sqrt=sqrt,
+            lut_dtype=lut_dtype, internal_distance_dtype=internal_dtype,
+            metric=kind, per_cluster=per_cluster, gather=gather,
+            fused=fused)
 
 
 # guards the lazy reconstruction-cache materialization: ladder

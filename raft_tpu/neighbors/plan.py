@@ -93,21 +93,6 @@ def _donate_ok() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# the stage structure of a compiled serving program, in program order
-# with static attribution weights (the fused executable cannot be
-# host-timed per stage — spans.add_stage_spans marks these
-# attributed=True; tools/profile_ivf_pieces.py measures the real
-# split, see docs/observability.md "Diagnosing one slow query")
-_PLAN_STAGES = (
-    ("raft.plan.stage.coarse", 0.12),
-    ("raft.plan.stage.inversion", 0.05),
-    ("raft.plan.stage.scan", 0.55),
-    ("raft.plan.stage.merge", 0.18),
-    ("raft.plan.stage.postprocess", 0.10),
-)
-_RESCORE_STAGE = ("raft.plan.stage.rescore", 0.25)
-
-
 @dataclass
 class SearchPlan:
     """One AOT-compiled serving program for a fixed (index, nq, k,
@@ -137,9 +122,13 @@ class SearchPlan:
 
     def _run(self, q: jax.Array) -> Tuple[jax.Array, jax.Array]:
         d, i = self._executable(q, *self._operands)
-        if self._host_epilogue is not None:
-            d, i = self._host_epilogue(d, i, q)
-        return d, i
+        return self._epilogue(d, i, q)
+
+    def _epilogue(self, d, i, q) -> Tuple[jax.Array, jax.Array]:
+        if self._host_epilogue is None:
+            return d, i
+        with spans.span("raft.plan.host_epilogue"):
+            return self._host_epilogue(d, i, q)
 
     def search(self, queries, block: bool = False
                ) -> Tuple[jax.Array, jax.Array]:
@@ -154,26 +143,31 @@ class SearchPlan:
         # device wait — around the sync it was paying anyway
         prof = block and profiler.sampled()
         t_call = time.perf_counter()
-        q = as_array(queries).astype(jnp.float32)
-        expects(q.shape == (self.nq, self.dim),
-                "plan.search: queries %s != plan shape (%d, %d) — build "
-                "a plan per serving batch shape", q.shape, self.nq,
-                self.dim)
-        obs.counter("raft.plan.search.total").inc()
-        obs.counter("raft.plan.search.queries").inc(self.nq)
         with spans.span("raft.plan.search", family=self.family,
                         nq=self.nq, k=self.k, n_probes=self.n_probes,
                         cap=self.cap, sync_free=self.sync_free,
                         blocked=block) as sp:
-            if self._donate and isinstance(queries, jax.Array):
-                q = jnp.array(q, copy=True)  # caller keeps their buffer
-            t0 = time.perf_counter()
-            d, i = self._run(q)
+            # the host→device copy of the queries and the enqueue of
+            # the compiled program (async dispatch: returns before the
+            # device is done)
+            with spans.span("raft.plan.enqueue"):
+                q = as_array(queries).astype(jnp.float32)
+                expects(q.shape == (self.nq, self.dim),
+                        "plan.search: queries %s != plan shape (%d, %d)"
+                        " — build a plan per serving batch shape",
+                        q.shape, self.nq, self.dim)
+                obs.counter("raft.plan.search.total").inc()
+                obs.counter("raft.plan.search.queries").inc(self.nq)
+                if self._donate and isinstance(queries, jax.Array):
+                    q = jnp.array(q, copy=True)  # caller keeps theirs
+                d, i = self._executable(q, *self._operands)
+            d, i = self._epilogue(d, i, q)
             t_enq = t_ready = 0.0
             if block:
                 if prof:
                     t_enq = time.perf_counter()
-                jax.block_until_ready((d, i))
+                with spans.span("raft.plan.device_wait"):
+                    jax.block_until_ready((d, i))
                 if prof:
                     t_ready = time.perf_counter()
                     spans.add_child_span(
@@ -181,12 +175,6 @@ class SearchPlan:
                         program="plan",
                         host_ms=round((t_enq - t_call) * 1e3, 3),
                         device_ms=round((t_ready - t_enq) * 1e3, 3))
-            # per-stage breakdown of the fused program (attributed —
-            # host walls only exist for the whole executable; under
-            # async dispatch this is enqueue time unless `block`)
-            spans.add_stage_spans(
-                self._stages(), time.perf_counter() - t0,
-                family=self.family, compiled=True)
             sp.set_attr("plan_key", repr(self.key))
         if prof and block:
             # the span/trace epilogue above is host work too: charge
@@ -198,10 +186,6 @@ class SearchPlan:
                 + (time.perf_counter() - t_ready),
                 device_s=t_ready - t_enq)
         return d, i
-
-    def _stages(self):
-        return (_PLAN_STAGES + (_RESCORE_STAGE,)
-                if self._host_epilogue is not None else _PLAN_STAGES)
 
     def search_batched(self, queries, block: bool = True
                        ) -> Tuple[jax.Array, jax.Array]:
@@ -285,7 +269,8 @@ def _flat_builder(index, k: int, params):
             else:
                 d, i = _search_impl(q, centers, data, ids, norms, scale,
                                     k, n_probes, sqrt, kind=kind)
-            return _postprocess(d, index.metric), i
+            with jax.named_scope("raft.plan.postprocess"):
+                return _postprocess(d, index.metric), i
 
         operands = (index.centers, index.lists_data, index.lists_norms,
                     index.lists_indices, jnp.float32(index.scale))
@@ -332,15 +317,18 @@ def _pq_builder(index, k: int, params):
         The sqrt applies only when the device phase didn't already
         (``dev_sqrt``: the kk == k no-rescore case sqrt's in-scan)."""
         from raft_tpu.neighbors.ivf_bq import _exact_rescore_device
-        if raw is not None:
-            ex, i_out = _exact_rescore_device(raw, q, i, k=k, kind=kind)
-            i_out = jnp.where(jnp.isfinite(ex), i_out, -1)
-            d = jnp.where(jnp.isfinite(ex), ex, jnp.inf)
-        else:
-            d, i_out = d[:, :k], i[:, :k]
-        if sqrt and not dev_sqrt:
-            d = jnp.sqrt(jnp.maximum(d, 0.0))
-        return _postprocess(d, index.metric), i_out
+        with jax.named_scope("raft.plan.rescore"):
+            if raw is not None:
+                ex, i_out = _exact_rescore_device(raw, q, i, k=k,
+                                                  kind=kind)
+                i_out = jnp.where(jnp.isfinite(ex), i_out, -1)
+                d = jnp.where(jnp.isfinite(ex), ex, jnp.inf)
+            else:
+                d, i_out = d[:, :k], i[:, :k]
+        with jax.named_scope("raft.plan.postprocess"):
+            if sqrt and not dev_sqrt:
+                d = jnp.sqrt(jnp.maximum(d, 0.0))
+            return _postprocess(d, index.metric), i_out
 
     def make(nq: int, cap: int):
         host_epilogue = None

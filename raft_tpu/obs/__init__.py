@@ -61,13 +61,13 @@ from raft_tpu.obs.registry import (
     counter,
     gauge,
     histogram,
-    snapshot,
     snapshot_diff,
     to_prometheus_text,
     reset,
     set_enabled,
     enabled,
 )
+from raft_tpu.obs.runtime import snapshot
 from raft_tpu.obs.timing import timed
 from raft_tpu.obs.spans import (
     Span,
@@ -76,7 +76,6 @@ from raft_tpu.obs.spans import (
     current_trace_id,
     current_traceparent,
     parse_traceparent,
-    add_stage_spans,
     set_trace_enabled,
     trace_enabled,
     set_trace_sample_rate,
@@ -112,7 +111,6 @@ __all__ = [
     "current_trace_id",
     "current_traceparent",
     "parse_traceparent",
-    "add_stage_spans",
     "set_trace_enabled",
     "trace_enabled",
     "set_trace_sample_rate",

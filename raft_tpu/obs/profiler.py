@@ -106,8 +106,8 @@ _ENV_WINDOW = "RAFT_TPU_PROFILE_WINDOW"
 _ENV_HBM_MS = "RAFT_TPU_PROFILE_HBM_MS"
 _ENV_HEADROOM = "RAFT_TPU_PROFILE_HBM_HEADROOM"
 
-# the sampled-sync child span (REQUIRED_SPAN_NAMES): unlike the
-# raft.plan.stage.* children this one is MEASURED, not attributed
+# the sampled-sync child span (REQUIRED_SPAN_NAMES): a MEASURED
+# device/host split of one blocking plan call
 SYNC_SPAN = _SYNC_SPAN = "raft.obs.profile.sync"
 
 

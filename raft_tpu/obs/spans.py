@@ -31,13 +31,16 @@ Semantics and caveats:
   (the same caveat as ``obs.timed``). ``sp.sync(value)`` optionally
   blocks on a device value and records the device-inclusive duration
   in ``attrs["device_ms"]``.
-* **attributed stages** — an AOT plan executes coarse/inversion/scan/
-  merge/postprocess as ONE fused program; per-stage host timing is
-  impossible by design. :func:`add_stage_spans` records the program's
-  stage structure as child spans whose durations split the measured
-  wall by static weights, marked ``attributed=True``. They show the
-  shape of the request; ``tools/profile_ivf_pieces.py`` is the
-  measured ground truth (docs/observability.md walkthrough).
+* **device stages** — an AOT plan executes coarse/scan/merge/rescore/
+  postprocess as ONE fused program, so no host span can time a stage.
+  The program's stages carry ``jax.named_scope("raft.plan.<stage>")``
+  instead: the device ops' metadata in an xprof/Perfetto trace names
+  the stage each op belongs to (docs/observability.md walkthrough).
+* **GC pauses** — while tracing is enabled, :mod:`raft_tpu.obs.runtime`
+  keeps a ``gc.callbacks`` hook that puts each collection on the
+  profiler clock as the range ``raft.runtime.gc`` and counts it under
+  ``raft.runtime.gc.*``; :func:`set_trace_enabled` installs and
+  removes it.
 * **toggle** — ``RAFT_TPU_TRACE=0`` (mirroring ``RAFT_TPU_METRICS``)
   no-ops the whole layer: ``span()`` returns one shared null object
   (nothing is allocated or recorded), runtime toggle via
@@ -75,8 +78,9 @@ import os
 import random
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
+from raft_tpu.obs import runtime as _runtime
 from raft_tpu.obs.registry import NAME_RE
 
 __all__ = [
@@ -87,7 +91,6 @@ __all__ = [
     "current_trace_id",
     "current_traceparent",
     "parse_traceparent",
-    "add_stage_spans",
     "add_child_span",
     "set_trace_enabled",
     "trace_enabled",
@@ -116,12 +119,19 @@ _tls = threading.local()
 # itertools.count is atomic in CPython; ids only need process-local
 # uniqueness (the pid prefixes exported traces where it matters)
 _ids = itertools.count(1)
+if _enabled:
+    _runtime.install()
 
 
 def set_trace_enabled(on: bool = True) -> None:
-    """Runtime toggle (initial state from ``RAFT_TPU_TRACE``)."""
+    """Runtime toggle (initial state from ``RAFT_TPU_TRACE``); also
+    installs or removes the GC pause hook (:mod:`raft_tpu.obs.runtime`)."""
     global _enabled
     _enabled = bool(on)
+    if _enabled:
+        _runtime.install()
+    else:
+        _runtime.uninstall()
 
 
 def trace_enabled() -> bool:
@@ -423,43 +433,6 @@ def parse_traceparent(header: Optional[str]
                                    for c in _flags):
         return None
     return trace_id, span_id
-
-
-def add_stage_spans(stages: Sequence[Tuple[str, float]], total_s: float,
-                    **attrs) -> None:
-    """Record attributed child spans under the current span: ``stages``
-    is a sequence of ``(name, weight)``; each stage's duration splits
-    ``total_s`` proportionally, laid end-to-end over the interval that
-    just elapsed (``[now - total_s, now]``). Used by the AOT plan path,
-    where the stages execute inside ONE fused program and cannot be
-    host-timed individually — spans carry ``attributed=True`` so
-    exporters and readers can tell estimation from measurement."""
-    if not _enabled:
-        return
-    tr = getattr(_tls, "trace", None)
-    if tr is None or not tr.stack:
-        return
-    parent = tr.stack[-1]
-    total_w = sum(w for _, w in stages)
-    if total_w <= 0 or total_s < 0:
-        return
-    tid = threading.get_ident()
-    cursor = time.perf_counter() - total_s
-    for name, w in stages:
-        if not NAME_RE.match(name):
-            raise ValueError(
-                f"stage span name {name!r} violates the taxonomy")
-        dur = total_s * (w / total_w)
-        tr.spans.append({
-            "name": name,
-            "span_id": _new_id(),
-            "parent_id": parent.span_id,
-            "t_start_ms": round((cursor - tr.t0) * 1e3, 3),
-            "duration_ms": round(dur * 1e3, 3),
-            "tid": tid,
-            "attrs": {"attributed": True, **attrs},
-        })
-        cursor += dur
 
 
 def add_child_span(name: str, start_s: float, duration_s: float,

@@ -318,12 +318,13 @@ def _finish_fused(od, oi, nq: int, k: int, sqrt: bool):
     """Tail of the fused scan+select calls: slice the resident state
     back to (nq, k) and apply the ``merge_candidates`` output
     conventions (id −1 ⇒ +inf distance, optional sqrt)."""
-    d = od[:k, :nq].T
-    i = oi[:k, :nq].T
-    d = jnp.where(i >= 0, d, jnp.inf)
-    if sqrt:
-        d = jnp.sqrt(jnp.maximum(d, 0.0))
-    return d, i
+    with jax.named_scope("raft.plan.merge"):
+        d = od[:k, :nq].T
+        i = oi[:k, :nq].T
+        d = jnp.where(i >= 0, d, jnp.inf)
+        if sqrt:
+            d = jnp.sqrt(jnp.maximum(d, 0.0))
+        return d, i
 
 
 def _pick_lc_fused(n_lists: int, max_list: int, cap: int, dim: int,
@@ -626,10 +627,11 @@ class _Layout:
     def merge_cap_major(self, cd, ci, probes, k: int, sqrt: bool):
         """Merge candidate blocks already in (n_lists, cap, B) layout."""
         from raft_tpu.neighbors._ivf_scan import merge_candidates
-        return merge_candidates(
-            cd[:, :self.cap].astype(jnp.float32), ci[:, :self.cap],
-            probes, self.inv_pos, k, sqrt, use_pallas_select=True,
-            cap=self.cap)
+        with jax.named_scope("raft.plan.merge"):
+            return merge_candidates(
+                cd[:, :self.cap].astype(jnp.float32), ci[:, :self.cap],
+                probes, self.inv_pos, k, sqrt, use_pallas_select=True,
+                cap=self.cap)
 
 
 def ivf_list_scan_pallas(queries, lists_data, lists_norms, lists_indices,

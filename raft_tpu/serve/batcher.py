@@ -61,9 +61,11 @@ from typing import Optional, Tuple
 import numpy as np
 
 from raft_tpu import obs
+from raft_tpu.core import trace
 from raft_tpu.core.error import expects
 from raft_tpu.core.logger import get_logger
 from raft_tpu.obs import profiler, spans
+from raft_tpu.obs import runtime as _runtime
 from raft_tpu.serve.controller import LoadController
 from raft_tpu.serve.ladder import PlanLadder
 from raft_tpu.serve.types import (DeadlineExceeded, DispatchError,
@@ -525,33 +527,41 @@ class SearchServer:
         idle_s = max(cfg.degrade_cooldown_ms / 1e3, 0.02)
         wait_s = cfg.max_wait_ms / 1e3
         while True:
-            with self._cond:
-                while not self._q and not self._closed:
-                    if not self._cond.wait(timeout=idle_s):
-                        # idle tick: the ladder steps back toward full
-                        # quality, the overload verdict clears, the
-                        # shed-rate window decays
-                        self._controller.observe(0.0, 0)
-                        self._update_shed_rate_locked()
-                if self._closed:
-                    break
-                # batching window: let the head-of-line request wait up
-                # to max_wait_ms for a fuller batch (or until the
-                # largest shape is already covered)
-                head_t = self._q[0].t_enq
-                while (self._rows_queued < self._ladder.max_shape
-                       and not self._closed and self._q):
-                    remaining = wait_s - (time.perf_counter() - head_t)
-                    if remaining <= 0:
+            # the dispatcher's phases cover its whole loop: collect →
+            # assemble → [batch span: enqueue, host_epilogue,
+            # device_wait, fetch] → scatter (docs/serving.md). collect,
+            # assemble and scatter are profiler ranges, not spans: a
+            # span there would root a trace of its own and enclose the
+            # request roots
+            with trace.range("raft.serve.collect"):
+                with self._cond:
+                    while not self._q and not self._closed:
+                        if not self._cond.wait(timeout=idle_s):
+                            # idle tick: the ladder steps back toward
+                            # full quality, the overload verdict
+                            # clears, the shed-rate window decays
+                            self._controller.observe(0.0, 0)
+                            self._update_shed_rate_locked()
+                    if self._closed:
                         break
-                    self._cond.wait(timeout=remaining)
-                if self._closed:
-                    break
-                batch, rows, expired, depth, now = \
-                    self._take_batch_locked()
-                self._inflight_rows = rows
-            for r in expired:
-                self._fail_deadline(r, now)
+                    # batching window: let the head-of-line request
+                    # wait up to max_wait_ms for a fuller batch (or
+                    # until the largest shape is already covered)
+                    head_t = self._q[0].t_enq
+                    while (self._rows_queued < self._ladder.max_shape
+                           and not self._closed and self._q):
+                        remaining = wait_s - (time.perf_counter()
+                                              - head_t)
+                        if remaining <= 0:
+                            break
+                        self._cond.wait(timeout=remaining)
+                    if self._closed:
+                        break
+                    batch, rows, expired, depth, now = \
+                        self._take_batch_locked()
+                    self._inflight_rows = rows
+                for r in expired:
+                    self._fail_deadline(r, now)
             if batch:
                 # dispatcher crash guard (mirrors the compactor's,
                 # ISSUE 10): one broken batch fails ITS futures with a
@@ -633,45 +643,129 @@ class SearchServer:
         return out
 
     def _execute(self, batch, rows: int, depth: int) -> None:
+        with trace.range("raft.serve.assemble"):
+            # profiler attribution: tag this dispatcher thread so a
+            # sampled dispatch inside plan.search lands in this
+            # server's (replica's) per-tag window — one None read when
+            # profiling is off
+            profiler.tag_dispatch(self._profile_tag)
+            t_start = time.perf_counter()
+            head_wait = t_start - min(r.t_enq for r in batch)
+            level = self._controller.observe(head_wait, depth)
+            shape, plan = self._plan_for_batch(rows, level)
+            qb = (batch[0].queries if len(batch) == 1
+                  else np.concatenate([r.queries for r in batch],
+                                      axis=0))
+            pad = shape - rows
+            if pad:
+                # duplicated-REAL-row padding (the pad_partial rule of
+                # ann_types.batched_search): repeated real rows stay
+                # in-distribution for the measured probe cap; their
+                # result rows are sliced off before scatter
+                obs.counter("raft.serve.batch.padded_rows").inc(pad)
+                reps = -(-pad // rows)
+                qb = np.concatenate([qb, np.tile(qb, (reps, 1))[:pad]],
+                                    axis=0)
+        plan, d, i, err, dead, t_done = self._run_batch(
+            batch, rows, shape, plan, qb, level)
+        # scatter: slice each request's rows out of the batch results
+        # and resolve its future; then record the per-request traces,
+        # so recording never delays an answer
+        with trace.range("raft.serve.scatter"):
+            exec_dur = t_done - t_start
+            _runtime.flush()
+            obs.counter("raft.serve.batch.total", level=level).inc()
+            obs.counter("raft.serve.batch.rows").inc(rows)
+            obs.counter("raft.serve.batch.slots").inc(shape)
+            obs.histogram("raft.serve.batch.size",
+                          buckets=obs.SIZE_BUCKETS).observe(rows)
+            obs.histogram("raft.serve.batch.occupancy",
+                          buckets=OCCUPANCY_BUCKETS).observe(
+                              rows / shape)
+            partial = bool(getattr(plan, "partial", False))
+            coverage = float(getattr(plan, "coverage", 1.0))
+            # quality sampling: ONE flag read per batch — None means
+            # sampling is off and nothing below allocates or runs
+            qm = self._quality
+            if qm is not None and err is None:
+                q_epoch = self._quality_epoch()
+                q_excl = self._quality_detail() if partial else ""
+            off = 0
+            answered = []
+            for r in batch:
+                if id(r) in dead:   # failed with DeadlineExceeded
+                    off += r.nq
+                    continue
+                wait_s = t_start - r.t_enq
+                obs.histogram("raft.serve.queue.delay.seconds",
+                              buckets=SERVE_LATENCY_BUCKETS).observe(
+                                  wait_s)
+                if err is not None:
+                    obs.counter("raft.serve.errors.total").inc()
+                    r.future.set_exception(err)
+                    continue
+                d_r = d[off:off + r.nq, :r.k].copy()
+                i_r = i[off:off + r.nq, :r.k].copy()
+                off += r.nq
+                lat = t_done - r.t_enq
+                obs.histogram("raft.serve.request.seconds",
+                              buckets=SERVE_LATENCY_BUCKETS).observe(lat)
+                obs.counter("raft.serve.completed.total").inc()
+                if partial:
+                    obs.counter(
+                        "raft.serve.failover.partial.total").inc()
+                r.future.set_result(
+                    SearchResult(d_r, i_r, partial=True,
+                                 coverage=coverage)
+                    if partial else (d_r, i_r))
+                answered.append((r, wait_s, lat))
+                if qm is not None:
+                    # shadow-exact sampling: a Bernoulli draw + bounded
+                    # copy on this thread; the exact replay happens on
+                    # the monitor's background thread, never in a batch
+                    # slot
+                    qm.offer(r.queries, i_r, r.k, epoch=q_epoch,
+                             coverage=coverage, excluded=q_excl)
+            # per-request root traces, once every answer is out:
+            # queue-wait + (shared) execution children under one
+            # raft.serve.request root — the flight recorder shows each
+            # caller's story, batch sharing included
+            for r, wait_s, lat in answered:
+                with spans.span("raft.serve.request",
+                                remote_parent=r.trace_ctx,
+                                nq=r.nq, k=r.k,
+                                outcome="partial" if partial else "ok",
+                                level=level, batch_shape=shape,
+                                latency_ms=round(lat * 1e3, 3)):
+                    spans.add_child_span("raft.serve.queue_wait",
+                                         r.t_enq, wait_s)
+                    spans.add_child_span("raft.serve.execute", t_start,
+                                         exec_dur, shape=shape,
+                                         shared=len(batch) > 1)
+
+    def _run_batch(self, batch, rows: int, shape: int, plan, qb,
+                   level: int):
+        """The ``raft.serve.batch`` root: dispatch with retries, then
+        the device→host fetch. Returns ``(plan, d, i, err, dead,
+        t_done)``: the plan that ran last, the host results (None after
+        a failure), the failure, and the ids of requests already failed
+        during a backoff."""
         cfg = self._cfg
-        # profiler attribution: tag this dispatcher thread so a sampled
-        # dispatch inside plan.search lands in this server's (replica's)
-        # per-tag window — one None read when profiling is off
-        profiler.tag_dispatch(self._profile_tag)
-        t_start = time.perf_counter()
-        head_wait = t_start - min(r.t_enq for r in batch)
-        level = self._controller.observe(head_wait, depth)
-        shape, plan = self._plan_for_batch(rows, level)
-        qb = (batch[0].queries if len(batch) == 1
-              else np.concatenate([r.queries for r in batch], axis=0))
-        pad = shape - rows
-        if pad:
-            # duplicated-REAL-row padding (the pad_partial rule of
-            # ann_types.batched_search): repeated real rows stay
-            # in-distribution for the measured probe cap; their result
-            # rows are sliced off before scatter
-            obs.counter("raft.serve.batch.padded_rows").inc(pad)
-            reps = -(-pad // rows)
-            qb = np.concatenate([qb, np.tile(qb, (reps, 1))[:pad]],
-                                axis=0)
-        err = None
+        d = i = err = None
         dead: set = set()       # ids of requests failed during backoff
         attempt = 0
         with spans.span("raft.serve.batch", shape=shape, rows=rows,
                         requests=len(batch),
                         occupancy=round(rows / shape, 4),
                         n_probes=plan.n_probes, level=level) as bsp:
-            for idx, r in enumerate(batch):
-                spans.add_child_span("raft.serve.queue_wait", r.t_enq,
-                                     t_start - r.t_enq, request=idx,
-                                     rows=r.nq)
             while True:
                 with spans.span("raft.serve.execute", shape=shape,
                                 n_probes=plan.n_probes,
                                 attempt=attempt):
                     try:
                         d, i = self._dispatch(plan, qb)
-                        d, i = np.asarray(d), np.asarray(i)
+                        with spans.span("raft.serve.fetch"):
+                            d, i = np.asarray(d), np.asarray(i)
                         err = None
                     except ShardFailedError as e:   # retryable
                         err = e
@@ -716,64 +810,4 @@ class SearchServer:
                         time.sleep(backoff)
             if attempt:
                 bsp.set_attr("retries", attempt)
-        t_done = time.perf_counter()
-        exec_dur = t_done - t_start
-        obs.counter("raft.serve.batch.total", level=level).inc()
-        obs.counter("raft.serve.batch.rows").inc(rows)
-        obs.counter("raft.serve.batch.slots").inc(shape)
-        obs.histogram("raft.serve.batch.size",
-                      buckets=obs.SIZE_BUCKETS).observe(rows)
-        obs.histogram("raft.serve.batch.occupancy",
-                      buckets=OCCUPANCY_BUCKETS).observe(rows / shape)
-        partial = bool(getattr(plan, "partial", False))
-        coverage = float(getattr(plan, "coverage", 1.0))
-        # quality sampling (ISSUE 11): ONE flag read per batch — None
-        # means sampling is off and nothing below allocates or runs
-        qm = self._quality
-        if qm is not None and err is None:
-            q_epoch = self._quality_epoch()
-            q_excl = self._quality_detail() if partial else ""
-        off = 0
-        for r in batch:
-            if id(r) in dead:   # already failed with DeadlineExceeded
-                off += r.nq
-                continue
-            wait_s = t_start - r.t_enq
-            obs.histogram("raft.serve.queue.delay.seconds",
-                          buckets=SERVE_LATENCY_BUCKETS).observe(wait_s)
-            if err is not None:
-                obs.counter("raft.serve.errors.total").inc()
-                r.future.set_exception(err)
-                continue
-            d_r = d[off:off + r.nq, :r.k].copy()
-            i_r = i[off:off + r.nq, :r.k].copy()
-            off += r.nq
-            lat = t_done - r.t_enq
-            obs.histogram("raft.serve.request.seconds",
-                          buckets=SERVE_LATENCY_BUCKETS).observe(lat)
-            obs.counter("raft.serve.completed.total").inc()
-            if partial:
-                obs.counter("raft.serve.failover.partial.total").inc()
-            # per-request root trace: queue-wait + (shared) execution
-            # children under one raft.serve.request root — the flight
-            # recorder shows each caller's story, batch sharing included
-            with spans.span("raft.serve.request",
-                            remote_parent=r.trace_ctx,
-                            nq=r.nq, k=r.k,
-                            outcome="partial" if partial else "ok",
-                            level=level, batch_shape=shape,
-                            latency_ms=round(lat * 1e3, 3)):
-                spans.add_child_span("raft.serve.queue_wait", r.t_enq,
-                                     wait_s)
-                spans.add_child_span("raft.serve.execute", t_start,
-                                     exec_dur, shape=shape,
-                                     shared=len(batch) > 1)
-            r.future.set_result(
-                SearchResult(d_r, i_r, partial=True, coverage=coverage)
-                if partial else (d_r, i_r))
-            if qm is not None:
-                # shadow-exact sampling: a Bernoulli draw + bounded
-                # copy on this thread; the exact replay happens on the
-                # monitor's background thread, never in a batch slot
-                qm.offer(r.queries, i_r, r.k, epoch=q_epoch,
-                         coverage=coverage, excluded=q_excl)
+        return plan, d, i, err, dead, time.perf_counter()

@@ -247,19 +247,20 @@ REQUIRED_NAMES = (
     # tiered placement policy scores from
     "raft.ivf_scan.probes.batches",
     "raft.ivf_scan.probes.mass",
+    # host-runtime pauses: garbage collections per generation, counted
+    # by the gc.callbacks hook while tracing is enabled
+    "raft.runtime.gc.collections",
+    "raft.runtime.gc.seconds",
 )
 
 # serving-path SPANS the tracing layer contracts to emit (ISSUE 3):
-# the request root, the attributed stage breakdown, the sub-batch
-# split, and the rank-tagged shard spans. Checked against every full
-# raft.* string literal in a full-tree scan (stage names live in the
-# _PLAN_STAGES table, not a call site).
+# the request root, the sub-batch split, and the rank-tagged shard
+# spans. Checked against every full raft.* string literal in a
+# full-tree scan (the dispatcher phases that are profiler ranges only
+# live in module constants, not span call sites).
 REQUIRED_SPAN_NAMES = (
     "raft.plan.search",
     "raft.plan.search_batched",
-    "raft.plan.stage.coarse",
-    "raft.plan.stage.scan",
-    "raft.plan.stage.merge",
     "raft.ann.sub_batch",
     "raft.parallel.ivf.shard",
     "raft.ivf_flat.search",
@@ -296,7 +297,6 @@ REQUIRED_SPAN_NAMES = (
     "raft.fleet.route",
     # resource observability (ISSUE 14): the profiler's sampled-sync
     # child span — a MEASURED device/host split under the request
-    # (attributed=False, unlike the raft.plan.stage.* estimates)
     "raft.obs.profile.sync",
     # fleet observability plane (ISSUE 16): each federator sweep and
     # each cross-process trace stitch opens one span — the
@@ -310,6 +310,18 @@ REQUIRED_SPAN_NAMES = (
     # parented by the caller's traceparent header — one routed request
     # stays ONE trace across process boundaries
     "raft.fleet.rpc",
+    # the dispatcher thread's phases: every instant of its loop lies
+    # under exactly one of them (collect, assemble and scatter are
+    # profiler ranges only; the plan's phases and fetch are spans
+    # under the raft.serve.batch root), and the GC pause range
+    "raft.serve.collect",
+    "raft.serve.assemble",
+    "raft.plan.enqueue",
+    "raft.plan.host_epilogue",
+    "raft.plan.device_wait",
+    "raft.serve.fetch",
+    "raft.serve.scatter",
+    "raft.runtime.gc",
 )
 
 

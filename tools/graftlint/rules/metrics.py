@@ -35,9 +35,9 @@ CALL_RE = re.compile(
     r"""|add_child_span)\(\s*(['"])([^'"]+)\2""")
 SPAN_KINDS = ("span", "spanned", "add_child_span")
 
-# any full raft.* string literal — the attributed stage-name tables the
-# plan layer hands to spans.add_stage_spans are plain tuples, not call
-# sites; used only for span-coverage checks, never flagged
+# any full raft.* string literal — names held in module constants (the
+# dispatcher's profiler-range phases) are not call sites; used only for
+# span-coverage checks, never flagged
 LITERAL_RE = re.compile(r"""['"](raft\.[a-z0-9_]+(?:\.[a-z0-9_]+)+)['"]""")
 
 # fixture-heavy / self-referential sources the taxonomy scan skips
