@@ -230,7 +230,7 @@ def _flat_builder(index, k: int, params):
     from raft_tpu.neighbors.ivf_flat import (_metric_kind, _postprocess,
                                              _search_impl)
     from raft_tpu.ops.dispatch import pallas_enabled
-    from raft_tpu.ops.pallas_ivf_scan import fused_mode, lc_mode
+    from raft_tpu.ops.pallas_ivf_scan import fused_mode, lc_mode, ragged_tail
 
     n_probes = min(params.n_probes, index.n_lists)
     kind = _metric_kind(index.metric)
@@ -252,6 +252,12 @@ def _flat_builder(index, k: int, params):
         use_fused = use_pallas and fused_mode() and k <= 256
         if use_list and use_fused:
             obs.counter("raft.ivf_scan.fused.total",
+                        family="ivf_flat").inc()
+        # the Pallas list scan reads the lists unpadded and completes a
+        # partial last bins window in VMEM
+        if use_list and use_pallas and ragged_tail(
+                index.lists_data.shape[1], params.scan_bins, k):
+            obs.counter("raft.ivf_scan.ragged_tail.total",
                         family="ivf_flat").inc()
 
         def fn(q, centers, data, norms, ids, scale):

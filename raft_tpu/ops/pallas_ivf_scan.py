@@ -19,7 +19,11 @@ lists:
     2. epilogue: + list-row norms + query norms − 2·ip, pad rows → +inf.
     3. binned partial top-k along sublanes → (B, cap) candidates with
        global db ids (TPU-KNN partial reduce; B ≥ 2k for the recall
-       gate, B == max_list ⇒ exact).
+       gate, B == max_list ⇒ exact). Row r goes to bin r % B. The
+       lists arrive as the index stores them, max_list rounded only to
+       8 rows: when B does not divide it, the last partial window is
+       completed to B rows in VMEM (+inf, id −1), so no list-axis pad
+       copies the lists in HBM.
 
 Each list's rows are read from HBM exactly once per query batch; the
 (max_list, cap) score block lives and dies in VMEM — the property the
@@ -92,13 +96,30 @@ def _flat_list_candidates(scale, q, y, norms_l, ids, *, bins: int,
     # STRIDED bins (row r → bin r % B): bucketized rows follow
     # dataset order, so a query's true neighbors sit in adjacent
     # rows — contiguous bins would collide them (measured 0.87 vs
-    # 0.99+ recall on clustered data); striding decorrelates free
-    w = ml // bins
-    db_ = d.reshape(w, bins, cap)
-    cd = jnp.min(db_, axis=0)                        # (B, cap)
-    rb = ids_b.reshape(w, bins, cap)
-    ci = jnp.min(jnp.where(db_ == cd[None, :, :], rb, _BIG_I32),
-                 axis=0)
+    # 0.99+ recall on clustered data); striding decorrelates free.
+    # The index rounds max_list to 8 rows while B is a search-time
+    # choice, so the list may end in a partial window of t = ML − w·B
+    # rows: it is completed to B rows HERE, in VMEM (+inf, id −1 — what
+    # a pad row scores), and folded in as one more window. Every row
+    # keeps bin r % B, so the candidates are those of a list padded to
+    # a multiple of B, without that padded copy of the lists in HBM.
+    w, t = divmod(ml, bins)
+    wins_d, wins_i = [], []
+    if w:
+        wins_d.append(d[:w * bins].reshape(w, bins, cap))
+        wins_i.append(ids_b[:w * bins].reshape(w, bins, cap))
+    if t:
+        wins_d.append(jnp.concatenate(
+            [d[w * bins:], jnp.full((bins - t, cap), jnp.inf, d.dtype)],
+            axis=0)[None])
+        wins_i.append(jnp.concatenate(
+            [ids_b[w * bins:], jnp.full((bins - t, cap), -1, jnp.int32)],
+            axis=0)[None])
+    cd = functools.reduce(jnp.minimum,
+                          [jnp.min(db_, axis=0) for db_ in wins_d])
+    ci = functools.reduce(jnp.minimum, [
+        jnp.min(jnp.where(db_ == cd[None, :, :], rb, _BIG_I32), axis=0)
+        for db_, rb in zip(wins_d, wins_i)])            # (B, cap)
     return cd, jnp.where(ci == _BIG_I32, -1, ci)
 
 
@@ -182,10 +203,12 @@ def lc_mode() -> int:
 
 
 def _pick_lc(n_lists: int, max_list: int, cap: int, dim: int,
-             itemsize: int, override: int = 0) -> int:
+             itemsize: int, override: int = 0, tail: int = 0) -> int:
     """Lists per grid cell: enough to amortize per-step overhead while
     the (LC·max_list·dim) data block + score blocks stay well under the
-    VMEM cap (double-buffered).
+    VMEM cap (double-buffered). ``tail``: rows of the completed partial
+    bins window (``_flat_list_candidates``), 0 when bins divide
+    max_list.
 
     ``override`` > 0 pins the value (snapped down to a divisor of
     n_lists) — resolved from ``RAFT_TPU_IVF_LC`` by ``lc_mode()`` at
@@ -200,7 +223,8 @@ def _pick_lc(n_lists: int, max_list: int, cap: int, dim: int,
     per_list = (max_list * dim * itemsize          # data block
                 + cap * dim * 4                    # gathered queries
                 + max_list * cap * 4               # score block
-                + max_list * (4 + 4))              # norms + ids
+                + max_list * (4 + 4)               # norms + ids
+                + tail * cap * (4 + 4))            # tail window d + ids
     budget = _VMEM_LIMIT // 3
     # ≤ 8 bounds the grid-step working set; the kernel body itself is
     # lc-independent now (fori_loop), so this is a VMEM/pipelining
@@ -329,7 +353,7 @@ def _finish_fused(od, oi, nq: int, k: int, sqrt: bool):
 
 def _pick_lc_fused(n_lists: int, max_list: int, cap: int, dim: int,
                    itemsize: int, k: int, nq: int, bins: int,
-                   override: int = 0) -> int:
+                   override: int = 0, tail: int = 0) -> int:
     """``_pick_lc`` with the fused kernel's extra VMEM residents: the
     (kp, nqp) state blocks (revisited outputs — live the whole grid)
     and the per-list scatter/merge temporaries (one-hot, scattered
@@ -349,7 +373,8 @@ def _pick_lc_fused(n_lists: int, max_list: int, cap: int, dim: int,
     per_list = (max_list * dim * itemsize
                 + cap * dim * 4
                 + max_list * cap * 4
-                + max_list * (4 + 4))
+                + max_list * (4 + 4)
+                + tail * cap * (4 + 4))
     budget = max((_VMEM_LIMIT // 3) - fixed, 0)
     lc = max(1, min(8, budget // max(per_list, 1)))
     while n_lists % lc:
@@ -580,9 +605,12 @@ def _fused_pq_scan_call(qsub, codes_t, norms, ids, books, qmap,
 
 
 class _Layout:
-    """Shared prologue of both list-major scans: bins resolution, probe
-    inversion, list-axis padding to a bins multiple, lane-aligned
-    inverted-table width.
+    """Shared prologue of the list-major scans: bins resolution, probe
+    inversion, lane-aligned inverted-table width, and (BQ and PQ only)
+    list-axis padding to a bins multiple. The flat scan pads nothing
+    in HBM: it reads the lists as the index stores them and completes
+    a partial last bins window in VMEM (``_flat_list_candidates``);
+    ``mlp`` is the padded length only the BQ and PQ scans read.
 
     ``bins``: 0 = auto — 4k bins. IVF lists concentrate a query's true
     neighbors far more than brute-force tiles do, so the collision
@@ -597,9 +625,11 @@ class _Layout:
         from raft_tpu.neighbors._ivf_scan import _invert_probes
         bins = _Layout.resolve_bins(bins, k, max_list)
         self.qmap, self.inv_pos = _invert_probes(probes, n_lists, cap)
-        # pad the list axis so bins divides it (pad rows: id -1 → +inf)
+        # the list axis rounded up to a bins multiple (pad rows: id -1
+        # → +inf); the flat scan's partial window is max_list % bins
         self.mlp = _round_up(max_list, bins if bins > 0 else 1)
         self.bins = self.mlp if bins < 0 else bins
+        self.tail = self.bins if ragged_tail(max_list, bins, k) else 0
         self.cap = cap
         self.capp = _round_up(max(cap, 8), 8)  # lane-aligned table width
 
@@ -634,6 +664,14 @@ class _Layout:
                 cap=self.cap)
 
 
+def ragged_tail(max_list: int, bins: int, k: int) -> bool:
+    """Whether the flat list scan completes a partial last bins window
+    in VMEM: the resolved bins (``_Layout``) do not divide
+    ``max_list``. Exact bins (-1) never do."""
+    b = _Layout.resolve_bins(bins, k, max_list)
+    return b > 0 and max_list % b != 0
+
+
 def ivf_list_scan_pallas(queries, lists_data, lists_norms, lists_indices,
                          probes, k: int, cap: int, scale=1.0,
                          bins: int = 0, sqrt: bool = False,
@@ -655,10 +693,9 @@ def ivf_list_scan_pallas(queries, lists_data, lists_norms, lists_indices,
     """
     nq, dim = queries.shape
     n_lists, max_list = lists_indices.shape
+    # the lists go to the kernel as stored: a max_list that bins do not
+    # divide ends in a partial window, completed in VMEM (no HBM pad)
     lay = _Layout(probes, n_lists, max_list, cap, bins, k)
-    lists_data = lay.pad_lists(lists_data, max_list)
-    lists_norms = lay.pad_lists(lists_norms, max_list)
-    lists_indices = lay.pad_lists(lists_indices, max_list, fill=-1)
 
     # pre-gather: each list's probing queries → (n_lists, cap, dim).
     # ~cap/mean-probes ≤ 2× the query bytes; read once by the kernel.
@@ -667,16 +704,16 @@ def ivf_list_scan_pallas(queries, lists_data, lists_norms, lists_indices,
     from raft_tpu.neighbors._ivf_scan import gather_query_rows
     qsub = gather_query_rows(queries, lay.padded_qmap(), mode=gather)
     if fused:
-        lc = _pick_lc_fused(n_lists, lay.mlp, lay.capp, dim,
+        lc = _pick_lc_fused(n_lists, max_list, lay.capp, dim,
                             lists_data.dtype.itemsize, k, nq, lay.bins,
-                            override=lc)
+                            override=lc, tail=lay.tail)
         od, oi = _fused_list_scan_call(
             qsub, lists_data, lists_norms, lists_indices,
             lay.padded_qmap(), lay.bins, lc, k, nq, scale,
             pallas_interpret(), metric=metric)
         return _finish_fused(od, oi, nq, k, sqrt)
-    lc = _pick_lc(n_lists, lay.mlp, lay.capp, dim,
-                  lists_data.dtype.itemsize, override=lc)
+    lc = _pick_lc(n_lists, max_list, lay.capp, dim,
+                  lists_data.dtype.itemsize, override=lc, tail=lay.tail)
     # internal_dtype: candidate-block dtype carried to the merge (the
     # IVF-PQ internal_distance_dtype role) — bf16 halves the kernel's
     # HBM writeback+readback; the merge re-ranks in f32 either way

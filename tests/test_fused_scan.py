@@ -244,6 +244,141 @@ class TestDispatchCount:
         assert _count_pallas_calls(full(True)) == 2
 
 
+def _synthetic_lists(n_lists, max_list, dim, storage, seed=0):
+    """Bucketed lists as an index stores them: ragged fills (id −1 pad
+    rows at each list's end), norms of the stored values."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(n_lists, max_list, dim)).astype(np.float32)
+    scale = 1.0
+    if storage == "int8":
+        scale = 1.0 / 32
+        x = np.clip(np.round(x / scale), -127, 127).astype(np.int8)
+    data = jnp.asarray(x).astype(storage)
+    fill = rng.integers(max_list // 2, max_list + 1, size=n_lists)
+    fill[0] = max_list                       # one list runs to the end
+    rows = np.arange(max_list)[None, :]
+    ids = np.where(rows < fill[:, None],
+                   np.arange(n_lists * max_list).reshape(n_lists, -1), -1)
+    ids = jnp.asarray(ids.astype(np.int32))
+    vals = data.astype(jnp.float32) * scale
+    norms = jnp.where(ids >= 0, jnp.sum(vals * vals, axis=2), 0.0)
+    return data, norms, ids, scale
+
+
+class TestUnpaddedLists:
+    """The flat list scan reads the lists as the index stores them and
+    completes a partial last bins window in VMEM: every tier must return
+    exactly what it returns for the same lists padded in HBM to a
+    multiple of bins (the layout it used to build per batch)."""
+
+    # (max_list, k, bins, pinned cap): 200 at bins 128, 72 at bins 64
+    # (auto, k 16), the cap-overflow mask path, and a max_list that the
+    # bins divide (the kernel unchanged)
+    CASES = {"ml200_b128": (200, 32, 128, 0),
+             "ml72_b64": (72, 16, 0, 0),
+             "cap_overflow": (200, 32, 128, 8),
+             "aligned": (256, 32, 128, 0)}
+
+    @pytest.mark.parametrize("storage", ["float32", "bfloat16", "int8"])
+    @pytest.mark.parametrize("metric", ["l2", "ip"])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_bit_identical_to_padded_lists(self, case, metric, storage):
+        from raft_tpu.ops.pallas_ivf_scan import (_Layout,
+                                                  ivf_list_scan_pallas,
+                                                  ragged_tail)
+        max_list, k, bins, pin = self.CASES[case]
+        n_lists, dim, nq, n_probes = 8, 16, 24, 3
+        data, norms, ids, scale = _synthetic_lists(n_lists, max_list,
+                                                   dim, storage)
+        q = jnp.asarray(np.random.default_rng(1).normal(
+            size=(nq, dim)).astype(np.float32))
+        centers = data[:, 0].astype(jnp.float32) * scale
+        probes = _ivf_scan.coarse_probes(q, centers, n_probes, kind=metric)
+        cap = pin or _ivf_scan.probe_cap(probes, n_lists)
+        if pin:
+            assert cap < _ivf_scan.probe_cap(probes, n_lists)
+        assert ragged_tail(max_list, bins, k) == (case != "aligned")
+        pad = _Layout.resolve_bins(bins, k, max_list)
+        pad = -max_list % pad
+        padded = (jnp.pad(data, ((0, 0), (0, pad), (0, 0))),
+                  jnp.pad(norms, ((0, 0), (0, pad))),
+                  jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1))
+        for fused in (True, False):
+            scan = functools.partial(
+                ivf_list_scan_pallas, k=k, cap=cap, scale=scale,
+                bins=bins, metric=metric, fused=fused)
+            d_u, i_u = scan(q, data, norms, ids, probes)
+            d_p, i_p = scan(q, *padded, probes)
+            np.testing.assert_array_equal(np.asarray(i_u), np.asarray(i_p))
+            np.testing.assert_array_equal(np.asarray(d_u), np.asarray(d_p))
+            assert (np.asarray(i_u) >= 0).any()
+
+
+class TestFlatPlanReadsStoredLists:
+    """Structure of the flat serving program (``plan._flat_builder``):
+    no ``pad`` copies the lists per batch, the scan kernel takes the
+    stored (n_lists, max_list, dim) array, and a plan build that takes
+    the in-VMEM tail says so once on ``raft.ivf_scan.ragged_tail``."""
+
+    @staticmethod
+    def _eqns(jaxpr):
+        from jax.extend.core import ClosedJaxpr, Jaxpr
+
+        def subs(v):
+            if isinstance(v, ClosedJaxpr):
+                yield v.jaxpr
+            elif isinstance(v, Jaxpr):
+                yield v
+            elif isinstance(v, (tuple, list)):
+                for item in v:
+                    yield from subs(item)
+
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for p in eqn.params.values():
+                for sub in subs(p):
+                    yield from TestFlatPlanReadsStoredLists._eqns(sub)
+
+    @pytest.mark.parametrize("scan_bins,ragged", [(0, True), (-1, False),
+                                                  (48, False)])
+    def test_no_list_pad_and_tail_counted_once(self, flat_index, flat_data,
+                                               scan_bins, ragged,
+                                               monkeypatch):
+        if not obs.enabled():
+            pytest.skip("metrics disabled (RAFT_TPU_METRICS=0)")
+        _, q = flat_data
+        k = 8
+        monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
+        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
+        n_lists, max_list, dim = flat_index.lists_data.shape
+        # 432 rows: auto bins (64 at k 8) leave a 48-row tail; exact
+        # bins and 48 divide it
+        assert (max_list % 64 != 0) and (max_list % 48 == 0)
+        sp = ivf_flat.SearchParams(n_probes=8, scan_order="list",
+                                   scan_bins=scan_bins)
+        make, _, _, _ = plan._flat_builder(flat_index, k, sp)
+        fn, operands, _, _ = make(q.shape[0], 16)
+        closed = jax.make_jaxpr(fn)(q, *operands)
+        eqns = list(self._eqns(closed.jaxpr))
+        pads = [e.invars[0].aval.shape for e in eqns
+                if e.primitive.name == "pad"]
+        assert not [s for s in pads if s[:2] == (n_lists, max_list)], pads
+        kernel_in = [v.aval.shape for e in eqns
+                     if e.primitive.name == "pallas_call"
+                     for v in e.invars]
+        assert (n_lists, max_list, dim) in kernel_in
+
+        name = "raft.ivf_scan.ragged_tail.total{family=ivf_flat}"
+        before = obs.snapshot()
+        p = plan.build_plan(flat_index, q, k, sp, warm=False)
+        mid = obs.snapshot()
+        assert _cdiff(before, mid, name) == (1 if ragged else 0)
+        # values: the plan answers what the cold path answers
+        d0, i0 = ivf_flat.search(flat_index, q, k, sp)
+        d1, i1 = p.search(q, block=True)
+        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+
+
 class TestFusedBq:
     @pytest.fixture(scope="class")
     def bq_data(self):

@@ -95,6 +95,20 @@ def test_ivf_flat_fused_scan(compiled):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("fused", [True, False])
+def test_ivf_flat_scan_ragged_tail(compiled, fused):
+    """max_list 2000 at k 32 (bins 128) ends in an 80-row partial
+    window, completed in VMEM; no pad of the lists survives."""
+    ml = 2000
+    text = compiled(
+        lambda q, data, norms, ids, probes: ivf_scan.ivf_list_scan_pallas(
+            q, data, norms, ids, probes, 32, CAP, fused=fused),
+        QUERIES, ((N_LISTS, ml, DIM), F32), ((N_LISTS, ml), F32),
+        ((N_LISTS, ml), I32), PROBE_IDS)
+    assert "tpu_custom_call" in text
+    assert f"[{N_LISTS},{ml + 48}," not in text
+
+
 @pytest.mark.parametrize("k", [10, 32])
 def test_ivf_pq_fused_code_scan(compiled, k):
     """k=10 put auto bins (4k=40 -> 64) inside one 128-lane tile, a

@@ -83,6 +83,9 @@ REQUIRED_NAMES = (
     "raft.ivf_scan.fused.total",
     "raft.ivf_scan.fused.queries",
     "raft.ivf_scan.coarse.fallback",
+    # flat plans whose list scan completes a partial last bins window
+    # in VMEM instead of padding the lists in HBM
+    "raft.ivf_scan.ragged_tail.total",
     # sharded/streaming build instruments (ISSUE 4): per-family sharded
     # build counters and the streaming ingestion counters — the
     # sharded_build_s bench rows and the build dashboards key on these

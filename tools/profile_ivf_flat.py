@@ -56,10 +56,9 @@ if up:
 cap = _ivf_scan.probe_cap(probes, nlists)
 print("cap:", cap)
 
-lay = pis._Layout(probes, nlists, idx.lists_data.shape[1], cap, 0, k)
-data = lay.pad_lists(idx.lists_data, idx.lists_data.shape[1])
-norms = lay.pad_lists(idx.lists_norms, idx.lists_norms.shape[1])
-ids = lay.pad_lists(idx.lists_indices, idx.lists_indices.shape[1], fill=-1)
+max_list = idx.lists_data.shape[1]
+lay = pis._Layout(probes, nlists, max_list, cap, 0, k)
+data, norms, ids = idx.lists_data, idx.lists_norms, idx.lists_indices
 qmap = lay.padded_qmap()
 
 # stage 2: qsub gather — honors RAFT_TPU_GATHER (rows|onehot) so the
@@ -72,8 +71,8 @@ print(f"qsub gather[{os.environ.get('RAFT_TPU_GATHER', 'rows')}] "
 qsub = f_gather(q)
 
 # stage 3: kernel
-lc = pis._pick_lc(nlists, lay.mlp, lay.capp, d, 4)
-print("lc:", lc, "bins:", lay.bins, "mlp:", lay.mlp)
+lc = pis._pick_lc(nlists, max_list, lay.capp, d, 4, tail=lay.tail)
+print("lc:", lc, "bins:", lay.bins, "max_list:", max_list)
 t = timed(lambda: pis._list_scan_call(qsub, data, norms, ids, lay.bins, lc,
                                       1.0, False))
 print(f"list-scan kernel: {t*1000:.1f} ms")
