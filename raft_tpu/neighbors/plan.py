@@ -339,7 +339,8 @@ def _pq_builder(index, k: int, params):
     def make(nq: int, cap: int):
         host_epilogue = None
         if scan_mode == "codes":
-            from raft_tpu.ops.pallas_ivf_scan import fused_mode
+            from raft_tpu.ops.pallas_ivf_scan import (fused_mode,
+                                                      pq_decoded_share)
             code_norms = ivf_pq._ensure_code_norms(index, params,
                                                    per_cluster, kind)
             gather = _ivf_scan.gather_mode()
@@ -347,6 +348,11 @@ def _pq_builder(index, k: int, params):
             if use_fused:
                 obs.counter("raft.ivf_scan.fused.total",
                             family="ivf_pq").inc()
+            # the code scan decodes each list only up to its last row
+            obs.gauge("raft.ivf_scan.pq.decoded_share").set(
+                pq_decoded_share(index.lists_indices, kk, cap, bins,
+                                 index.pq_dim, index.pq_centers.shape[1],
+                                 index.rot_dim, params.lut_dtype))
 
             def device_phase(q, centers, centers_rot, rot, books, codes,
                              norms, ids):

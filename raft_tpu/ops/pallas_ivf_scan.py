@@ -521,53 +521,64 @@ def _fused_bq_scan_call(qsub, bits_i32, norms2, scales, ids, qmap,
     return od, oi
 
 
-def _fused_pq_scan_kernel(qsub_ref, codes_ref, norms_ref, ids_ref,
+def _cell_rows(ext_ref, split: int, sub_ml: int):
+    """Rows grid cell ``g`` decodes: its sub-list's share of the list's
+    extent (``_scan_extents``), read from the scalar-prefetched
+    ``ext_ref`` — 0 for a cell past the list's last row, or of a list
+    no query probes."""
+    g = pl.program_id(0)
+    return jnp.clip(ext_ref[g // split] - (g % split) * sub_ml, 0, sub_ml)
+
+
+def _fused_pq_scan_kernel(ext_ref, qsub_ref, codes_ref, norms_ref, ids_ref,
                           books_ref, qmap_ref, cent_ref, od_ref, oi_ref,
-                          *, bins: int, k: int, metric: str, pq_dim: int,
-                          pq_len: int, n_codes: int, lut_dtype,
-                          per_cluster: bool):
+                          *, bins: int, chunk: int, split: int, k: int,
+                          metric: str, pq_dim: int, pq_len: int,
+                          n_codes: int, lut_dtype, per_cluster: bool):
     _init_state(od_ref, oi_ref)
-    cd, ci = _pq_cell_candidates(
-        qsub_ref[0], codes_ref[0], norms_ref[0, 0], ids_ref[0, 0],
-        books_ref, bins=bins, metric=metric, pq_dim=pq_dim,
-        pq_len=pq_len, n_codes=n_codes, lut_dtype=lut_dtype,
-        per_cluster=per_cluster)
-    if metric == "ip":
-        # −q·c_l in-kernel (see _fused_bq_scan_kernel); for PER_CLUSTER
-        # both operands arrive p-major permuted — the dot is invariant
-        corr = jnp.sum(qsub_ref[0] * cent_ref[0, 0][None, :], axis=1)
-        cd = cd - corr[:, None]
-    _merge_state(od_ref, oi_ref, cd, ci, qmap_ref[0, 0], k=k, cap_axis=0)
+    n = _cell_rows(ext_ref, split, codes_ref.shape[2])
+
+    # a cell with no stored row, or of an unprobed list, has no
+    # candidate to offer: no decode, no score, no merge
+    @pl.when(n > 0)
+    def _():
+        cd, ci = _pq_cell_candidates(
+            qsub_ref[0], codes_ref, norms_ref, ids_ref, books_ref, n,
+            bins=bins, chunk=chunk, metric=metric, pq_dim=pq_dim,
+            pq_len=pq_len, n_codes=n_codes, lut_dtype=lut_dtype,
+            per_cluster=per_cluster)
+        if metric == "ip":
+            # −q·c_l in-kernel (see _fused_bq_scan_kernel); for
+            # PER_CLUSTER both operands arrive p-major permuted — the
+            # dot is invariant
+            corr = jnp.sum(qsub_ref[0] * cent_ref[0, 0][None, :], axis=1)
+            cd = cd - corr[:, None]
+        _merge_state(od_ref, oi_ref, cd, ci, qmap_ref[0, 0], k=k,
+                     cap_axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("bins", "k", "nq", "metric",
-                                             "lut_dtype", "interpret",
-                                             "split", "per_cluster"))
-def _fused_pq_scan_call(qsub, codes_t, norms, ids, books, qmap,
-                        centers_rot, bins: int, k: int, nq: int,
-                        interpret: bool, metric: str, lut_dtype,
+@functools.partial(jax.jit, static_argnames=("bins", "chunk", "k", "nq",
+                                             "metric", "lut_dtype",
+                                             "interpret", "split",
+                                             "per_cluster"))
+def _fused_pq_scan_call(ext, qsub, codes_t, norms, ids, books, qmap,
+                        centers_rot, bins: int, chunk: int, k: int,
+                        nq: int, interpret: bool, metric: str, lut_dtype,
                         split: int = 1, per_cluster: bool = False):
     """The fused tail of the code scan: same grid/operands as
-    ``_pq_scan_call`` (incl. the ``split`` sub-cell sharing of a list's
-    query/qmap blocks via ``g // split``) plus the qmap and rotated
-    centers, with the candidate blocks replaced by the revisited
-    state."""
+    ``_pq_scan_call`` (incl. the list extents, the ``split`` sub-cell
+    sharing of a list's query/qmap blocks via ``g // split``) plus the
+    qmap and rotated centers, with the candidate blocks replaced by the
+    revisited state."""
     n_lists, cap, rot_dim = qsub.shape
     n_cells, pq_dim, max_list = codes_t.shape
     kp = _round_up(k, 8)
     nqp = _round_up(nq, 128)
-    if per_cluster:
-        n_codes, pq_len = books.shape[1], books.shape[2]
-        books_spec = pl.BlockSpec((1, n_codes, pq_len),
-                                  lambda g: (g // split, 0, 0))
-    else:
-        n_codes = books.shape[1] // pq_dim
-        pq_len = rot_dim // pq_dim
-        books_spec = pl.BlockSpec((rot_dim, pq_dim * n_codes),
-                                  lambda g: (0, 0))
+    books_spec, n_codes, pq_len = _pq_books_spec(books, pq_dim, rot_dim,
+                                                 split, per_cluster)
     kern = functools.partial(
-        _fused_pq_scan_kernel, bins=bins, k=k, metric=metric,
-        pq_dim=pq_dim, pq_len=pq_len, n_codes=n_codes,
+        _fused_pq_scan_kernel, bins=bins, chunk=chunk, split=split, k=k,
+        metric=metric, pq_dim=pq_dim, pq_len=pq_len, n_codes=n_codes,
         lut_dtype=jnp.dtype(lut_dtype), per_cluster=per_cluster)
     norms3 = norms[:, None, :]
     ids3 = ids[:, None, :]
@@ -575,18 +586,22 @@ def _fused_pq_scan_call(qsub, codes_t, norms, ids, books, qmap,
     cent3 = centers_rot[:, None, :]
     od, oi = pl.pallas_call(
         kern,
-        grid=(n_cells,),
-        in_specs=[pl.BlockSpec((1, cap, rot_dim),
-                               lambda g: (g // split, 0, 0)),
-                  pl.BlockSpec((1, pq_dim, max_list), lambda g: (g, 0, 0)),
-                  pl.BlockSpec((1, 1, max_list), lambda g: (g, 0, 0)),
-                  pl.BlockSpec((1, 1, max_list), lambda g: (g, 0, 0)),
-                  books_spec,
-                  pl.BlockSpec((1, 1, cap), lambda g: (g // split, 0, 0)),
-                  pl.BlockSpec((1, 1, rot_dim),
-                               lambda g: (g // split, 0, 0))],
-        out_specs=[pl.BlockSpec((kp, nqp), lambda g: (0, 0)),
-                   pl.BlockSpec((kp, nqp), lambda g: (0, 0))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_cells,),
+            in_specs=[pl.BlockSpec((1, cap, rot_dim),
+                                   lambda g, e: (g // split, 0, 0)),
+                      pl.BlockSpec((1, pq_dim, max_list),
+                                   lambda g, e: (g, 0, 0)),
+                      pl.BlockSpec((1, 1, max_list), lambda g, e: (g, 0, 0)),
+                      pl.BlockSpec((1, 1, max_list), lambda g, e: (g, 0, 0)),
+                      books_spec,
+                      pl.BlockSpec((1, 1, cap),
+                                   lambda g, e: (g // split, 0, 0)),
+                      pl.BlockSpec((1, 1, rot_dim),
+                                   lambda g, e: (g // split, 0, 0))],
+            out_specs=[pl.BlockSpec((kp, nqp), lambda g, e: (0, 0)),
+                       pl.BlockSpec((kp, nqp), lambda g, e: (0, 0))]),
         out_shape=[jax.ShapeDtypeStruct((kp, nqp), jnp.float32),
                    jax.ShapeDtypeStruct((kp, nqp), jnp.int32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -599,7 +614,7 @@ def _fused_pq_scan_call(qsub, codes_t, norms, ids, books, qmap,
                             + 4 * n_lists * cap * rot_dim + 8 * kp * nqp),
             transcendentals=0),
         interpret=interpret,
-    )(qsub, jax.lax.bitcast_convert_type(codes_t, jnp.int8), norms3,
+    )(ext, qsub, jax.lax.bitcast_convert_type(codes_t, jnp.int8), norms3,
       ids3, books, qmap3, cent3)
     return od, oi
 
@@ -889,11 +904,11 @@ def ivf_bq_scan_pallas(q_rot, centers_rot, bits, norms2, scales,
     return lay.merge(cd, ci, probes, k, sqrt)
 
 
-def _pq_scan_kernel(qsub_ref, codes_ref, norms_ref, ids_ref, books_ref,
-                    cd_ref, ci_ref, *, bins: int, metric: str, pq_dim: int,
-                    pq_len: int, n_codes: int, lut_dtype,
-                    per_cluster: bool):
-    """One IVF list per grid cell, scored straight from its u8 codes.
+def _pq_scan_kernel(ext_ref, qsub_ref, codes_ref, norms_ref, ids_ref,
+                    books_ref, cd_ref, ci_ref, *, bins: int, chunk: int,
+                    split: int, metric: str, pq_dim: int, pq_len: int,
+                    n_codes: int, lut_dtype, per_cluster: bool):
+    """One IVF sub-list per grid cell, scored straight from its u8 codes.
 
     Decode is ONE one-hot × codebook matmul on the MXU, lanes-major
     over list rows: the codes arrive pre-transposed (pq_dim, ML), one
@@ -912,6 +927,13 @@ def _pq_scan_kernel(qsub_ref, codes_ref, norms_ref, ids_ref, books_ref,
     property, ivf_pq_search.cuh:593) and ONE K = rot_dim matmul scores
     all probing queries against it.
 
+    Only the cell's stored rows are decoded: ``ext_ref`` (scalar-
+    prefetched, one int32 per list) holds each list's extent, and the
+    cell walks ``ceil(n / chunk)`` chunks of its ``n`` rows
+    (``_cell_rows``) — padding past a list's last row and lists no
+    query probes cost nothing. A cell with no row runs no chunk and
+    writes the (+inf, −1) block the padded scan produced for it.
+
     PER_CLUSTER: the cell's single codebook (C, pl) is shared across
     subspaces, so block-diagonal stacking would need a per-list B.
     Instead the one-hot stacks on the LANE axis — ``oh2`` (C,
@@ -922,27 +944,31 @@ def _pq_scan_kernel(qsub_ref, codes_ref, norms_ref, ids_ref, books_ref,
     needs no in-kernel transpose.
     """
     cd, ci = _pq_cell_candidates(
-        qsub_ref[0], codes_ref[0], norms_ref[0, 0], ids_ref[0, 0],
-        books_ref, bins=bins, metric=metric, pq_dim=pq_dim,
-        pq_len=pq_len, n_codes=n_codes, lut_dtype=lut_dtype,
-        per_cluster=per_cluster)
+        qsub_ref[0], codes_ref, norms_ref, ids_ref, books_ref,
+        _cell_rows(ext_ref, split, codes_ref.shape[2]), bins=bins,
+        chunk=chunk, metric=metric, pq_dim=pq_dim, pq_len=pq_len,
+        n_codes=n_codes, lut_dtype=lut_dtype, per_cluster=per_cluster)
     cd_ref[0] = cd.astype(cd_ref.dtype)
     ci_ref[0] = ci
 
 
-def _pq_cell_candidates(q, codes_i8, norms_l, ids, books_ref, *,
-                        bins: int, metric: str, pq_dim: int, pq_len: int,
-                        n_codes: int, lut_dtype, per_cluster: bool):
+def _pq_cell_candidates(q, codes_ref, norms_ref, ids_ref, books_ref, n, *,
+                        bins: int, chunk: int, metric: str, pq_dim: int,
+                        pq_len: int, n_codes: int, lut_dtype,
+                        per_cluster: bool):
     """One PQ cell's binned candidates scored straight from its u8
     codes (the shared per-cell body — see ``_flat_list_candidates``;
     ``books_ref`` stays a ref because PER_CLUSTER reads a per-cell
     block while PER_SUBSPACE reads the shared decode matrix).
-    Returns ``(cd (cap, bins), ci (cap, bins))`` — slot-major, unlike
-    the flat/bq helpers."""
-    # codes arrive as i8 bitcast of the u8 store (1 B/code of HBM
-    # traffic), pre-transposed; recover 0..255 with a mask
-    codes_t = codes_i8.astype(jnp.int32) & 0xFF      # (pq_dim, ML)
-    ml = codes_t.shape[1]
+
+    Only the cell's first ``n`` rows are decoded, ``chunk`` rows (a
+    multiple of ``bins``) at a time; each chunk's strided-bin minimum
+    folds into a running ``(cap, bins)`` minimum with the single-pass
+    tie rule (smallest id among equal distances), so the candidates are
+    those of a scan over every row of the cell: rows past ``n`` hold id
+    −1, scored +inf (``n`` 0 gives +inf, id −1 throughout). Returns
+    ``(cd (cap, bins), ci (cap, bins))`` — slot-major, unlike the
+    flat/bq helpers."""
     cap = q.shape[0]
     # bf16 LUT = single MXU pass (the reference's fp16-LUT speed tier);
     # f32 LUT = HIGHEST-precision passes (its fp32 accuracy tier);
@@ -952,80 +978,104 @@ def _pq_cell_candidates(q, codes_i8, norms_l, ids, books_ref, *,
     f32_lut = jnp.dtype(lut_dtype) == jnp.dtype(jnp.float32)
     operand = jnp.float32 if f32_lut else jnp.bfloat16
     prec = jax.lax.Precision.HIGHEST if f32_lut else None
+    rr = jnp.sum(q * q, axis=1)[:, None]                 # (cap, 1)
+    q_op = q.astype(operand)
+    w = chunk // bins
 
-    if per_cluster:
-        codes_flat = codes_t.reshape(1, pq_dim * ml)
-        iota = jax.lax.broadcasted_iota(
-            jnp.int32, (n_codes, pq_dim * ml), 0)
-        oh2 = (iota == codes_flat).astype(operand)   # (C, pq_dim·ML)
-        book = books_ref[0]                          # (C, pl)
-        dec_pm = jax.lax.dot_general(
-            book.astype(operand), oh2, (((0,), (0,)), ((), ())),
+    def one_chunk(c, carry):
+        run_d, run_i = carry
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        # codes arrive as i8 bitcast of the u8 store (1 B/code of HBM
+        # traffic), pre-transposed; recover 0..255 with a mask
+        codes_t = codes_ref[0, :, rows].astype(jnp.int32) & 0xFF
+        if per_cluster:
+            codes_flat = codes_t.reshape(1, pq_dim * chunk)
+            iota = jax.lax.broadcasted_iota(
+                jnp.int32, (n_codes, pq_dim * chunk), 0)
+            oh2 = (iota == codes_flat).astype(operand)  # (C, pq_dim·ch)
+            dec_pm = jax.lax.dot_general(
+                books_ref[0].astype(operand), oh2,
+                (((0,), (0,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32)   # (pl, pq_dim·ch)
+            dec_t = dec_pm.reshape(pq_len * pq_dim, chunk)  # p-major
+        else:
+            iota = jax.lax.broadcasted_iota(
+                jnp.int32, (pq_dim, n_codes, chunk), 1)
+            oh = (iota == codes_t[:, None, :]).astype(operand)
+            oh2 = oh.reshape(pq_dim * n_codes, chunk)
+            dec_t = jax.lax.dot_general(
+                books_ref[...].astype(operand), oh2,
+                (((1,), (0,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32)   # (rot_dim, ch)
+
+        ip = jax.lax.dot_general(
+            q_op, dec_t.astype(operand), (((1,), (0,)), ((), ())),
             precision=prec,
-            preferred_element_type=jnp.float32)      # (pl, pq_dim·ML)
-        dec_t = dec_pm.reshape(pq_len * pq_dim, ml)  # p-major rows
-    else:
-        iota = jax.lax.broadcasted_iota(
-            jnp.int32, (pq_dim, n_codes, ml), 1)
-        oh = (iota == codes_t[:, None, :]).astype(operand)
-        oh2 = oh.reshape(pq_dim * n_codes, ml)
-        dec_t = jax.lax.dot_general(
-            books_ref[...].astype(operand), oh2, (((1,), (0,)), ((), ())),
-            precision=prec,
-            preferred_element_type=jnp.float32)      # (rot_dim, ML)
+            preferred_element_type=jnp.float32)       # (cap, ch)
+        ids_b = jnp.broadcast_to(ids_ref[0, :, rows], (cap, chunk))
+        if metric == "ip":
+            d = jnp.where(ids_b >= 0, -ip, jnp.inf)
+        else:
+            d = rr + norms_ref[0, :, rows] - 2.0 * ip
+            d = jnp.where(ids_b >= 0, jnp.maximum(d, 0.0), jnp.inf)
 
-    ip = jax.lax.dot_general(
-        q.astype(operand), dec_t.astype(operand),
-        (((1,), (0,)), ((), ())), precision=prec,
-        preferred_element_type=jnp.float32)          # (cap, ML)
-    ids_b = jnp.broadcast_to(ids[None, :], (cap, ml))
-    if metric == "ip":
-        d = jnp.where(ids_b >= 0, -ip, jnp.inf)
-    else:
-        rr = jnp.sum(q * q, axis=1)[:, None]             # (cap, 1)
-        d = rr + norms_l[None, :] - 2.0 * ip
-        d = jnp.where(ids_b >= 0, jnp.maximum(d, 0.0), jnp.inf)
+        # strided bins along the row axis (row r → bin r % B), row-major
+        # reshape (cap, w, B): element [., i, b] = row i·B + b; chunks
+        # start on bins multiples, so every row keeps its bin
+        db_ = d.reshape(cap, w, bins)
+        cd = jnp.min(db_, axis=1)                        # (cap, B)
+        rb = ids_b.reshape(cap, w, bins)
+        ci = jnp.min(jnp.where(db_ == cd[:, None, :], rb, _BIG_I32),
+                     axis=1)
+        best = jnp.minimum(run_d, cd)
+        return best, jnp.minimum(jnp.where(run_d == best, run_i, _BIG_I32),
+                                 jnp.where(cd == best, ci, _BIG_I32))
 
-    # strided bins along the row axis (row r → bin r % B), row-major
-    # reshape (cap, w, B): element [., i, b] = row i·B + b
-    w = ml // bins
-    db_ = d.reshape(cap, w, bins)
-    cd = jnp.min(db_, axis=1)                            # (cap, B)
-    rb = ids_b.reshape(cap, w, bins)
-    ci = jnp.min(jnp.where(db_ == cd[:, None, :], rb, _BIG_I32), axis=1)
+    init = (jnp.full((cap, bins), jnp.inf, jnp.float32),
+            jnp.full((cap, bins), _BIG_I32, jnp.int32))
+    cd, ci = jax.lax.fori_loop(0, pl.cdiv(n, chunk), one_chunk, init)
     return cd, jnp.where(ci == _BIG_I32, -1, ci)
 
 
-@functools.partial(jax.jit, static_argnames=("bins", "metric", "out_dtype",
-                                             "lut_dtype", "interpret",
-                                             "split", "per_cluster"))
-def _pq_scan_call(qsub, codes_t, norms, ids, books, bins: int,
-                  interpret: bool, metric: str, lut_dtype,
-                  out_dtype=jnp.float32, split: int = 1,
-                  per_cluster: bool = False):
-    """``split`` > 1: codes/norms/ids carry ``split`` sub-lists per
-    original list (leading dim n_lists·split); the query blocks stay
-    per-ORIGINAL-list and are shared across a list's sub-cells via the
-    index map — no duplicated HBM. ``codes_t`` arrives pre-transposed
-    (n_cells, pq_dim, sub_ml) u8. ``books``: PER_SUBSPACE — the
-    block-diagonal decode matrix (rot_dim, pq_dim·C), one shared block
-    fetched once; PER_CLUSTER — (n_lists, C, pl), each cell fetches its
-    own list's codebook (and ``qsub`` arrives p-major permuted, see
-    ``_pq_scan_kernel``)."""
-    n_lists, cap, rot_dim = qsub.shape
-    n_cells, pq_dim, max_list = codes_t.shape
+def _pq_books_spec(books, pq_dim: int, rot_dim: int, split: int,
+                   per_cluster: bool):
+    """The codebook operand's block and its (n_codes, pq_len):
+    PER_CLUSTER — each cell fetches its list's (C, pl) book;
+    PER_SUBSPACE — one shared block-diagonal decode matrix."""
     if per_cluster:
         n_codes, pq_len = books.shape[1], books.shape[2]
-        books_spec = pl.BlockSpec((1, n_codes, pq_len),
-                                  lambda g: (g // split, 0, 0))
-    else:
-        n_codes = books.shape[1] // pq_dim
-        pq_len = rot_dim // pq_dim
-        books_spec = pl.BlockSpec((rot_dim, pq_dim * n_codes),
-                                  lambda g: (0, 0))
+        return (pl.BlockSpec((1, n_codes, pq_len),
+                             lambda g, e: (g // split, 0, 0)),
+                n_codes, pq_len)
+    return (pl.BlockSpec((rot_dim, books.shape[1]), lambda g, e: (0, 0)),
+            books.shape[1] // pq_dim, rot_dim // pq_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("bins", "chunk", "metric",
+                                             "out_dtype", "lut_dtype",
+                                             "interpret", "split",
+                                             "per_cluster"))
+def _pq_scan_call(ext, qsub, codes_t, norms, ids, books, bins: int,
+                  chunk: int, interpret: bool, metric: str, lut_dtype,
+                  out_dtype=jnp.float32, split: int = 1,
+                  per_cluster: bool = False):
+    """``ext`` (n_lists,) int32: each list's extent, scalar-prefetched
+    (``_scan_extents``). ``split`` > 1: codes/norms/ids carry ``split``
+    sub-lists per original list (leading dim n_lists·split); the query
+    blocks stay per-ORIGINAL-list and are shared across a list's
+    sub-cells via the index map — no duplicated HBM. ``codes_t``
+    arrives pre-transposed (n_cells, pq_dim, sub_ml) u8. ``books``:
+    PER_SUBSPACE — the block-diagonal decode matrix (rot_dim,
+    pq_dim·C), one shared block fetched once; PER_CLUSTER — (n_lists,
+    C, pl), each cell fetches its own list's codebook (and ``qsub``
+    arrives p-major permuted, see ``_pq_scan_kernel``)."""
+    n_lists, cap, rot_dim = qsub.shape
+    n_cells, pq_dim, max_list = codes_t.shape
+    books_spec, n_codes, pq_len = _pq_books_spec(books, pq_dim, rot_dim,
+                                                 split, per_cluster)
     kern = functools.partial(
-        _pq_scan_kernel, bins=bins, metric=metric, pq_dim=pq_dim,
-        pq_len=pq_len, n_codes=n_codes,
+        _pq_scan_kernel, bins=bins, chunk=chunk, split=split,
+        metric=metric, pq_dim=pq_dim, pq_len=pq_len, n_codes=n_codes,
         lut_dtype=jnp.dtype(lut_dtype), per_cluster=per_cluster)
     # norms/ids carry a singleton middle axis (see _list_scan_call): the
     # 2-D (1, max_list) block put 1 in a Mosaic-constrained slot and
@@ -1034,15 +1084,19 @@ def _pq_scan_call(qsub, codes_t, norms, ids, books, bins: int,
     ids3 = ids[:, None, :]
     cd, ci = pl.pallas_call(
         kern,
-        grid=(n_cells,),
-        in_specs=[pl.BlockSpec((1, cap, rot_dim),
-                               lambda g: (g // split, 0, 0)),
-                  pl.BlockSpec((1, pq_dim, max_list), lambda g: (g, 0, 0)),
-                  pl.BlockSpec((1, 1, max_list), lambda g: (g, 0, 0)),
-                  pl.BlockSpec((1, 1, max_list), lambda g: (g, 0, 0)),
-                  books_spec],
-        out_specs=[pl.BlockSpec((1, cap, bins), lambda g: (g, 0, 0)),
-                   pl.BlockSpec((1, cap, bins), lambda g: (g, 0, 0))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_cells,),
+            in_specs=[pl.BlockSpec((1, cap, rot_dim),
+                                   lambda g, e: (g // split, 0, 0)),
+                      pl.BlockSpec((1, pq_dim, max_list),
+                                   lambda g, e: (g, 0, 0)),
+                      pl.BlockSpec((1, 1, max_list), lambda g, e: (g, 0, 0)),
+                      pl.BlockSpec((1, 1, max_list), lambda g, e: (g, 0, 0)),
+                      books_spec],
+            out_specs=[pl.BlockSpec((1, cap, bins), lambda g, e: (g, 0, 0)),
+                       pl.BlockSpec((1, cap, bins),
+                                    lambda g, e: (g, 0, 0))]),
         out_shape=[jax.ShapeDtypeStruct((n_cells, cap, bins), out_dtype),
                    jax.ShapeDtypeStruct((n_cells, cap, bins), jnp.int32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -1057,9 +1111,82 @@ def _pq_scan_call(qsub, codes_t, norms, ids, books, bins: int,
                             + 8 * n_cells * cap * bins),
             transcendentals=0),
         interpret=interpret,
-    )(qsub, jax.lax.bitcast_convert_type(codes_t, jnp.int8), norms3, ids3,
-      books)
+    )(ext, qsub, jax.lax.bitcast_convert_type(codes_t, jnp.int8), norms3,
+      ids3, books)
     return cd, ci
+
+
+# rows a code-scan cell decodes per step: a multiple of bins dividing
+# the cell's sub-list, at most this many (one bins window when bins
+# are wider) — the granularity at which a list's tail is decoded. On a
+# v5e at the 2M-row IVF-PQ point (sub-lists of 1024, mean list 977 of
+# 3600) 256 beats 512 by 0.4%; 512 wins 2.8% when every sub-list is full
+_PQ_CHUNK_ROWS = 256
+
+
+def _pq_bins(bins: int, k: int, max_list: int) -> int:
+    """The code scan's resolved bins: it bins along the lane axis
+    ((cap, ML) -> (cap, w, bins)), and Mosaic refuses a reshape that
+    splits a 128-lane tile, so bins are whole lane tiles (more bins
+    keep a superset of candidates)."""
+    bins = _Layout.resolve_bins(bins, k, max_list)
+    return _round_up(max_list if bins < 0 else bins, 128)
+
+
+def _pq_cells(max_list: int, bins: int, cap: int, pq_dim: int,
+              n_codes: int, rot_dim: int, lut_dtype):
+    """``(split, sub_ml, chunk)``: the code scan's grid over a list of
+    ``max_list`` rows padded to a ``bins`` multiple. VMEM bound: per
+    grid cell the stacked one-hot (pq_dim·C, sub_ml), decode tile
+    (rot_dim, sub_ml) and score block (cap, sub_ml) all scale with the
+    list length — oversized lists split into ``split`` sub-lists of
+    ``sub_ml`` rows (extra grid cells sharing the list's probing
+    queries) so skewed or low-n_lists indexes still compile. A cell
+    decodes ``chunk`` rows at a time (``_PQ_CHUNK_ROWS``)."""
+    from raft_tpu.neighbors._ivf_scan import largest_divisor_at_most
+    op_item = 4 if jnp.dtype(lut_dtype) == jnp.dtype(jnp.float32) else 2
+    capp = _round_up(max(cap, 8), 8)
+    per_row = (pq_dim * n_codes * op_item + rot_dim * 4 + capp * 4
+               + pq_dim * 4)
+    row_budget = max(bins, (_VMEM_LIMIT // 3) // per_row)
+    mlp = _round_up(max_list, bins)
+    split = -(-mlp // _round_up(row_budget, bins))
+    sub_ml = _round_up(-(-mlp // split), bins)
+    chunk = bins * largest_divisor_at_most(
+        sub_ml // bins, max(1, _PQ_CHUNK_ROWS // bins))
+    return split, sub_ml, chunk
+
+
+def _list_extents(lists_indices):
+    """(n_lists,) int32: the position after each list's last row with an
+    id ≥ 0 — every row past it is padding. Derived from the ids, so it
+    holds for any layout a caller hands in (holes, a fold, a prefix)."""
+    ml = lists_indices.shape[1]
+    pos = jnp.arange(1, ml + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(lists_indices >= 0, pos[None, :], 0), axis=1)
+
+
+def _scan_extents(lists_indices, qmap):
+    """The extents the code scan decodes: a list no query probes (its
+    ``qmap`` row all −1) has none."""
+    return jnp.where(jnp.any(qmap >= 0, axis=1),
+                     _list_extents(lists_indices), 0)
+
+
+def pq_decoded_share(lists_indices, k: int, cap: int, bins: int,
+                     pq_dim: int, n_codes: int, rot_dim: int,
+                     lut_dtype) -> float:
+    """Share of the code scan's stored rows (``n_cells · sub_ml``) it
+    decodes when every list is probed: each list's extent rounded up to
+    the scan's chunk. One host read of the (n_lists,) extents."""
+    import numpy as np
+    n_lists, max_list = lists_indices.shape
+    bins = _pq_bins(bins, k, max_list)
+    split, sub_ml, chunk = _pq_cells(max_list, bins, cap, pq_dim, n_codes,
+                                     rot_dim, lut_dtype)
+    ext = np.asarray(_list_extents(lists_indices)).astype(np.int64)
+    return float((-(-ext // chunk) * chunk).sum()) / (n_lists * split
+                                                      * sub_ml)
 
 
 def ivf_pq_code_scan_pallas(q_rot, centers_rot, pq_centers, codes,
@@ -1093,16 +1220,21 @@ def ivf_pq_code_scan_pallas(q_rot, centers_rot, pq_centers, codes,
     ``code_norms`` are exact: PQ subspaces concatenate orthogonally, so
     ``||decoded_i||² = Σ_s ||book_s[c_is]||²`` is computed once at build
     from the codebook norm table.
+
+    The lists arrive padded to the largest; each list's extent (the
+    position after its last row with an id ≥ 0, 0 when no query probes
+    it) rides to the kernel as a scalar, and a grid cell decodes only
+    the chunks below it — the candidates are those of a scan over every
+    row, since rows past the extent hold id −1.
     """
     nq = q_rot.shape[0]
     n_lists, max_list, pq_dim = codes.shape
     _, n_codes, pq_len = pq_centers.shape
-    # the code scan bins along the lane axis ((cap, ML) -> (cap, w,
-    # bins)), and Mosaic refuses a reshape that splits a 128-lane tile:
-    # bins are whole lane tiles (more bins keep a superset of candidates)
-    bins = _Layout.resolve_bins(bins, k, max_list)
-    bins = _round_up(max_list if bins < 0 else bins, 128)
+    bins = _pq_bins(bins, k, max_list)
     lay = _Layout(probes, n_lists, max_list, cap, bins, k)
+    # each list's extent, from its ids (0 for a list no query probes):
+    # the kernel decodes only the rows below it
+    ext = _scan_extents(lists_indices, lay.qmap)
     codes = lay.pad_lists(codes, max_list)
     code_norms = lay.pad_lists(code_norms, max_list)
     lists_indices = lay.pad_lists(lists_indices, max_list, fill=-1)
@@ -1143,17 +1275,8 @@ def ivf_pq_code_scan_pallas(q_rot, centers_rot, pq_centers, codes,
         # table — so the L2 epilogue stays self-consistent)
         books_in = B.astype(jnp.float8_e4m3fn if fp8 else operand)
 
-    # VMEM bound: per grid cell the stacked one-hot (pq_dim·C, sub_ml),
-    # decode tile (rot_dim, sub_ml) and score block (cap, sub_ml) all
-    # scale with the list length — split oversized lists into `split`
-    # sub-lists (extra grid cells sharing the list's probing queries)
-    # so skewed or low-n_lists indexes still compile.
-    op_item = 4 if f32_lut else 2
-    per_row = (pq_dim * n_codes * op_item + rot_dim * 4 + lay.capp * 4
-               + pq_dim * 4)
-    row_budget = max(lay.bins, (_VMEM_LIMIT // 3) // per_row)
-    split = -(-lay.mlp // _round_up(row_budget, lay.bins))
-    sub_ml = _round_up(-(-lay.mlp // split), lay.bins)
+    split, sub_ml, chunk = _pq_cells(max_list, lay.bins, cap, pq_dim,
+                                     n_codes, rot_dim, lut_dtype)
     mlp2 = sub_ml * split
     if mlp2 != lay.mlp:
         pad = [(0, 0), (0, mlp2 - lay.mlp)]
@@ -1187,14 +1310,14 @@ def ivf_pq_code_scan_pallas(q_rot, centers_rot, pq_centers, codes,
         cent_k = (centers_rot[..., perm]
                   if (per_cluster and metric == "ip") else centers_rot)
         od, oi = _fused_pq_scan_call(
-            qsub_k, codes_t, as_sub(code_norms), as_sub(lists_indices),
-            books_in, lay.padded_qmap(), cent_k, lay.bins, k, nq,
-            pallas_interpret(), metric=metric, lut_dtype=lut_dtype,
-            split=split, per_cluster=per_cluster)
+            ext, qsub_k, codes_t, as_sub(code_norms),
+            as_sub(lists_indices), books_in, lay.padded_qmap(), cent_k,
+            lay.bins, chunk, k, nq, pallas_interpret(), metric=metric,
+            lut_dtype=lut_dtype, split=split, per_cluster=per_cluster)
         return _finish_fused(od, oi, nq, k, sqrt)
-    cd, ci = _pq_scan_call(qsub_k, codes_t, as_sub(code_norms),
+    cd, ci = _pq_scan_call(ext, qsub_k, codes_t, as_sub(code_norms),
                            as_sub(lists_indices), books_in, lay.bins,
-                           pallas_interpret(), metric=metric,
+                           chunk, pallas_interpret(), metric=metric,
                            lut_dtype=lut_dtype,
                            out_dtype=internal_distance_dtype, split=split,
                            per_cluster=per_cluster)

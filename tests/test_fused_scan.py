@@ -489,6 +489,141 @@ class TestFusedPq:
         assert rec_f >= rec_u - 0.005, (rec_f, rec_u)
 
 
+def _pq_layout(per_cluster: bool):
+    """A hand-built IVF-PQ layout over 8 lists of max_list 2048 (pq_dim
+    8, 32 codes, rot_dim 32) whose extents cover the code scan's cases:
+    an empty list, an extent off the chunk grid, a full list (extent ==
+    the padded length), a −1 hole before the last real row, a 3-row
+    list, one that ends inside a second sub-list, and two lists that
+    carry rows but no query probes."""
+    rng = np.random.default_rng(7)
+    n_lists, max_list, pq_dim, n_codes, pq_len = 8, 2048, 8, 32, 4
+    rot_dim = pq_dim * pq_len
+    sizes = [0, 300, 2048, 200, 3, 1300, 400, 400]
+    ids = np.full((n_lists, max_list), -1, np.int64)
+    for lst, n in enumerate(sizes):
+        ids[lst, :n] = lst * max_list + np.arange(n)
+    ids[3, 50:60] = -1                                 # the hole
+    codes = rng.integers(0, n_codes, (n_lists, max_list, pq_dim),
+                         dtype=np.uint8)
+    norms = rng.uniform(1.0, 4.0, (n_lists, max_list)).astype(np.float32)
+    books = rng.normal(size=(n_lists if per_cluster else pq_dim, n_codes,
+                             pq_len)).astype(np.float32)
+    q_rot = rng.normal(size=(6, rot_dim)).astype(np.float32)
+    centers_rot = rng.normal(size=(n_lists, rot_dim)).astype(np.float32)
+    # query 0 probes the empty and the 3-row list only (k 8 leaves it
+    # five −1 sentinels); lists 6 and 7 are probed by no query
+    probes = np.array([[0, 4], [1, 2], [2, 3], [5, 1], [3, 5], [2, 4]],
+                      np.int32)
+    return dict(q_rot=jnp.asarray(q_rot), centers_rot=jnp.asarray(
+        centers_rot), pq_centers=jnp.asarray(books),
+        codes=jnp.asarray(codes), code_norms=jnp.asarray(norms),
+        lists_indices=jnp.asarray(ids.astype(np.int32)),
+        probes=jnp.asarray(probes)), sizes
+
+
+class TestPqExtents:
+    """The code scan decodes each cell only up to its list's extent, in
+    chunks, and skips cells past it and lists no query probes. Its
+    answers must be bit-identical to the same kernels run over every
+    row (extents forced past the padded length)."""
+
+    K = 8
+
+    @staticmethod
+    def _force_split(monkeypatch, max_list, cap, want):
+        """Shrink the VMEM budget until the code scan splits each list
+        into ``want`` sub-lists (``test_vmem_split_path_agrees``'s
+        lever); returns ``(split, sub_ml, chunk)``."""
+        from raft_tpu.ops import pallas_ivf_scan as pis
+        lim = pis._VMEM_LIMIT
+        while True:
+            geo = pis._pq_cells(max_list, 128, cap, 8, 32, 32,
+                                jnp.bfloat16)
+            if geo[0] >= want:
+                return geo
+            lim //= 2
+            monkeypatch.setattr(pis, "_VMEM_LIMIT", lim)
+
+    @pytest.mark.parametrize("fused", [True, False])
+    @pytest.mark.parametrize("split", [1, 2])
+    @pytest.mark.parametrize("per_cluster", [False, True])
+    @pytest.mark.parametrize("metric", ["l2", "ip"])
+    def test_bit_identical_to_every_row(self, metric, per_cluster, split,
+                                        fused, monkeypatch):
+        from raft_tpu.ops import pallas_ivf_scan as pis
+        args, sizes = _pq_layout(per_cluster)
+        cap = _ivf_scan.probe_cap(args["probes"], len(sizes))
+        geo = self._force_split(monkeypatch, 2048, cap, split)
+        assert geo[0] == split and geo[2] < geo[1]   # chunks per cell
+        # the extents hold every case: hole scanned, unprobed lists 0
+        lay = pis._Layout(args["probes"], len(sizes), 2048, cap, 128,
+                          self.K)
+        ext = np.asarray(pis._scan_extents(args["lists_indices"],
+                                           lay.qmap))
+        np.testing.assert_array_equal(ext, [0, 300, 2048, 200, 3, 1300,
+                                            0, 0])
+        assert 300 % geo[2] and 1300 % geo[2]
+        scan = functools.partial(pis.ivf_pq_code_scan_pallas, k=self.K,
+                                 cap=cap, metric=metric,
+                                 per_cluster=per_cluster, fused=fused)
+        d_e, i_e = scan(**args)
+        monkeypatch.setattr(
+            pis, "_scan_extents",
+            lambda ids, qmap: jnp.full((ids.shape[0],), 1 << 30, jnp.int32))
+        d_a, i_a = scan(**args)
+        i_e, i_a = np.asarray(i_e), np.asarray(i_a)
+        np.testing.assert_array_equal(i_e, i_a)
+        np.testing.assert_array_equal(np.asarray(d_e), np.asarray(d_a))
+        # the sentinel: query 0 has three real rows for k 8
+        assert (i_e[0] >= 0).sum() == 3 and (i_e[0, 3:] == -1).all()
+        assert (i_e[1:] >= 0).all()
+        assert not np.isin(i_e, np.arange(3 * 2048 + 50,
+                                          3 * 2048 + 60)).any()
+
+
+class TestPqDecodedShare:
+    """``raft.ivf_scan.pq.decoded_share``: the share of the stored code
+    rows the scan decodes at full batches — each list's extent rounded
+    up to the chunk, over ``n_cells · sub_ml``."""
+
+    def test_hand_built_layout(self):
+        from raft_tpu.ops import pallas_ivf_scan as pis
+        args, _ = _pq_layout(False)
+        split, sub_ml, chunk = pis._pq_cells(2048, 128, 3, 8, 32, 32,
+                                             jnp.bfloat16)
+        assert (split, sub_ml, chunk) == (1, 2048, 256)
+        got = pis.pq_decoded_share(args["lists_indices"], 8, 3, 0, 8, 32,
+                                   32, jnp.bfloat16)
+        # extents 0, 300, 2048, 200 (hole inside), 3, 1300, 400, 400
+        # → decoded 0 + 512 + 2048 + 256 + 256 + 1536 + 512 + 512
+        assert got == 5632 / (8 * 2048)
+
+    def test_plan_build_sets_gauge(self, monkeypatch):
+        if not obs.enabled():
+            pytest.skip("metrics disabled (RAFT_TPU_METRICS=0)")
+        from raft_tpu.ops import pallas_ivf_scan as pis
+        x, _ = make_blobs(n_samples=3000, n_features=32, centers=20,
+                          cluster_std=3.0, seed=3)
+        idx = ivf_pq.build(jnp.asarray(np.asarray(x)), ivf_pq.IndexParams(
+            n_lists=16, kmeans_n_iters=4, pq_dim=8))
+        q = jnp.asarray(np.asarray(x)[:16])
+        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
+        sp = ivf_pq.SearchParams(n_probes=4, scan_mode="codes")
+        p = plan.build_plan(idx, q, 8, sp, warm=False)
+        got = obs.snapshot()["gauges"]["raft.ivf_scan.pq.decoded_share"]
+        ids = np.asarray(idx.lists_indices)
+        ml = ids.shape[1]
+        ext = np.where(ids >= 0, np.arange(1, ml + 1), 0).max(axis=1)
+        split, sub_ml, chunk = pis._pq_cells(
+            ml, pis._pq_bins(0, 8, ml), p.cap, 8, idx.pq_centers.shape[1],
+            idx.rot_dim, sp.lut_dtype)
+        want = (-(-ext // chunk) * chunk).sum() / (ids.shape[0] * split
+                                                    * sub_ml)
+        assert 0.0 < want <= 1.0
+        assert got == want
+
+
 class TestPlanRoutesFused:
     """Acceptance: SearchPlan / PlanLadder route through the fused
     kernel with zero steady-state compiles — asserted from the

@@ -124,6 +124,51 @@ def test_ivf_pq_fused_code_scan(compiled, k):
     assert "tpu_custom_call" in text
 
 
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, sub-jaxprs (jit, loops) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else [p]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_ivf_pq_code_scan_extents(compiled, fused):
+    """The served IVF-PQ cell's code scan: 2048 lists of max_list 3600,
+    pq_dim 64 at 256 codes, k 256 at bins 128, 128 probes. One int32
+    extent per list is scalar-prefetched (the custom call's first
+    operand), and each cell's chunk loop (a dynamic trip count) reads
+    its code, norm and id blocks at dynamic lane offsets."""
+    max_list, pq_dim, probes, cap = 3600, 64, 128, 72
+    shapes = (QUERIES, CENTERS, ((pq_dim, 256, DIM // pq_dim), F32),
+              ((N_LISTS, max_list, pq_dim), jnp.uint8),
+              ((N_LISTS, max_list), F32), ((N_LISTS, max_list), I32),
+              ((BATCH, probes), I32))
+
+    def scan(q, c, books, codes, norms, ids, pr):
+        return ivf_scan.ivf_pq_code_scan_pallas(
+            q, c, books, codes, norms, ids, pr, 256, cap, bins=128,
+            fused=fused)
+
+    closed = jax.make_jaxpr(scan)(*[jax.ShapeDtypeStruct(*s)
+                                    for s in shapes])
+    # the code scan is the one kernel with a scalar-prefetched operand
+    # (the unfused tier's merge adds a select_k kernel)
+    kernels = [e for e in _eqns(closed.jaxpr)
+               if e.primitive.name == "pallas_call"
+               and e.params["grid_mapping"].num_index_operands == 1]
+    assert len(kernels) == 1
+    assert "while" in {e.primitive.name
+                       for e in _eqns(kernels[0].params["jaxpr"])}
+    text = compiled(scan, *shapes)
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert call and f"operand_layout_constraints={{s32[{N_LISTS}]{{0}}, " \
+        in call[0]
+
+
 def test_ivf_bq_fused_scan(compiled):
     text = compiled(
         lambda q, c, bits, n2, sc, ids, probes: ivf_scan.ivf_bq_scan_pallas(

@@ -86,6 +86,9 @@ REQUIRED_NAMES = (
     # flat plans whose list scan completes a partial last bins window
     # in VMEM instead of padding the lists in HBM
     "raft.ivf_scan.ragged_tail.total",
+    # share of the stored PQ code rows the code scan decodes (each list
+    # up to its last row, in whole chunks), set per PQ plan build
+    "raft.ivf_scan.pq.decoded_share",
     # sharded/streaming build instruments (ISSUE 4): per-family sharded
     # build counters and the streaming ingestion counters — the
     # sharded_build_s bench rows and the build dashboards key on these
