@@ -104,7 +104,7 @@ class CompileClock:
 
 class Phase:
     """Times one phase and gathers its facts: wall and compile seconds,
-    counter deltas, the tier ladder state, peak device bytes."""
+    counter deltas, peak device bytes."""
 
     def __init__(self, name: str, clock: CompileClock):
         self.name = name
@@ -121,7 +121,6 @@ class Phase:
         if exc_type is not None:
             return
         from raft_tpu import obs
-        from raft_tpu.ops import compile_budget
         t1 = time.perf_counter()
         wall = t1 - self._t0
         compile_s = self.clock.seconds(self._t0, t1)
@@ -132,7 +131,6 @@ class Phase:
                  if k.startswith("raft.compile_cache.event{")}
         emit(phase=self.name, wall_s=wall, compile_s=compile_s,
              execute_s=wall - compile_s, **self.facts,
-             tiers=compile_budget.snapshot(),
              peak_bytes_in_use=peak_bytes(), compile_cache=cache)
 
     def count(self, name: str) -> float:

@@ -176,10 +176,9 @@ def compile_mutate_program(index, rep_queries, nq: int, k: int, params,
     zero-steady-state-compile assertion reads the same counters as the
     immutable serving tier."""
     import numpy as np
-    from raft_tpu.neighbors import _ivf_scan
     from raft_tpu.neighbors import plan as plan_mod
 
-    family, builder = plan_mod._resolve_builder(index)
+    family, program = plan_mod._resolve_builder(index)
     q = np.asarray(rep_queries, np.float32)
     expects(q.ndim == 2 and q.shape[1] == index.dim,
             "mutate: rep_queries must be (nq, dim=%d), got %s",
@@ -187,28 +186,23 @@ def compile_mutate_program(index, rep_queries, nq: int, k: int, params,
     reps = -(-nq // q.shape[0])
     q = np.tile(q, (reps, 1))[:nq]
     k_main = k + max(0, int(slack))
-    make, n_probes, kind, use_pallas_coarse = builder(index, k_main,
-                                                      params)
-    _ivf_scan.count_coarse_fallback(n_probes, use_pallas_coarse)
     metric = index.metric
     obs.counter("raft.plan.cache.misses").inc()
     obs.counter("raft.plan.build.total").inc()
     with obs.timed("raft.mutate.plan.build", family=family):
-        cap = _ivf_scan.resolve_cap(index.cap_cache, jnp.asarray(q),
-                                    index.centers, params, n_probes,
-                                    index.n_lists, kind=kind,
-                                    use_pallas=use_pallas_coarse)
-        fn_main, operands, host_epilogue, _key_bits = make(nq, cap)
-        expects(host_epilogue is None,
+        r = plan_mod.resolve_cap(plan_mod.route(index, nq, k_main, params),
+                                 index, jnp.asarray(q), params)
+        expects(not r.host_tail,
                 "mutate: the wrapped %s plan needs a host-side rescore "
                 "epilogue (raw corpus off-device) — mutable serving "
                 "requires a sync-free plan (keep_raw=False, or device "
                 "rescore)", family)
+        operands = plan_mod._operands(r, index)
         n_ops = len(operands)
 
         def fused(q_in, *ops):
             core, (dd, dn, di, tw) = ops[:n_ops], ops[n_ops:]
-            d, i = fn_main(q_in, *core)
+            d, i = program(q_in, *core, r=r)
             ds = delta_scores(q_in, dd, dn, di, metric)
             return mutate_tail(d, i.astype(jnp.int32), ds, di, tw, k,
                                metric)
@@ -222,7 +216,7 @@ def compile_mutate_program(index, rep_queries, nq: int, k: int, params,
             *_delta_structs(delta_cap, index.dim, tomb_words)).compile()
         # compile-time ledger (resource profiler): idle-chip seconds
         profiler.note_compile("mutate", time.perf_counter() - t_c0)
-    return MutateExecutable(executable, operands, nq, k, n_probes, cap,
+    return MutateExecutable(executable, operands, nq, k, r.n_probes, r.cap,
                             delta_cap, tomb_words)
 
 

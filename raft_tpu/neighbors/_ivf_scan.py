@@ -20,7 +20,7 @@ label); the TPU-native version inverts the map outright:
 
 Worth it when the reuse factor ``nq·n_probes / n_lists`` is high; the
 probe-major scan stays the right call for small/online batches (it only
-touches probed lists). ``search()`` picks automatically.
+touches probed lists). ``plan.route`` picks the order.
 """
 
 from __future__ import annotations
@@ -483,10 +483,10 @@ def fused_list_search(queries, centers, data, norms, ids, scale, *,
     round-trips (``ivf_flat_search.cuh:1057``). ``lc`` (static):
     kernel lists-per-grid-cell, 0 = auto — resolved by callers via
     ``pallas_ivf_scan.lc_mode()`` outside jit so the cache keys on it.
-    ``fused`` (static, ``pallas_ivf_scan.fused_mode()`` resolved by
-    callers likewise): route the fine phase through the single-
-    pallas_call scan+select kernel — the top-k state stays resident in
-    VMEM and the scan → gather → select_k chain disappears (ISSUE 7)."""
+    ``fused`` (static, set by the route for k ≤ 256): route the fine
+    phase through the single-pallas_call scan+select kernel — the
+    top-k state stays resident in VMEM and the scan → gather →
+    select_k chain disappears."""
     with jax.named_scope("raft.plan.coarse"):
         probes = coarse_probes(queries, centers, n_probes, kind=kind,
                                use_pallas=use_pallas)

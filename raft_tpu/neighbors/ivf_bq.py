@@ -412,9 +412,9 @@ def _fused_bq_search_pallas(queries, centers, centers_rot, rot, bits,
     straight from HBM — 8× less scan bandwidth than the XLA tier's
     materialized decode tiles. ``gather`` is the RAFT_TPU_GATHER
     strategy resolved OUTSIDE jit (the _ivf_scan contract); ``lc``
-    likewise (``pallas_ivf_scan.lc_mode``), 0 = auto; ``fused``
-    (``pallas_ivf_scan.fused_mode``) routes the fine phase through the
-    single-pallas_call scan+select kernel (ISSUE 7)."""
+    likewise (``pallas_ivf_scan.lc_mode``), 0 = auto; ``fused`` routes
+    the fine phase through the single-pallas_call scan+select kernel
+    (the route takes it for kk ≤ 256)."""
     from raft_tpu.neighbors import _ivf_scan as S
     from raft_tpu.ops.pallas_ivf_scan import ivf_bq_scan_pallas
     probes = S.coarse_probes(queries, centers, n_probes, kind=kind,
@@ -424,17 +424,6 @@ def _fused_bq_search_pallas(queries, centers, centers_rot, rot, bits,
                               ids, probes, kk, cap, bins=bins,
                               gather=gather, metric=kind, lc=lc,
                               fused=fused)
-
-
-def _resolve(index: Index, queries, params: SearchParams,
-             n_probes: int, use_pallas: bool, kind: str = "l2") -> int:
-    from raft_tpu.neighbors import _ivf_scan as S
-    # use_pallas/kind must match the serving path's coarse selection —
-    # a tie resolved differently could push a list past the measured
-    # cap and silently shed probes (resolve_cap docstring)
-    return S.resolve_cap(index.cap_cache, queries, index.centers,
-                         params, n_probes, index.n_lists, kind=kind,
-                         use_pallas=use_pallas)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "kind"))
@@ -554,129 +543,29 @@ def search(index: Index, queries, k: int,
 
 def _search_spanned(index: Index, queries, k: int, params, res, sp
                     ) -> Tuple[jax.Array, jax.Array]:
+    from raft_tpu.neighbors import plan
+    from raft_tpu.neighbors.ann_types import (MAX_QUERY_BATCH,
+                                              batched_search)
     q = as_array(queries).astype(jnp.float32)
     sp.set_attr("nq", int(q.shape[0]))
     expects(q.shape[1] == index.dim, "ivf_bq.search: dim mismatch")
-    from raft_tpu.neighbors.ann_types import (MAX_QUERY_BATCH,
-                                              batched_search)
     if q.shape[0] > MAX_QUERY_BATCH:
         # reference batching loop (ivf_pq_search.cuh:1234 role): bounds
         # the inverted-table width (cap ≤ nq) and reuses one compiled
         # shape per batch
         return batched_search(
             lambda qb: search(index, qb, k, params, res=res), q)
-    from raft_tpu.neighbors.ivf_flat import _metric_kind
+    # rescore_factor shapes the DEVICE phase (kk = factor·k candidates)
+    # whether or not raw vectors exist — so an estimator-only index runs
+    # the same compiled search as the rescored one, returning the
+    # estimator top-k
+    r = plan.route(index, q.shape[0], k, params)
     # per-batch telemetry (the batched path recurses per sub-batch)
     obs.counter("raft.ivf_bq.search.queries").inc(q.shape[0])
     obs.histogram("raft.ivf_bq.search.batch_size",
                   buckets=obs.SIZE_BUCKETS).observe(q.shape[0])
-    kind = _metric_kind(index.metric)
-    if index.metric == DistanceType.CosineExpanded:
-        q = q / jnp.maximum(jnp.linalg.norm(q, axis=1, keepdims=True),
-                            1e-30)
-    n_probes = min(params.n_probes, index.n_lists)
-    # mirror the n_probes/probe_cap validation style: a negative value
-    # would bypass the auto-bins branch ('or' catches only 0) and fail
-    # deep in the scan with an opaque reshape error (ADVICE r3 #4)
-    expects(params.scan_bins >= 0,
-            "ivf_bq.search: scan_bins must be >= 0 (0 = auto), got %d",
-            params.scan_bins)
-    expects(params.rescore_factor >= 0,
-            "ivf_bq.search: rescore_factor must be >= 0, got %d",
-            params.rescore_factor)
-    expects(params.rescore_on_device in ("auto", "always", "never"),
-            "ivf_bq.search: rescore_on_device: want auto|always|never,"
-            " got %r", params.rescore_on_device)
-    rescore = params.rescore_factor > 0 and index.raw is not None
-    # rescore_factor shapes the DEVICE phase (candidate count) whether
-    # or not raw vectors exist — so an estimator-only index (or a bench
-    # chaining the device program) runs the same compiled search as the
-    # rescored one; without raw the estimator top-k is returned.
-    # No clamp to index.size: merge_candidates pads short candidate
-    # sets, preserving the (nq, k) output contract of the other indexes.
-    kk = max(params.rescore_factor, 1) * k
-    from raft_tpu.ops.dispatch import pallas_enabled
-    use_pallas = pallas_enabled()
-    cap = _resolve(index, q, params, n_probes, use_pallas, kind=kind)
-    max_list = index.bits.shape[1]
-    # auto bins: a 32x-oversampled GLOBAL candidate pool (n_probes·bins
-    # ≈ 32·kk, floor 128/list) instead of the flat/pq per-list 4·k rule
-    # — kk here is rescore_factor·k, and scaling bins with it directly
-    # would blow the merge width (64 probes × 4·256 bins = 32k-wide
-    # select) and the candidate blocks (~0.5 GB at the 500k bench
-    # point). Safe because bins are STRIDED in both tiers
-    # (binned_partial_topk / the kernels): narrow bins no longer
-    # collide dataset-adjacent true neighbors — measured 0.920 vs the
-    # contiguous formulation's 0.868 recall@10 at 30k×64/128-list with
-    # this same pool size
-    bins = min(params.scan_bins
-               or max(128, (32 * kk) // max(n_probes, 1)), max_list)
-    # chunk bound: BOTH the (chunk, cap, max_list) estimator block
-    # (the _ivf_scan._chunk_size budget every XLA-tier search uses)
-    # AND the (chunk, max_list, dim) decode tile must stay modest
-    from raft_tpu.neighbors._ivf_scan import (_chunk_size,
-                                              largest_divisor_at_most)
-    chunk = min(  # both are divisors of n_lists, so their min is too
-        _chunk_size(index.n_lists, cap, max_list),
-        largest_divisor_at_most(
-            index.n_lists,
-            max(1, (64 << 20) // max(1, max_list * index.dim * 2))))
     obs.histogram("raft.ivf_bq.search.n_probes",
-                  buckets=obs.SIZE_BUCKETS).observe(n_probes)
-    sp.set_attrs(n_probes=n_probes, rescore=rescore)
-    from raft_tpu.neighbors._ivf_scan import count_coarse_fallback
-    count_coarse_fallback(n_probes, use_pallas)
+                  buckets=obs.SIZE_BUCKETS).observe(r.n_probes)
+    sp.set_attrs(n_probes=r.n_probes, rescore=r.rescoring)
     with obs.timed("raft.ivf_bq.search"):
-        from raft_tpu.ops.compile_budget import run_tiers
-        from raft_tpu.ops.pallas_ivf_scan import fused_mode, lc_mode
-
-        def pallas_tier(lc, fz: bool = False):
-            from raft_tpu.neighbors._ivf_scan import gather_mode
-            return lambda: _fused_bq_search_pallas(
-                q, index.centers, index.centers_rot,
-                index.rotation_matrix, index.bits, index.norms2,
-                index.scales, index.lists_indices, kk=kk, bins=bins,
-                n_probes=n_probes, cap=cap, gather=gather_mode(),
-                kind=kind, lc=lc, fused=fz)
-
-        # compile-budget ladder (ops/compile_budget.py): fused
-        # scan+select (ONE pallas_call fine phase, ISSUE 7) → Pallas
-        # unpack scan → Pallas grid-per-list → the XLA decode-tile
-        # formulation (proven-compilable tail)
-        tiers = []
-        fused_on = use_pallas and fused_mode() and kk <= 256
-        if fused_on:
-            obs.counter("raft.ivf_scan.fused.total",
-                        family="ivf_bq").inc()
-            obs.counter("raft.ivf_scan.fused.queries").inc(q.shape[0])
-            lc0f = lc_mode()
-            tiers.append((f"pallas_fused_lc{lc0f or 'auto'}",
-                          pallas_tier(lc0f, True)))
-        if use_pallas:
-            from raft_tpu.ops.pallas_ivf_scan import _pick_lc
-            lc0 = lc_mode()
-            tiers.append((f"pallas_lc{lc0 or 'auto'}", pallas_tier(lc0)))
-            # no lc=1 rung when the first tier already resolves to it
-            # (see ivf_flat.search)
-            auto_lc = _pick_lc(index.n_lists, max_list, cap,
-                               index.dim, 2)
-            if lc0 != 1 and not (lc0 == 0 and auto_lc == 1):
-                tiers.append(("pallas_lc1", pallas_tier(1)))
-        tiers.append(("xla_decode", lambda: _fused_bq_search(
-            q, index.centers, index.centers_rot,
-            index.rotation_matrix, index.bits, index.norms2,
-            index.scales, index.lists_indices, kk=kk, bins=bins,
-            n_probes=n_probes, cap=cap, chunk=chunk, dim=index.dim,
-            kind=kind)))
-        # key covers every program-shaping static (see ivf_flat.search)
-        from raft_tpu.neighbors._ivf_scan import gather_mode
-        shape_key = (f"ivf_bq[{q.shape[0]}x{index.dim},kk={kk},"
-                     f"p={n_probes},cap={cap},L={index.n_lists},"
-                     f"bins={bins},{kind},g={gather_mode()},"
-                     f"fz={fused_on}]")
-        d_est, ids = run_tiers(shape_key, tiers)
-        raw_dev = (resolve_raw_device(index, params.rescore_on_device)
-                   if rescore else None)
-        return finish_search(d_est, ids, index.raw, q, k,
-                             metric=index.metric, rescore=rescore,
-                             raw_dev=raw_dev)
+        return plan.run(index, q, r, params)

@@ -432,128 +432,30 @@ def search(index: Index, queries, k: int,
 
 def _search_spanned(index: Index, queries, k: int, params, res, sp
                     ) -> Tuple[jax.Array, jax.Array]:
-    q = as_array(queries).astype(jnp.float32)
-    sp.set_attr("nq", int(q.shape[0]))
-    expects(q.shape[1] == index.dim, "ivf_flat.search: dim mismatch")
-    expects(params.scan_order in ("auto", "probe", "list"),
-            f"ivf_flat.search: unknown scan_order {params.scan_order!r}")
+    from raft_tpu.neighbors import plan
     from raft_tpu.neighbors.ann_types import (MAX_QUERY_BATCH,
                                               batched_search,
                                               pin_scan_order)
+    q = as_array(queries).astype(jnp.float32)
+    sp.set_attr("nq", int(q.shape[0]))
+    expects(q.shape[1] == index.dim, "ivf_flat.search: dim mismatch")
     if q.shape[0] > MAX_QUERY_BATCH:
         # reference search batching (ivf_pq_search.cuh:1234 role); pin
         # "auto" choices from the FULL query count first
         pinned = pin_scan_order(params, q.shape[0], index.n_lists)
         return batched_search(
             lambda qb: search(index, qb, k, pinned, res=res), q)
-    n_probes = min(params.n_probes, index.n_lists)
-    sp.set_attr("n_probes", n_probes)
+    r = plan.route(index, q.shape[0], k, params)
+    sp.set_attrs(n_probes=r.n_probes, order=r.order)
     # per-batch telemetry (the batched path recurses here per
     # sub-batch, so queries sum correctly across the split)
     obs.counter("raft.ivf_flat.search.queries").inc(q.shape[0])
     obs.histogram("raft.ivf_flat.search.batch_size",
                   buckets=obs.SIZE_BUCKETS).observe(q.shape[0])
     obs.histogram("raft.ivf_flat.search.n_probes",
-                  buckets=obs.SIZE_BUCKETS).observe(n_probes)
-    sqrt = index.metric in (DistanceType.L2SqrtExpanded,
-                            DistanceType.L2SqrtUnexpanded)
-    kind = _metric_kind(index.metric)
-    if index.metric == DistanceType.CosineExpanded:
-        q = q / jnp.maximum(jnp.linalg.norm(q, axis=1, keepdims=True),
-                            1e-30)
-    from raft_tpu.ops.dispatch import pallas_enabled
-    nq = q.shape[0]
-    # the XLA-tier list scan only has the l2 core; don't pay the coarse
-    # phase + probe_cap host sync just to fall through to probe-major
-    from raft_tpu.neighbors.ann_types import list_order_auto
-    use_list = ((pallas_enabled() or kind == "l2")
-                and (params.scan_order == "list"
-                     or (params.scan_order == "auto"
-                         and list_order_auto(nq, n_probes,
-                                             index.n_lists))))
-    sp.set_attr("order", "list" if use_list else "probe")
+                  buckets=obs.SIZE_BUCKETS).observe(r.n_probes)
     # RAII scope at the public search (the reference's nvtx range slot);
-    # covers both the list-major and probe-major paths — obs.timed opens
-    # the trace range and the order-labeled latency histogram together
-    with obs.timed("raft.ivf_flat.search",
-                   order="list" if use_list else "probe"):
-        if use_list:
-            from raft_tpu.neighbors import _ivf_scan
-            from raft_tpu.ops.compile_budget import run_tiers
-            from raft_tpu.ops.pallas_ivf_scan import fused_mode, lc_mode
-            use_pallas = pallas_enabled()
-            _ivf_scan.count_coarse_fallback(n_probes, use_pallas)
-            cap = _ivf_scan.resolve_cap(index.cap_cache, q,
-                                        index.centers, params, n_probes,
-                                        index.n_lists, kind=kind,
-                                        use_pallas=use_pallas)
-
-            def fused(pallas: bool, lc: int = 0, fz: bool = False):
-                return lambda: _ivf_scan.fused_list_search(
-                    q, index.centers, index.lists_data,
-                    index.lists_norms, index.lists_indices,
-                    jnp.float32(index.scale), k=k, n_probes=n_probes,
-                    cap=cap, bins=params.scan_bins, sqrt=sqrt,
-                    kind=kind, use_pallas=pallas,
-                    gather=_ivf_scan.gather_mode(),
-                    internal_dtype=params.internal_distance_dtype,
-                    lc=lc, fused=fz)
-
-            # compile-budget ladder, structurally simplest LAST (see
-            # ops/compile_budget.py): fused scan+select (ONE pallas_call
-            # fine phase, ISSUE 7) → Pallas kernel (auto or env lc) →
-            # Pallas grid-per-list (loop-free body) → XLA inverted scan
-            # (l2 core only) → probe-major eager scan (always
-            # compiles — small per-probe programs)
-            lc0 = lc_mode()
-            tiers = []
-            # the resident state keeps k on sublanes; past the select_k
-            # bound the merge rounds stop paying for themselves — the
-            # unfused tiers cover large k
-            fused_on = use_pallas and fused_mode() and k <= 256
-            if fused_on:
-                obs.counter("raft.ivf_scan.fused.total",
-                            family="ivf_flat").inc()
-                obs.counter("raft.ivf_scan.fused.queries").inc(nq)
-                tiers.append((f"pallas_fused_lc{lc0 or 'auto'}",
-                              fused(True, lc0, True)))
-            if use_pallas:
-                from raft_tpu.ops.pallas_ivf_scan import _pick_lc
-                tiers.append((f"pallas_lc{lc0 or 'auto'}",
-                              fused(True, lc0)))
-                # skip the lc=1 rung when the first tier already IS
-                # lc=1 (explicitly, or via the auto pick — approximated
-                # on unpadded shapes): re-submitting the same program
-                # would burn a second budget on a wedged service
-                auto_lc = _pick_lc(index.n_lists,
-                                   index.lists_data.shape[1], cap,
-                                   index.dim,
-                                   index.lists_data.dtype.itemsize)
-                if lc0 != 1 and not (lc0 == 0 and auto_lc == 1):
-                    tiers.append(("pallas_lc1", fused(True, 1)))
-            if kind == "l2":
-                tiers.append(("xla_inverted", fused(False)))
-            tiers.append(("probe_major", lambda: _search_impl(
-                q, index.centers, index.lists_data,
-                index.lists_indices, index.lists_norms,
-                jnp.float32(index.scale), k, n_probes, sqrt,
-                kind=kind)))
-            # the key must cover EVERY static arg that changes the
-            # compiled program — tier state shared across distinct
-            # programs would bypass the budget for never-compiled
-            # variants (r4 review finding)
-            shape_key = (f"ivf_flat[{nq}x{index.dim},k={k},"
-                         f"p={n_probes},cap={cap},L={index.n_lists},"
-                         f"ml={index.lists_data.shape[1]},"
-                         f"{kind},sqrt={sqrt},b={params.scan_bins},"
-                         f"g={_ivf_scan.gather_mode()},"
-                         f"idt={jnp.dtype(params.internal_distance_dtype).name},"
-                         f"dt={index.lists_data.dtype.name},"
-                         f"fz={fused_on}]")
-            d, i = run_tiers(shape_key, tiers)
-        else:
-            d, i = _search_impl(q, index.centers, index.lists_data,
-                                index.lists_indices, index.lists_norms,
-                                jnp.float32(index.scale), k, n_probes,
-                                sqrt, kind=kind)
-    return _postprocess(d, index.metric), i
+    # obs.timed opens the trace range and the order-labeled latency
+    # histogram together
+    with obs.timed("raft.ivf_flat.search", order=r.order):
+        return plan.run(index, q, r, params)

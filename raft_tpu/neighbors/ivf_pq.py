@@ -946,9 +946,8 @@ def _fused_code_search(q, centers, centers_rot, rot, pq_centers, codes,
             fused=fused)
 
 
-# guards the lazy reconstruction-cache materialization: ladder
-# fallback tiers can run on a compile-budget thread concurrently with
-# the inline tail (see _ensure_decoded)
+# guards the lazy reconstruction-cache materialization against
+# concurrent searches (see _ensure_decoded)
 _DECODE_LOCK = threading.Lock()
 
 
@@ -964,19 +963,19 @@ def _base_code_norms(index: Index):
     return index.code_norms
 
 
-def _ensure_code_norms(index: Index, params: "SearchParams",
-                       per_cluster: bool, kind: str):
+def _ensure_code_norms(index: Index, lut_dtype, kind: str):
     """Code norms matched to the LUT tier the code scan decodes: the
     fp8 tier's L2 epilogue must use norms of the fp8-QUANTIZED books
     (reference fp_8bit tier — the LUT there carries the same
     quantization in its distance terms); every other tier uses the
-    exact build-time norms. Shared by ``search`` and the plan layer."""
-    if (jnp.dtype(params.lut_dtype) == jnp.dtype(jnp.float8_e4m3fn)
+    exact build-time norms."""
+    if (jnp.dtype(lut_dtype) == jnp.dtype(jnp.float8_e4m3fn)
             and kind == "l2"):
         if index.code_norms_fp8 is None:
             books8 = index.pq_centers.astype(
                 jnp.float8_e4m3fn).astype(jnp.float32)
-            fn = (_code_norms_per_cluster if per_cluster
+            fn = (_code_norms_per_cluster
+                  if index.codebook_kind == CodebookGen.PER_CLUSTER
                   else _code_norms)
             index.code_norms_fp8 = fn(index.codes, books8,
                                       index.lists_indices)
@@ -987,12 +986,11 @@ def _ensure_code_norms(index: Index, params: "SearchParams",
 def _ensure_decoded(index: Index, per_cluster: bool) -> None:
     """Materialize the bf16 reconstruction cache lazily.
 
-    Lock: ladder fallback tiers may run in a compile-budget thread
-    while a later tier runs inline on the main thread — an unguarded
-    check-then-set would materialize the ~8× decoded cache TWICE
+    Lock: concurrent searches on one index must not run an unguarded
+    check-then-set — it would materialize the ~8× decoded cache TWICE
     (peak-HBM hazard) and race the index mutation (r4 review finding).
-    The decode programs are simple proven-compilable gathers, so
-    holding the lock across them is bounded in practice."""
+    The decode programs are simple gathers, so holding the lock across
+    them is bounded in practice."""
     if index.decoded is not None and index.decoded_norms is not None:
         return
     with _DECODE_LOCK:
@@ -1022,192 +1020,34 @@ def search(index: Index, queries, k: int,
 
 def _search_spanned(index: Index, queries, k: int, params, res, sp
                     ) -> Tuple[jax.Array, jax.Array]:
-    q = as_array(queries).astype(jnp.float32)
-    sp.set_attr("nq", int(q.shape[0]))
-    expects(q.shape[1] == index.dim, "ivf_pq.search: dim mismatch")
-    expects(params.scan_mode in ("auto", "codes", "reconstruct", "lut"),
-            f"ivf_pq.search: unknown scan_mode {params.scan_mode!r}")
+    from raft_tpu.neighbors import plan
     from raft_tpu.neighbors.ann_types import (MAX_QUERY_BATCH,
                                               batched_search,
                                               pin_scan_order)
+    q = as_array(queries).astype(jnp.float32)
+    sp.set_attr("nq", int(q.shape[0]))
+    expects(q.shape[1] == index.dim, "ivf_pq.search: dim mismatch")
     if q.shape[0] > MAX_QUERY_BATCH:
         # reference batching loop (ivf_pq_search.cuh:1251/:1234); pin
         # "auto" choices from the FULL query count first
         import dataclasses
-        mode = params.scan_mode
-        if mode == "auto":
-            from raft_tpu.ops.dispatch import pallas_enabled
-            mode = "codes" if pallas_enabled() else "reconstruct"
+        mode = plan.route(index, q.shape[0], k, params).scan_mode
         pinned = pin_scan_order(dataclasses.replace(params, scan_mode=mode),
                                 q.shape[0], index.n_lists)
         return batched_search(
             lambda qb: search(index, qb, k, pinned, res=res), q)
-    expects(params.scan_order in ("auto", "probe", "list"),
-            f"ivf_pq.search: unknown scan_order {params.scan_order!r}")
-    n_probes = min(params.n_probes, index.n_lists)
-    sp.set_attr("n_probes", n_probes)
+    r = plan.route(index, q.shape[0], k, params)
+    sp.set_attrs(n_probes=r.n_probes, mode=r.scan_mode,
+                 rescoring=r.rescoring)
     # per-batch telemetry (the batched path recurses here per
     # sub-batch, so queries sum correctly across the split)
     obs.counter("raft.ivf_pq.search.queries").inc(q.shape[0])
     obs.histogram("raft.ivf_pq.search.batch_size",
                   buckets=obs.SIZE_BUCKETS).observe(q.shape[0])
     obs.histogram("raft.ivf_pq.search.n_probes",
-                  buckets=obs.SIZE_BUCKETS).observe(n_probes)
-    sqrt = index.metric in (DistanceType.L2SqrtExpanded,
-                            DistanceType.L2SqrtUnexpanded)
-    from raft_tpu.neighbors.ivf_flat import _metric_kind, _postprocess
-    kind = _metric_kind(index.metric)
-    per_cluster = index.codebook_kind == CodebookGen.PER_CLUSTER
-
-    # exact re-ranking (SearchParams.rescore_factor): the device phase
-    # returns kk = factor·k estimator candidates; the epilogue re-ranks
-    # them against the host raw corpus (ivf_bq.finish_search — shared
-    # so the exact-rescore semantics stay identical across families)
-    expects(params.rescore_factor >= 0,
-            "ivf_pq.search: rescore_factor must be >= 0")
-    expects(params.rescore_on_device in ("auto", "always", "never"),
-            "ivf_pq.search: rescore_on_device: want auto|always|never,"
-            " got %r", params.rescore_on_device)
-    rescoring = params.rescore_factor > 0 and index.raw is not None
-    kk = max(params.rescore_factor, 1) * k
-    # sqrt/output conventions move to the epilogue when it is not the
-    # legacy slice (finish_search applies them itself)
-    dev_sqrt = sqrt if (kk == k and not rescoring) else False
-
-    def _epilogue(d, i):
-        if kk == k and not rescoring:
-            return _postprocess(d, index.metric), i
-        from raft_tpu.neighbors.ivf_bq import (finish_search,
-                                               resolve_raw_device)
-        raw_dev = (resolve_raw_device(index, params.rescore_on_device)
-                   if rescoring else None)
-        return finish_search(d, i, index.raw, q, k, metric=index.metric,
-                             rescore=rescoring, raw_dev=raw_dev)
-
-    # candidate bins: when rescoring widens kk, the per-list 4·k auto
-    # rule (pallas_ivf_scan._Layout) would blow the merge width
-    # (n_probes·4·kk-wide selects, ~0.5 GB candidate blocks at the
-    # bench point) — switch to the ivf_bq global-pool rule: a
-    # 32×-oversampled pool spread over the probed lists, floor 128
-    bins = params.scan_bins
-    if bins == 0 and kk > k:
-        max_list = index.codes.shape[1]
-        bins = min(max(128, (32 * kk) // max(n_probes, 1)), max_list)
-
-    scan_mode = params.scan_mode
-    if scan_mode == "auto":
-        from raft_tpu.ops.dispatch import pallas_enabled
-        scan_mode = "codes" if pallas_enabled() else "reconstruct"
-    sp.set_attrs(mode=scan_mode, rescoring=rescoring)
-    expects(jnp.dtype(params.lut_dtype) in
-            (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16),
-             jnp.dtype(jnp.float8_e4m3fn)),
-            "ivf_pq: lut_dtype must be float32|bfloat16|float8_e4m3fn")
-    # the fp8 tier only exists on the code-resident scan: reject rather
-    # than silently measure the full-precision reconstruct/lut paths
-    expects(jnp.dtype(params.lut_dtype) != jnp.dtype(jnp.float8_e4m3fn)
-            or scan_mode == "codes",
-            "ivf_pq: lut_dtype=float8_e4m3fn requires scan_mode='codes' "
-            "(resolved scan_mode is %r)", scan_mode)
-    def _recon_list():
-        """Reconstruct-cache fused list scan (l2 core only)."""
-        from raft_tpu.neighbors import _ivf_scan
-        _ensure_decoded(index, per_cluster)
-        cap = _ivf_scan.resolve_cap(index.cap_cache, q, index.centers,
-                                    params, n_probes, index.n_lists)
-        # lists hold decoded rotated residuals: the scan offsets
-        # each list's queries by its rotated center so the einsum
-        # scores ||(q_rot - c_l) - decoded||²
-        return _ivf_scan.fused_reconstruct_list_search(
-            q, index.centers, index.centers_rot,
-            index.rotation_matrix, index.decoded,
-            index.decoded_norms, index.lists_indices, k=kk,
-            n_probes=n_probes, cap=cap, bins=bins,
-            sqrt=dev_sqrt)
-
-    def _recon_probe():
-        """Probe-major reconstruct scan — small per-probe programs,
-        the always-compilable tail of the codes ladder."""
-        _ensure_decoded(index, per_cluster)
-        return _search_impl_reconstruct(
-            q, index.centers, index.centers_rot,
-            index.rotation_matrix, index.decoded,
-            index.decoded_norms, index.lists_indices,
-            kk, n_probes, dev_sqrt, kind=kind)
-
-    if scan_mode == "codes":
-        from raft_tpu.neighbors import _ivf_scan
-        from raft_tpu.ops.compile_budget import run_tiers
-        from raft_tpu.ops.pallas_ivf_scan import fused_mode
-        _ivf_scan.count_coarse_fallback(n_probes, True)
-        # RAII scope (reference nvtx range in search, ivf_pq_search.cuh:
-        # 1263), exception-safe; obs.timed opens the trace range AND the
-        # wall-time histogram under one taxonomy name
-        with obs.timed("raft.ivf_pq.search", mode="codes"):
-            cap = _ivf_scan.resolve_cap(index.cap_cache, q,
-                                        index.centers, params, n_probes,
-                                        index.n_lists, kind=kind,
-                                        use_pallas=True)
-            code_norms = _ensure_code_norms(index, params, per_cluster,
-                                            kind)
-
-            def codes_tier(fz: bool = False):
-                return lambda: _fused_code_search(
-                    q, index.centers, index.centers_rot,
-                    index.rotation_matrix, index.pq_centers, index.codes,
-                    code_norms, index.lists_indices, k=kk,
-                    n_probes=n_probes, cap=cap, bins=bins,
-                    sqrt=dev_sqrt, kind=kind, lut_dtype=params.lut_dtype,
-                    internal_dtype=params.internal_distance_dtype,
-                    per_cluster=per_cluster,
-                    gather=_ivf_scan.gather_mode(), fused=fz)
-
-            # compile-budget ladder (ops/compile_budget.py): the fused
-            # scan+select code kernel (ONE pallas_call fine phase,
-            # ISSUE 7), the unfused Pallas code scan, then the
-            # reconstruct-cache XLA formulations (which trade the
-            # codes' memory footprint for a proven program shape).
-            # NOTE the fallbacks score bf16 reconstructions — same
-            # recall class, not bit-identical.
-            fused_on = fused_mode() and kk <= 256
-            tiers = []
-            if fused_on:
-                obs.counter("raft.ivf_scan.fused.total",
-                            family="ivf_pq").inc()
-                obs.counter("raft.ivf_scan.fused.queries").inc(
-                    q.shape[0])
-                tiers.append(("pallas_fused_codes", codes_tier(True)))
-            tiers.append(("pallas_codes", codes_tier()))
-            if kind == "l2":
-                tiers.append(("xla_reconstruct_list", _recon_list))
-            tiers.append(("reconstruct_probe_major", _recon_probe))
-            # key covers every program-shaping static (see
-            # ivf_flat.search)
-            shape_key = (f"ivf_pq[{q.shape[0]}x{index.dim},k={kk},"
-                         f"p={n_probes},cap={cap},L={index.n_lists},"
-                         f"pq={index.pq_dim}x{index.pq_bits}b,"
-                         f"{kind},sqrt={dev_sqrt},b={bins},"
-                         f"lut={jnp.dtype(params.lut_dtype).name},"
-                         f"idt={jnp.dtype(params.internal_distance_dtype).name},"
-                         f"pc={per_cluster},"
-                         f"g={_ivf_scan.gather_mode()},"
-                         f"fz={fused_on}]")
-            d, i = run_tiers(shape_key, tiers)
-        return _epilogue(d, i)
-    if scan_mode == "reconstruct":
-        with obs.timed("raft.ivf_pq.search", mode="reconstruct"):
-            nq = q.shape[0]
-            from raft_tpu.neighbors.ann_types import list_order_auto
-            use_list = (kind == "l2"
-                        and (params.scan_order == "list"
-                             or (params.scan_order == "auto"
-                                 and list_order_auto(nq, n_probes,
-                                                     index.n_lists))))
-            d, i = _recon_list() if use_list else _recon_probe()
-        return _epilogue(d, i)
-    with obs.timed("raft.ivf_pq.search", mode="lut"):
-        d, i = _search_impl(q, index.centers, index.centers_rot,
-                            index.rotation_matrix, index.pq_centers,
-                            index.codes, index.lists_indices, kk, n_probes,
-                            dev_sqrt, kind=kind, per_cluster=per_cluster)
-    return _epilogue(d, i)
+                  buckets=obs.SIZE_BUCKETS).observe(r.n_probes)
+    # RAII scope (reference nvtx range in search, ivf_pq_search.cuh:
+    # 1263), exception-safe; obs.timed opens the trace range AND the
+    # wall-time histogram under one taxonomy name
+    with obs.timed("raft.ivf_pq.search", mode=r.scan_mode):
+        return plan.run(index, q, r, params)

@@ -1,22 +1,27 @@
-"""Search plans: AOT-compiled, host-sync-free IVF serving.
+"""Search routes and plans: one scan route per IVF family.
 
-Why this layer exists: the last green TPU window measured IVF-Flat at
-9,769 QPS end-to-end against a 73,781 QPS chained marginal — a ~9 ms
-per-batch FIXED cost (host dispatch, cap measurement, tier routing,
-Python glue) swallowed 87% of the speedup the index should buy.
-TPU-KNN (arxiv 2206.14286) and the serving-kernel literature agree:
-TPU k-NN serving is dispatch-bound unless the whole query path is one
-compiled program that the host merely enqueues.
+Every IVF search takes its kernel choice from ONE place. :func:`route`
+turns (index shapes, nq, k, params, ``pallas_enabled()``) into a
+frozen :class:`Route`: list- or probe-major order, Pallas or XLA, the
+fused scan+select kernel or not, the IVF-PQ scan mode, bins, chunk,
+lc, gather, rescoring and where the raw corpus lives. The one device
+program :func:`_program` runs a route, which it takes as a static
+argument. The eager ``ivf_flat.search`` / ``ivf_pq.search`` /
+``ivf_bq.search`` call it through the process-wide jit cache
+(:func:`run`); :func:`build_plan` lowers and compiles the same
+function once, AOT, for the serving layer.
 
-A :class:`SearchPlan` is the serving-shape contract made explicit:
+Why plans exist: TPU k-NN serving is dispatch-bound unless the whole
+query path is one compiled program that the host merely enqueues
+(TPU-KNN, arxiv 2206.14286). A :class:`SearchPlan` is the
+serving-shape contract made explicit:
 
-* **AOT compile** — the full fused search (coarse GEMM + top-k, probe
+* **AOT compile** — the route's program (coarse GEMM + top-k, probe
   inversion, list scan, merge, metric postprocess, and — when the raw
   corpus is device-resident — the exact re-rank) is lowered and
   compiled ONCE at plan-build time via ``jax.jit(...).lower(...)
-  .compile()``, keyed by (index shapes, nq, k, n_probes, cap, dtypes).
-  Serving calls hand the executable its buffers; no tracing, no tier
-  ladder, no shape hashing on the hot path.
+  .compile()``, keyed by (nq, dim, route). Serving calls hand the
+  executable its buffers; no tracing or shape hashing on the hot path.
 * **No host syncs** — :func:`warmup` measures the inverted-table cap
   once from representative queries and prefills the index's
   ``cap_cache``, so ``_ivf_scan.resolve_cap`` never round-trips on the
@@ -25,21 +30,20 @@ A :class:`SearchPlan` is the serving-shape contract made explicit:
 * **Async pipelined batching** — :meth:`SearchPlan.search_batched`
   enqueues sub-batches back-to-back (donating the padded query buffers
   it creates on backends that support donation) and performs a single
-  terminal ``block_until_ready``; the dispatch-sync-dispatch loop of
-  the cold path disappears.
+  terminal ``block_until_ready``.
 
 Plans are cached on the index (``index.plan_cache``; hits/misses/
 evictions under ``raft.plan.cache.*``, LRU-bounded by
 ``RAFT_TPU_PLAN_CACHE_MAX`` — the serving shape ladder churns shapes
-routinely). The cold path — ``ivf_flat.search`` etc. — is
-unchanged and remains the flexible/debug entry; see
-docs/performance.md for the serving guide.
+routinely). See docs/performance.md for the serving guide.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Tuple
 
@@ -219,327 +223,340 @@ class SearchPlan:
 
 
 # ---------------------------------------------------------------------------
-# family builders: each returns (fn, operands, host_epilogue) where
-# ``fn(q, *operands) -> (d, i)`` is the pure jittable serving program
+# the route: every decision that shapes a family's device program
 # ---------------------------------------------------------------------------
 
+_SQRT_METRICS = (DistanceType.L2SqrtExpanded,
+                 DistanceType.L2SqrtUnexpanded)
 
-def _flat_builder(index, k: int, params):
-    from raft_tpu.neighbors import _ivf_scan
+
+@dataclass(frozen=True)
+class Route:
+    """One IVF search's kernel choice — hashable, so it is the static
+    argument of :func:`_program` and the identity of a plan."""
+
+    family: str              # "ivf_flat" | "ivf_pq" | "ivf_bq"
+    metric: DistanceType
+    kind: str                # "l2" | "ip" scoring core
+    n_probes: int
+    k: int
+    kk: int                  # candidates the scan returns (rescore·k)
+    order: str               # "list" (inverted table) | "probe"
+    pallas: bool             # Pallas coarse select_k + scan, else XLA
+    fused: bool              # scan+select kernel, top-k resident in VMEM
+    scan_mode: str = ""      # ivf_pq: "codes" | "reconstruct" | "lut"
+    bins: int = 0
+    chunk: int = 0           # ivf_bq XLA decode tile, lists per step
+    lc: int = 0              # Pallas lists per grid cell, 0 = auto
+    gather: str = "rows"
+    dev_sqrt: bool = False   # the scan itself takes the sqrt
+    rescoring: bool = False
+    raw_on_device: bool = False
+    per_cluster: bool = False
+    lut_dtype: Optional[str] = None       # dtype names: a cheap repr
+    internal_dtype: Optional[str] = None  # in every plan's span
+    cap: int = 0             # inverted-table width (list order only)
+
+    @property
+    def host_tail(self) -> bool:
+        """The exact rescore runs on the host (raw corpus off-device):
+        the program returns the estimator candidates."""
+        return self.rescoring and not self.raw_on_device
+
+
+def route(index, nq: int, k: int, params) -> Route:
+    """The route of ``nq`` queries at ``k`` — its cap still 0 (see
+    :func:`resolve_cap`). Rejects the parameters no route serves."""
+    from raft_tpu.neighbors import ivf_pq
+    from raft_tpu.neighbors._ivf_scan import gather_mode
     from raft_tpu.neighbors.ann_types import list_order_auto
-    from raft_tpu.neighbors.ivf_flat import (_metric_kind, _postprocess,
-                                             _search_impl)
-    from raft_tpu.ops.dispatch import pallas_enabled
-    from raft_tpu.ops.pallas_ivf_scan import fused_mode, lc_mode, ragged_tail
-
-    n_probes = min(params.n_probes, index.n_lists)
-    kind = _metric_kind(index.metric)
-    sqrt = index.metric in (DistanceType.L2SqrtExpanded,
-                            DistanceType.L2SqrtUnexpanded)
-    use_pallas = pallas_enabled()
-
-    def make(nq: int, cap: int):
-        use_list = ((use_pallas or kind == "l2")
-                    and (params.scan_order == "list"
-                         or (params.scan_order == "auto"
-                             and list_order_auto(nq, n_probes,
-                                                 index.n_lists))))
-        gather = _ivf_scan.gather_mode()
-        lc = lc_mode()
-        # fused scan+select tier (ISSUE 7): the plan compiles the ONE-
-        # pallas_call fine phase — zero new steady-state compiles, the
-        # ladder machinery rides the same build_plan path unchanged
-        use_fused = use_pallas and fused_mode() and k <= 256
-        if use_list and use_fused:
-            obs.counter("raft.ivf_scan.fused.total",
-                        family="ivf_flat").inc()
-        # the Pallas list scan reads the lists unpadded and completes a
-        # partial last bins window in VMEM
-        if use_list and use_pallas and ragged_tail(
-                index.lists_data.shape[1], params.scan_bins, k):
-            obs.counter("raft.ivf_scan.ragged_tail.total",
-                        family="ivf_flat").inc()
-
-        def fn(q, centers, data, norms, ids, scale):
-            if index.metric == DistanceType.CosineExpanded:
-                q = q / jnp.maximum(
-                    jnp.linalg.norm(q, axis=1, keepdims=True), 1e-30)
-            if use_list:
-                d, i = _ivf_scan.fused_list_search(
-                    q, centers, data, norms, ids, scale, k=k,
-                    n_probes=n_probes, cap=cap, bins=params.scan_bins,
-                    sqrt=sqrt, kind=kind, use_pallas=use_pallas,
-                    gather=gather,
-                    internal_dtype=params.internal_distance_dtype,
-                    lc=lc, fused=use_fused)
-            else:
-                d, i = _search_impl(q, centers, data, ids, norms, scale,
-                                    k, n_probes, sqrt, kind=kind)
-            with jax.named_scope("raft.plan.postprocess"):
-                return _postprocess(d, index.metric), i
-
-        operands = (index.centers, index.lists_data, index.lists_norms,
-                    index.lists_indices, jnp.float32(index.scale))
-        key_bits = (use_list, use_pallas, use_fused, gather, lc,
-                    params.scan_bins,
-                    jnp.dtype(params.internal_distance_dtype).name,
-                    index.lists_data.dtype.name)
-        return fn, operands, None, key_bits
-
-    return make, n_probes, kind, use_pallas
-
-
-def _pq_builder(index, k: int, params):
-    from raft_tpu.neighbors import _ivf_scan, ivf_pq
-    from raft_tpu.neighbors.ann_types import list_order_auto
-    from raft_tpu.neighbors.ivf_bq import (finish_search,
-                                           resolve_raw_device)
-    from raft_tpu.neighbors.ivf_flat import _metric_kind, _postprocess
-    from raft_tpu.ops.dispatch import pallas_enabled
-
-    n_probes = min(params.n_probes, index.n_lists)
-    kind = _metric_kind(index.metric)
-    sqrt = index.metric in (DistanceType.L2SqrtExpanded,
-                            DistanceType.L2SqrtUnexpanded)
-    per_cluster = index.codebook_kind == ivf_pq.CodebookGen.PER_CLUSTER
-    use_pallas = pallas_enabled()
-    scan_mode = params.scan_mode
-    if scan_mode == "auto":
-        scan_mode = "codes" if use_pallas else "reconstruct"
-    rescoring = params.rescore_factor > 0 and index.raw is not None
-    kk = max(params.rescore_factor, 1) * k
-    dev_sqrt = sqrt if (kk == k and not rescoring) else False
-    bins = params.scan_bins
-    if bins == 0 and kk > k:
-        max_list = index.codes.shape[1]
-        bins = min(max(128, (32 * kk) // max(n_probes, 1)), max_list)
-    raw_dev = (resolve_raw_device(index, params.rescore_on_device)
-               if rescoring else None)
-
-    def _device_epilogue(d, i, q, raw):
-        """In-jit tail: exact device rescore (when the raw corpus is
-        device-resident) or the estimator slice, then the family output
-        conventions — mirrors ivf_bq.finish_search's device branch.
-        The sqrt applies only when the device phase didn't already
-        (``dev_sqrt``: the kk == k no-rescore case sqrt's in-scan)."""
-        from raft_tpu.neighbors.ivf_bq import _exact_rescore_device
-        with jax.named_scope("raft.plan.rescore"):
-            if raw is not None:
-                ex, i_out = _exact_rescore_device(raw, q, i, k=k,
-                                                  kind=kind)
-                i_out = jnp.where(jnp.isfinite(ex), i_out, -1)
-                d = jnp.where(jnp.isfinite(ex), ex, jnp.inf)
-            else:
-                d, i_out = d[:, :k], i[:, :k]
-        with jax.named_scope("raft.plan.postprocess"):
-            if sqrt and not dev_sqrt:
-                d = jnp.sqrt(jnp.maximum(d, 0.0))
-            return _postprocess(d, index.metric), i_out
-
-    def make(nq: int, cap: int):
-        host_epilogue = None
-        if scan_mode == "codes":
-            from raft_tpu.ops.pallas_ivf_scan import (fused_mode,
-                                                      pq_decoded_share)
-            code_norms = ivf_pq._ensure_code_norms(index, params,
-                                                   per_cluster, kind)
-            gather = _ivf_scan.gather_mode()
-            use_fused = fused_mode() and kk <= 256
-            if use_fused:
-                obs.counter("raft.ivf_scan.fused.total",
-                            family="ivf_pq").inc()
-            # the code scan decodes each list only up to its last row
-            obs.gauge("raft.ivf_scan.pq.decoded_share").set(
-                pq_decoded_share(index.lists_indices, kk, cap, bins,
-                                 index.pq_dim, index.pq_centers.shape[1],
-                                 index.rot_dim, params.lut_dtype))
-
-            def device_phase(q, centers, centers_rot, rot, books, codes,
-                             norms, ids):
-                return ivf_pq._fused_code_search(
-                    q, centers, centers_rot, rot, books, codes, norms,
-                    ids, k=kk, n_probes=n_probes, cap=cap, bins=bins,
-                    sqrt=dev_sqrt, kind=kind,
-                    lut_dtype=params.lut_dtype,
-                    internal_dtype=params.internal_distance_dtype,
-                    per_cluster=per_cluster, gather=gather,
-                    fused=use_fused)
-
-            operands = [index.centers, index.centers_rot,
-                        index.rotation_matrix, index.pq_centers,
-                        index.codes, code_norms, index.lists_indices]
-            key_bits = ("codes", gather, use_fused,
-                        jnp.dtype(params.lut_dtype).name,
-                        jnp.dtype(params.internal_distance_dtype).name,
-                        bins, kk, rescoring, raw_dev is not None)
-        else:
-            expects(scan_mode == "reconstruct",
-                    "plan: ivf_pq scan_mode %r has no serving plan "
-                    "(use 'auto', 'codes' or 'reconstruct')", scan_mode)
-            ivf_pq._ensure_decoded(index, per_cluster)
-            use_list = (kind == "l2"
-                        and (params.scan_order == "list"
-                             or (params.scan_order == "auto"
-                                 and list_order_auto(nq, n_probes,
-                                                     index.n_lists))))
-
-            def device_phase(q, centers, centers_rot, rot, decoded,
-                             decoded_norms, ids):
-                if use_list:
-                    return _ivf_scan.fused_reconstruct_list_search(
-                        q, centers, centers_rot, rot, decoded,
-                        decoded_norms, ids, k=kk, n_probes=n_probes,
-                        cap=cap, bins=bins, sqrt=dev_sqrt)
-                return ivf_pq._search_impl_reconstruct(
-                    q, centers, centers_rot, rot, decoded,
-                    decoded_norms, ids, kk, n_probes, dev_sqrt,
-                    kind=kind)
-
-            operands = [index.centers, index.centers_rot,
-                        index.rotation_matrix, index.decoded,
-                        index.decoded_norms, index.lists_indices]
-            key_bits = ("reconstruct", use_list, bins, kk, rescoring,
-                        raw_dev is not None)
-
-        if rescoring and raw_dev is None:
-            # raw corpus exceeds the device budget: the exact re-rank
-            # runs host-side per batch — correct, but NOT sync-free
-            def host_epilogue(d, i, q):
-                return finish_search(d, i, index.raw, q, k,
-                                     metric=index.metric, rescore=True,
-                                     raw_dev=None)
-
-            fn_tail = None
-        else:
-            fn_tail = raw_dev
-
-        def fn(q, *ops):
-            if fn_tail is not None:
-                *core, raw = ops
-            else:
-                core, raw = ops, None
-            d, i = device_phase(q, *core)
-            if host_epilogue is not None:
-                return d, i   # estimator phase only; host tail follows
-            return _device_epilogue(d, i, q, raw)
-
-        if fn_tail is not None:
-            operands.append(fn_tail)
-        return fn, tuple(operands), host_epilogue, key_bits
-
-    return make, n_probes, kind, (use_pallas and scan_mode == "codes")
-
-
-def _bq_builder(index, k: int, params):
-    from raft_tpu.neighbors import _ivf_scan, ivf_bq
-    from raft_tpu.neighbors._ivf_scan import (_chunk_size,
-                                              largest_divisor_at_most)
+    from raft_tpu.neighbors.ivf_bq import resolve_raw_device
     from raft_tpu.neighbors.ivf_flat import _metric_kind
     from raft_tpu.ops.dispatch import pallas_enabled
     from raft_tpu.ops.pallas_ivf_scan import lc_mode
 
+    family, _ = _resolve_builder(index)
+    order = getattr(params, "scan_order", "auto")
+    expects(order in ("auto", "probe", "list"),
+            "%s.search: unknown scan_order %r", family, order)
+    factor = getattr(params, "rescore_factor", 0)
+    expects(factor >= 0, "%s.search: rescore_factor must be >= 0, got %d",
+            family, factor)
+    on_device = getattr(params, "rescore_on_device", "auto")
+    expects(on_device in ("auto", "always", "never"),
+            "%s.search: rescore_on_device: want auto|always|never, got %r",
+            family, on_device)
+    # a negative value would bypass the auto-bins rule and fail deep in
+    # the scan with an opaque reshape error
+    expects(family != "ivf_bq" or params.scan_bins >= 0,
+            "ivf_bq.search: scan_bins must be >= 0 (0 = auto), got %d",
+            params.scan_bins)
     n_probes = min(params.n_probes, index.n_lists)
     kind = _metric_kind(index.metric)
-    use_pallas = pallas_enabled()
-    rescoring = params.rescore_factor > 0 and index.raw is not None
-    kk = max(params.rescore_factor, 1) * k
-    max_list = index.bits.shape[1]
-    raw_dev = (ivf_bq.resolve_raw_device(index, params.rescore_on_device)
-               if rescoring else None)
+    pallas = pallas_enabled()
+    mode, lut_dtype, per_cluster = "", None, False
+    if family == "ivf_pq":
+        mode = params.scan_mode
+        expects(mode in ("auto", "codes", "reconstruct", "lut"),
+                "ivf_pq.search: unknown scan_mode %r", mode)
+        if mode == "auto":
+            mode = "codes" if pallas else "reconstruct"
+        lut_dtype = jnp.dtype(params.lut_dtype).name
+        expects(lut_dtype in ("float32", "bfloat16", "float8_e4m3fn"),
+                "ivf_pq: lut_dtype must be float32|bfloat16|float8_e4m3fn")
+        # the fp8 tier only exists on the code-resident scan: reject
+        # rather than silently measure the full-precision paths
+        expects(lut_dtype != "float8_e4m3fn" or mode == "codes",
+                "ivf_pq: lut_dtype=float8_e4m3fn requires scan_mode="
+                "'codes' (resolved scan_mode is %r)", mode)
+        pallas = mode == "codes"
+        per_cluster = index.codebook_kind == ivf_pq.CodebookGen.PER_CLUSTER
 
-    def make(nq: int, cap: int):
-        from raft_tpu.ops.pallas_ivf_scan import fused_mode
-        bins = min(params.scan_bins
-                   or max(128, (32 * kk) // max(n_probes, 1)), max_list)
-        chunk = min(
-            _chunk_size(index.n_lists, cap, max_list),
-            largest_divisor_at_most(
-                index.n_lists,
-                max(1, (64 << 20) // max(1, max_list * index.dim * 2))))
-        gather = _ivf_scan.gather_mode()
-        lc = lc_mode()
-        use_fused = use_pallas and fused_mode() and kk <= 256
-        if use_fused:
-            obs.counter("raft.ivf_scan.fused.total",
-                        family="ivf_bq").inc()
+    if family == "ivf_bq" or mode == "codes":
+        order = "list"
+    elif mode == "lut":
+        order = "probe"
+    else:
+        # the XLA list scans have the l2 core only
+        listable = kind == "l2" or pallas
+        order = ("list" if listable and (order == "list" or (
+            order == "auto" and list_order_auto(nq, n_probes,
+                                                index.n_lists)))
+                 else "probe")
+        pallas = pallas and order == "list"
 
-        def device_phase(q, centers, centers_rot, rot, bits, norms2,
-                         scales, ids):
-            if use_pallas:
-                return ivf_bq._fused_bq_search_pallas(
-                    q, centers, centers_rot, rot, bits, norms2, scales,
-                    ids, kk=kk, bins=bins, n_probes=n_probes, cap=cap,
-                    gather=gather, kind=kind, lc=lc, fused=use_fused)
-            return ivf_bq._fused_bq_search(
-                q, centers, centers_rot, rot, bits, norms2, scales,
-                ids, kk=kk, bins=bins, n_probes=n_probes, cap=cap,
-                chunk=chunk, dim=index.dim, kind=kind)
-
-        operands = [index.centers, index.centers_rot,
-                    index.rotation_matrix, index.bits, index.norms2,
-                    index.scales, index.lists_indices]
-        host_epilogue = None
-        if rescoring and raw_dev is None:
-            def host_epilogue(d, i, q):
-                return ivf_bq.finish_search(d, i, index.raw, q, k,
-                                            metric=index.metric,
-                                            rescore=True, raw_dev=None)
-
-        def fn(q, *ops):
-            if index.metric == DistanceType.CosineExpanded:
-                q = q / jnp.maximum(
-                    jnp.linalg.norm(q, axis=1, keepdims=True), 1e-30)
-            if raw_dev is not None:
-                *core, raw = ops
-            else:
-                core, raw = ops, None
-            d, i = device_phase(q, *core)
-            if host_epilogue is not None:
-                return d, i
-            return _bq_device_tail(d, i, q, raw, index.metric, k, kind,
-                                   rescoring)
-
-        if raw_dev is not None:
-            operands.append(raw_dev)
-        key_bits = (use_pallas, use_fused, gather, lc, bins, chunk, kk,
-                    rescoring, raw_dev is not None)
-        return fn, tuple(operands), host_epilogue, key_bits
-
-    return make, n_probes, kind, use_pallas
+    rescoring = factor > 0 and getattr(index, "raw", None) is not None
+    kk = max(factor, 1) * k
+    max_list = index.lists_indices.shape[1]
+    bins = params.scan_bins if order == "list" else 0
+    if bins == 0 and order == "list" and family != "ivf_flat" \
+            and (kk > k or family == "ivf_bq"):
+        # a 32x-oversampled GLOBAL candidate pool (n_probes·bins ≈
+        # 32·kk, floor 128/list) instead of the per-list 4·k rule:
+        # scaling bins with kk would blow the merge width (64 probes ×
+        # 4·256 bins = 32k-wide select) and the candidate blocks (~0.5
+        # GB at the 500k bench point). Safe because bins are STRIDED
+        # in every tier — measured 0.920 vs the contiguous
+        # formulation's 0.868 recall@10 at 30k×64/128-list
+        bins = min(max(128, (32 * kk) // max(n_probes, 1)), max_list)
+    elif family == "ivf_bq":
+        bins = min(bins, max_list)
+    idt = getattr(params, "internal_distance_dtype", None)
+    return Route(
+        family=family, metric=index.metric, kind=kind, n_probes=n_probes,
+        k=k, kk=kk, order=order, pallas=pallas,
+        # the resident state keeps kk on sublanes: past the select_k
+        # bound the unfused kernels serve
+        fused=pallas and kk <= 256, scan_mode=mode, bins=bins,
+        lc=lc_mode() if pallas and family != "ivf_pq" else 0,
+        gather=gather_mode() if pallas else "rows",
+        dev_sqrt=(index.metric in _SQRT_METRICS and kk == k
+                  and not rescoring and family != "ivf_bq"),
+        rescoring=rescoring,
+        raw_on_device=rescoring and resolve_raw_device(
+            index, on_device) is not None,
+        per_cluster=per_cluster, lut_dtype=lut_dtype,
+        internal_dtype=None if idt is None else jnp.dtype(idt).name)
 
 
-def _bq_device_tail(d, i, q, raw, metric, k: int, kind: str,
-                    rescoring: bool):
-    """In-jit estimator slice / device rescore + output conventions
-    (finish_search's jittable branches, shared by the bq and pq plans
-    when no host epilogue is needed)."""
+# host read of the PQ list extents behind the decoded-share gauge,
+# memoized for the last (lists, route) so a run of same-route eager
+# searches stays free of host syncs
+_LAST_SHARE: list = [None, None, 0.0]
+
+
+def resolve_cap(r: Route, index, q, params) -> Route:
+    """``r`` with its inverted-table cap (list order: the one
+    measurement sync of a cold shape, then ``index.cap_cache``) and,
+    for the IVF-BQ XLA scan, its decode chunk; notes the route's
+    counters. Both the eager search and the plan build land here."""
+    from raft_tpu.neighbors import _ivf_scan
+    from raft_tpu.ops.pallas_ivf_scan import pq_decoded_share, ragged_tail
+    if r.order == "list":
+        cap = _ivf_scan.resolve_cap(index.cap_cache, q, index.centers,
+                                    params, r.n_probes, index.n_lists,
+                                    kind=r.kind, use_pallas=r.pallas)
+        chunk = 0
+        if r.family == "ivf_bq" and not r.pallas:
+            # both the (chunk, cap, max_list) estimator block and the
+            # (chunk, max_list, dim) decode tile stay modest
+            max_list = index.lists_indices.shape[1]
+            chunk = min(
+                _ivf_scan._chunk_size(index.n_lists, cap, max_list),
+                _ivf_scan.largest_divisor_at_most(
+                    index.n_lists,
+                    max(1, (64 << 20) // max(1, max_list * index.dim * 2))))
+        r = dataclasses.replace(r, cap=cap, chunk=chunk)
+    _ivf_scan.count_coarse_fallback(r.n_probes, r.pallas)
+    if r.fused:
+        obs.counter("raft.ivf_scan.fused.total", family=r.family).inc()
+    # the Pallas list scan reads the lists unpadded and completes a
+    # partial last bins window in VMEM
+    if r.family == "ivf_flat" and r.pallas and ragged_tail(
+            index.lists_data.shape[1], r.bins, r.k):
+        obs.counter("raft.ivf_scan.ragged_tail.total",
+                    family="ivf_flat").inc()
+    if r.scan_mode == "codes":
+        # the code scan decodes each list only up to its last row
+        lists, last, share = _LAST_SHARE
+        if lists is None or lists() is not index.lists_indices \
+                or last != r:
+            share = pq_decoded_share(
+                index.lists_indices, r.kk, r.cap, r.bins, index.pq_dim,
+                index.pq_centers.shape[1], index.rot_dim, r.lut_dtype)
+            _LAST_SHARE[:] = [weakref.ref(index.lists_indices), r, share]
+        obs.gauge("raft.ivf_scan.pq.decoded_share").set(share)
+    return r
+
+
+def _operands(r: Route, index) -> tuple:
+    """The index arrays :func:`_program` takes after the queries,
+    materializing the route's lazy caches (code norms matched to the
+    LUT tier, the reconstruction cache, the device raw corpus)."""
+    from raft_tpu.neighbors import ivf_pq
+    if r.family == "ivf_flat":
+        ops = (index.centers, index.lists_data, index.lists_norms,
+               index.lists_indices, jnp.float32(index.scale))
+    elif r.family == "ivf_bq":
+        ops = (index.centers, index.centers_rot, index.rotation_matrix,
+               index.bits, index.norms2, index.scales, index.lists_indices)
+    elif r.scan_mode == "reconstruct":
+        ivf_pq._ensure_decoded(index, r.per_cluster)
+        ops = (index.centers, index.centers_rot, index.rotation_matrix,
+               index.decoded, index.decoded_norms, index.lists_indices)
+    else:
+        norms = ((ivf_pq._ensure_code_norms(index, r.lut_dtype, r.kind),)
+                 if r.scan_mode == "codes" else ())
+        ops = (index.centers, index.centers_rot, index.rotation_matrix,
+               index.pq_centers, index.codes, *norms, index.lists_indices)
+    return ops + ((index.raw_dev,) if r.raw_on_device else ())
+
+
+def _flat_scan(r: Route, q, centers, data, norms, ids, scale):
+    from raft_tpu.neighbors import _ivf_scan, ivf_flat
+    if r.order == "probe":
+        return ivf_flat._search_impl(q, centers, data, ids, norms, scale,
+                                     r.k, r.n_probes, r.dev_sqrt,
+                                     kind=r.kind)
+    return _ivf_scan.fused_list_search(
+        q, centers, data, norms, ids, scale, k=r.k, n_probes=r.n_probes,
+        cap=r.cap, bins=r.bins, sqrt=r.dev_sqrt, kind=r.kind,
+        use_pallas=r.pallas, gather=r.gather,
+        internal_dtype=r.internal_dtype, lc=r.lc, fused=r.fused)
+
+
+def _pq_scan(r: Route, q, centers, centers_rot, rot, *ops):
+    from raft_tpu.neighbors import _ivf_scan, ivf_pq
+    if r.scan_mode == "codes":
+        return ivf_pq._fused_code_search(
+            q, centers, centers_rot, rot, *ops, k=r.kk,
+            n_probes=r.n_probes, cap=r.cap, bins=r.bins, sqrt=r.dev_sqrt,
+            kind=r.kind, lut_dtype=r.lut_dtype,
+            internal_dtype=r.internal_dtype, per_cluster=r.per_cluster,
+            gather=r.gather, fused=r.fused)
+    if r.scan_mode == "lut":
+        return ivf_pq._search_impl(q, centers, centers_rot, rot, *ops,
+                                   r.kk, r.n_probes, r.dev_sqrt,
+                                   kind=r.kind, per_cluster=r.per_cluster)
+    if r.order == "list":
+        # lists hold decoded rotated residuals: the scan offsets each
+        # list's queries by its rotated center
+        return _ivf_scan.fused_reconstruct_list_search(
+            q, centers, centers_rot, rot, *ops, k=r.kk,
+            n_probes=r.n_probes, cap=r.cap, bins=r.bins, sqrt=r.dev_sqrt)
+    return ivf_pq._search_impl_reconstruct(
+        q, centers, centers_rot, rot, *ops, r.kk, r.n_probes, r.dev_sqrt,
+        kind=r.kind)
+
+
+def _bq_scan(r: Route, q, centers, centers_rot, rot, bits, norms2,
+             scales, ids):
+    from raft_tpu.neighbors import ivf_bq
+    if r.pallas:
+        return ivf_bq._fused_bq_search_pallas(
+            q, centers, centers_rot, rot, bits, norms2, scales, ids,
+            kk=r.kk, bins=r.bins, n_probes=r.n_probes, cap=r.cap,
+            gather=r.gather, kind=r.kind, lc=r.lc, fused=r.fused)
+    return ivf_bq._fused_bq_search(
+        q, centers, centers_rot, rot, bits, norms2, scales, ids, kk=r.kk,
+        bins=r.bins, n_probes=r.n_probes, cap=r.cap, chunk=r.chunk,
+        dim=q.shape[1], kind=r.kind)
+
+
+_SCANS = {"ivf_flat": _flat_scan, "ivf_pq": _pq_scan, "ivf_bq": _bq_scan}
+
+
+def _program(q, *ops, r: Route):
+    """The device program of route ``r``: (queries, *index arrays) →
+    final (dists, ids) — or the kk estimator candidates when the exact
+    rescore runs on the host (``r.host_tail``)."""
     from raft_tpu.neighbors.ivf_bq import _exact_rescore_device
     from raft_tpu.neighbors.ivf_flat import _postprocess
-    sqrt = metric in (DistanceType.L2SqrtExpanded,
-                      DistanceType.L2SqrtUnexpanded)
-    if rescoring and raw is not None:
-        ex, i_out = _exact_rescore_device(raw, q, i, k=k, kind=kind)
-        i_out = jnp.where(jnp.isfinite(ex), i_out, -1)
-        d = jnp.where(jnp.isfinite(ex), ex, jnp.inf)
-    else:
-        d, i_out = d[:, :k], i[:, :k]
-    if sqrt:
-        d = jnp.sqrt(jnp.maximum(d, 0.0))
-    return _postprocess(d, metric), i_out
+    if r.metric == DistanceType.CosineExpanded:
+        q = q / jnp.maximum(jnp.linalg.norm(q, axis=1, keepdims=True),
+                            1e-30)
+    if r.raw_on_device:
+        *ops, raw = ops
+    d, i = _SCANS[r.family](r, q, *ops)
+    if r.host_tail:
+        return d, i
+    if r.raw_on_device or r.kk > r.k:
+        with jax.named_scope("raft.plan.rescore"):
+            if r.raw_on_device:
+                ex, i = _exact_rescore_device(raw, q, i, k=r.k,
+                                              kind=r.kind)
+                i = jnp.where(jnp.isfinite(ex), i, -1)
+                d = jnp.where(jnp.isfinite(ex), ex, jnp.inf)
+            else:
+                d, i = d[:, :r.k], i[:, :r.k]
+    with jax.named_scope("raft.plan.postprocess"):
+        if r.metric in _SQRT_METRICS and not r.dev_sqrt:
+            d = jnp.sqrt(jnp.maximum(d, 0.0))
+        return _postprocess(d, r.metric), i
 
 
-_BUILDERS = {}
+_PROGRAM = jax.jit(_program, static_argnames=("r",))
+# the plan's copy: it consumes the query buffer (a no-op, with a noisy
+# warning, where the backend does not donate — see _donate_ok)
+_DONATING = jax.jit(_program, static_argnames=("r",), donate_argnums=(0,))
+
+
+def _host_epilogue(r: Route, index) -> Optional[Callable]:
+    """The exact rescore of a ``host_tail`` route against the host raw
+    corpus (``ivf_bq.finish_search``), else None."""
+    if not r.host_tail:
+        return None
+
+    def rescore(d, i, q):
+        from raft_tpu.neighbors.ivf_bq import finish_search
+        if r.metric == DistanceType.CosineExpanded:
+            q = q / jnp.maximum(
+                jnp.linalg.norm(q, axis=1, keepdims=True), 1e-30)
+        return finish_search(d, i, index.raw, q, r.k, metric=r.metric,
+                             rescore=True, raw_dev=None)
+
+    return rescore
+
+
+def run(index, q, r: Route, params) -> Tuple[jax.Array, jax.Array]:
+    """The eager search along ``r``: resolve the cap, run the program
+    through the jit cache, then the host rescore if the route has
+    one."""
+    r = resolve_cap(r, index, q, params)
+    if r.fused:
+        obs.counter("raft.ivf_scan.fused.queries").inc(q.shape[0])
+    d, i = _PROGRAM(q, *_operands(r, index), r=r)
+    epilogue = _host_epilogue(r, index)
+    return (d, i) if epilogue is None else epilogue(d, i, q)
 
 
 def _resolve_builder(index):
+    """(family name, device program) of an IVF index."""
     from raft_tpu.neighbors import ivf_bq, ivf_flat, ivf_pq
-    if not _BUILDERS:
-        _BUILDERS.update({ivf_flat.Index: ("ivf_flat", _flat_builder),
-                          ivf_pq.Index: ("ivf_pq", _pq_builder),
-                          ivf_bq.Index: ("ivf_bq", _bq_builder)})
-    for cls, (name, builder) in _BUILDERS.items():
+    for cls, name in ((ivf_flat.Index, "ivf_flat"), (ivf_pq.Index, "ivf_pq"),
+                      (ivf_bq.Index, "ivf_bq")):
         if isinstance(index, cls):
-            return name, builder
+            return name, _program
     expects(False, "plan: unsupported index type %s (want ivf_flat/"
             "ivf_pq/ivf_bq Index)", type(index).__name__)
 
@@ -557,14 +574,13 @@ def build_plan(index, queries, k: int, params=None,
     serving plan for this (index, nq, k, params) point.
 
     ``queries`` — a REPRESENTATIVE batch (real shape AND distribution:
-    the inverted-table cap is measured from it, exactly like the cold
-    path's first call). One host sync happens here, never on the
+    the inverted-table cap is measured from it, exactly like the eager
+    search's first call). One host sync happens here, never on the
     serving path. With ``warm`` the compiled program is also executed
     once on the sample batch so device-side warmup (e.g. kernel
     autotuning) is off the serving path too.
     """
-    from raft_tpu.neighbors import _ivf_scan
-    family, builder = _resolve_builder(index)
+    family, _ = _resolve_builder(index)
     if params is None:
         params = _default_params(family)
     q = as_array(queries).astype(jnp.float32)
@@ -572,21 +588,16 @@ def build_plan(index, queries, k: int, params=None,
             "plan: queries must be (nq, dim=%d), got %s", index.dim,
             q.shape)
     nq = q.shape[0]
-    make, n_probes, kind, use_pallas_coarse = builder(index, k, params)
-    _ivf_scan.count_coarse_fallback(n_probes, use_pallas_coarse)
+    r = route(index, nq, k, params)
     with spans.span("raft.plan.build", family=family, nq=nq,
                     k=k) as bsp, \
             obs.timed("raft.plan.build", family=family):
         # the ONE measurement round-trip of the plan lifecycle: also
-        # prefills index.cap_cache so the cold path (ivf_flat.search et
-        # al.) is sync-free at this shape from now on
-        cap = _ivf_scan.resolve_cap(index.cap_cache, q, index.centers,
-                                    params, n_probes, index.n_lists,
-                                    kind=kind,
-                                    use_pallas=use_pallas_coarse)
-        bsp.set_attrs(cap=cap, n_probes=n_probes)
-        fn, operands, host_epilogue, key_bits = make(nq, cap)
-        key = (family, nq, index.dim, k, n_probes, cap, kind) + key_bits
+        # prefills index.cap_cache so the eager search (ivf_flat.search
+        # et al.) is sync-free at this shape from now on
+        r = resolve_cap(r, index, q, params)
+        bsp.set_attrs(cap=r.cap, n_probes=r.n_probes)
+        key = (nq, index.dim) + dataclasses.astuple(r)
         cached = index.plan_cache.pop(key, None)
         if cached is not None:
             # re-insert at the MRU end: the plain insertion-ordered dict
@@ -599,18 +610,20 @@ def build_plan(index, queries, k: int, params=None,
         obs.counter("raft.plan.build.total").inc()
         bsp.set_attr("plan_cache", "miss")
         donate = _donate_ok()
-        jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
+        operands = _operands(r, index)
         q_struct = jax.ShapeDtypeStruct((nq, index.dim), jnp.float32)
         t_c0 = time.perf_counter()
-        executable = jitted.lower(q_struct, *operands).compile()
+        executable = (_DONATING if donate else _PROGRAM).lower(
+            q_struct, *operands, r=r).compile()
         # compile-time ledger (resource profiler): the seconds the
         # chip sat idle while the host built this program
         profiler.note_compile("plan", time.perf_counter() - t_c0)
         plan = SearchPlan(family=family, key=key, nq=nq, dim=index.dim,
-                          k=k, n_probes=n_probes, cap=cap,
+                          k=k, n_probes=r.n_probes, cap=r.cap,
                           metric=index.metric, _executable=executable,
                           _operands=operands,
-                          _host_epilogue=host_epilogue, _donate=donate)
+                          _host_epilogue=_host_epilogue(r, index),
+                          _donate=donate)
         index.plan_cache[key] = plan
         cache_max = _plan_cache_max()
         if cache_max > 0:

@@ -30,12 +30,13 @@ Each list's rows are read from HBM exactly once per query batch; the
 reference's fused kernel has on GPU. Candidates are gathered back
 per (query, probe) and merged with the exact Pallas ``select_k``.
 
-The FUSED tier (``fused=True`` / ``RAFT_TPU_IVF_FUSED``, ISSUE 7) goes
-one step further: the per-query top-k state stays resident in VMEM
-across the list grid (the ``_select_kernel`` output-block-revisiting
-trick, filtered-merge early-skip included), so the candidate tensor
-never reaches HBM and the whole fine phase — scan, scatter, select —
-is ONE ``pallas_call`` where the unfused path needs three dispatches
+The FUSED kernels (``fused=True``, which the search route takes
+whenever k ≤ 256) go one step further: the per-query top-k state
+stays resident in VMEM across the list grid (the ``_select_kernel``
+output-block-revisiting trick, filtered-merge early-skip included), so
+the candidate tensor never reaches HBM and the whole fine phase —
+scan, scatter, select — is ONE ``pallas_call`` where the unfused path
+needs three dispatches
 (scan kernel → XLA gather → select_k kernel). This is the in-kernel
 ``block_sort`` of the reference's ``interleaved_scan_kernel``
 (``ivf_flat_search.cuh:665``) rebuilt for the list-major TPU geometry.
@@ -211,10 +212,9 @@ def _pick_lc(n_lists: int, max_list: int, cap: int, dim: int,
     max_list.
 
     ``override`` > 0 pins the value (snapped down to a divisor of
-    n_lists) — resolved from ``RAFT_TPU_IVF_LC`` by ``lc_mode()`` at
-    the public search entries and threaded here statically. ``1`` =
-    grid-per-list: the PQ kernel's structure, loop-free kernel body,
-    the compile-budget ladder's middle tier."""
+    n_lists) — resolved from ``RAFT_TPU_IVF_LC`` by ``lc_mode()`` in
+    the search route and threaded here statically. ``1`` =
+    grid-per-list: the PQ kernel's structure, loop-free kernel body."""
     if override > 0:
         lc = min(override, n_lists)
         while n_lists % lc:
@@ -248,17 +248,6 @@ def _pick_lc(n_lists: int, max_list: int, cap: int, dim: int,
 # finite stand-in for +inf through the scatter matmul (inf · 0 = NaN
 # would poison the one-hot accumulation); far above any real distance
 _BIG_F32 = 3.0e38
-
-
-def fused_mode() -> bool:
-    """Resolve the ``RAFT_TPU_IVF_FUSED`` routing flag OUTSIDE jit (the
-    ``lc_mode()``/``gather_mode()`` contract): callers thread it through
-    the fused searches as a static argument so the jit cache keys on it.
-    Default ON — the unfused Pallas / XLA tiers stay in the
-    compile-budget ladder as fallbacks."""
-    import os
-    return os.environ.get("RAFT_TPU_IVF_FUSED", "1").lower() \
-        not in ("0", "never", "off")
 
 
 def _merge_state(od_ref, oi_ref, cd, ci, qm, *, k: int, cap_axis: int):
@@ -703,7 +692,7 @@ def ivf_list_scan_pallas(queries, lists_data, lists_norms, lists_indices,
     lists per grid cell, 0 = auto (callers resolve ``lc_mode()``
     outside jit). ``fused``: keep the top-k state resident in the scan
     kernel (ONE pallas_call — no candidate tensor, no gather, no
-    select_k dispatch; callers resolve ``fused_mode()`` outside jit).
+    select_k dispatch; the search route takes it for k ≤ 256).
     Returns (dists (nq, k), ids (nq, k)) sorted best-first.
     """
     nq, dim = queries.shape

@@ -64,7 +64,7 @@ PACK_ID_SENTINEL = (1 << 24) - 1
 def merge_mode(default: str = "int8") -> str:
     """Resolve the cross-shard merge wire format from
     ``RAFT_TPU_DIST_MERGE`` (``f32`` | ``int8``), host-side and OUTSIDE
-    jit (the ``fused_mode`` pattern). ``default`` differs by caller:
+    jit (the ``lc_mode`` pattern). ``default`` differs by caller:
     the serving tier (``serve/dist.py``) compresses by default; the
     library functions (``distributed_ivf_*_search``) default to the
     exact f32 merge so their bit-exactness contracts (dryrun

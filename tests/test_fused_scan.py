@@ -17,6 +17,7 @@ one ``pallas_call``; plan/ladder routing with zero steady-state
 compiles; the coarse-selection fallback counter.
 """
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -32,6 +33,14 @@ from raft_tpu.random import make_blobs
 def _cdiff(before, after, name):
     return (after["counters"].get(name, 0.0)
             - before["counters"].get(name, 0.0))
+
+
+def _unfused(index, q, k, sp):
+    """The eager search along its own route with the scan called
+    ``fused=False`` — the unfused Pallas reference."""
+    r = plan.route(index, q.shape[0], k, sp)
+    assert r.fused and r.pallas
+    return plan.run(index, q, dataclasses.replace(r, fused=False), sp)
 
 
 def _recall(got, want, k):
@@ -102,7 +111,6 @@ class TestFusedFlat:
         sp = ivf_flat.SearchParams(n_probes=16, scan_order="list",
                                    scan_bins=ml)
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         d_f, i_f = ivf_flat.search(flat_index, q, k, sp)
         monkeypatch.setenv("RAFT_TPU_PALLAS", "never")  # → xla_inverted
         d_x, i_x = ivf_flat.search(flat_index, q, k, sp)
@@ -123,10 +131,8 @@ class TestFusedFlat:
         sp = ivf_flat.SearchParams(n_probes=8, scan_order="list",
                                    scan_bins=ml)
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         d_f, i_f = ivf_flat.search(idx, q, k, sp)
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "0")
-        d_u, i_u = ivf_flat.search(idx, q, k, sp)
+        d_u, i_u = _unfused(idx, q, k, sp)
         np.testing.assert_array_equal(np.asarray(i_f), np.asarray(i_u))
         np.testing.assert_allclose(np.asarray(d_f), np.asarray(d_u),
                                    rtol=1e-5, atol=1e-5)
@@ -143,7 +149,6 @@ class TestFusedFlat:
             metric=DistanceType.InnerProduct))
         k, ml = 8, int(idx.lists_indices.shape[1])
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         d_f, i_f = ivf_flat.search(idx, q, k, ivf_flat.SearchParams(
             n_probes=8, scan_order="list", scan_bins=ml))
         d_p, i_p = ivf_flat.search(idx, q, k, ivf_flat.SearchParams(
@@ -163,10 +168,8 @@ class TestFusedFlat:
         sp = ivf_flat.SearchParams(n_probes=16, scan_order="list",
                                    scan_bins=ml, probe_cap=8)
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         d_f, i_f = ivf_flat.search(flat_index, q, k, sp)
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "0")
-        d_u, i_u = ivf_flat.search(flat_index, q, k, sp)
+        d_u, i_u = _unfused(flat_index, q, k, sp)
         np.testing.assert_array_equal(np.asarray(i_f), np.asarray(i_u))
         np.testing.assert_allclose(np.asarray(d_f), np.asarray(d_u),
                                    rtol=1e-5, atol=1e-5)
@@ -181,10 +184,8 @@ class TestFusedFlat:
         k = 8
         sp = ivf_flat.SearchParams(n_probes=8, scan_order="list")
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         _, i_f = ivf_flat.search(flat_index, q, k, sp)
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "0")
-        _, i_u = ivf_flat.search(flat_index, q, k, sp)
+        _, i_u = _unfused(flat_index, q, k, sp)
         xn, qn = np.asarray(x), np.asarray(q)
         d2 = ((xn ** 2).sum(1)[None, :] + (qn ** 2).sum(1)[:, None]
               - 2 * qn @ xn.T)
@@ -315,7 +316,7 @@ class TestUnpaddedLists:
 
 
 class TestFlatPlanReadsStoredLists:
-    """Structure of the flat serving program (``plan._flat_builder``):
+    """Structure of the flat serving program (``plan._program``):
     no ``pad`` copies the lists per batch, the scan kernel takes the
     stored (n_lists, max_list, dim) array, and a plan build that takes
     the in-VMEM tail says so once on ``raft.ivf_scan.ragged_tail``."""
@@ -349,16 +350,17 @@ class TestFlatPlanReadsStoredLists:
         _, q = flat_data
         k = 8
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         n_lists, max_list, dim = flat_index.lists_data.shape
         # 432 rows: auto bins (64 at k 8) leave a 48-row tail; exact
         # bins and 48 divide it
         assert (max_list % 64 != 0) and (max_list % 48 == 0)
         sp = ivf_flat.SearchParams(n_probes=8, scan_order="list",
                                    scan_bins=scan_bins)
-        make, _, _, _ = plan._flat_builder(flat_index, k, sp)
-        fn, operands, _, _ = make(q.shape[0], 16)
-        closed = jax.make_jaxpr(fn)(q, *operands)
+        r = dataclasses.replace(plan.route(flat_index, q.shape[0], k, sp),
+                                cap=16)
+        closed = jax.make_jaxpr(
+            lambda q, *ops: plan._program(q, *ops, r=r))(
+                q, *plan._operands(r, flat_index))
         eqns = list(self._eqns(closed.jaxpr))
         pads = [e.invars[0].aval.shape for e in eqns
                 if e.primitive.name == "pad"]
@@ -404,10 +406,8 @@ class TestFusedBq:
         ml = int(idx.lists_indices.shape[1])
         sp = ivf_bq.SearchParams(n_probes=16, scan_bins=ml)
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         d_f, i_f = ivf_bq.search(idx, q, 8, sp)
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "0")
-        d_u, i_u = ivf_bq.search(idx, q, 8, sp)
+        d_u, i_u = _unfused(idx, q, 8, sp)
         np.testing.assert_array_equal(np.asarray(i_f), np.asarray(i_u))
         np.testing.assert_allclose(np.asarray(d_f), np.asarray(d_u),
                                    rtol=1e-5, atol=1e-5)
@@ -459,7 +459,6 @@ class TestFusedPq:
         idx, x, q = pq_setup
         k = 8
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         sp = ivf_pq.SearchParams(n_probes=8, scan_mode="codes")
         d0, i0 = ivf_pq.search(idx, q, k, sp)
         monkeypatch.setattr(pis, "_VMEM_LIMIT", 1 << 18)  # force split
@@ -476,10 +475,8 @@ class TestFusedPq:
         k = 8
         sp = ivf_pq.SearchParams(n_probes=8, scan_mode="codes")
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         _, i_f = ivf_pq.search(idx, q, k, sp)
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "0")
-        _, i_u = ivf_pq.search(idx, q, k, sp)
+        _, i_u = _unfused(idx, q, k, sp)
         xn, qn = np.asarray(x), np.asarray(q)
         d2 = ((xn ** 2).sum(1)[None, :] + (qn ** 2).sum(1)[:, None]
               - 2 * qn @ xn.T)
@@ -608,7 +605,6 @@ class TestPqDecodedShare:
         idx = ivf_pq.build(jnp.asarray(np.asarray(x)), ivf_pq.IndexParams(
             n_lists=16, kmeans_n_iters=4, pq_dim=8))
         q = jnp.asarray(np.asarray(x)[:16])
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         sp = ivf_pq.SearchParams(n_probes=4, scan_mode="codes")
         p = plan.build_plan(idx, q, 8, sp, warm=False)
         got = obs.snapshot()["gauges"]["raft.ivf_scan.pq.decoded_share"]
@@ -635,7 +631,6 @@ class TestPlanRoutesFused:
             pytest.skip("metrics disabled (RAFT_TPU_METRICS=0)")
         _, q = flat_data
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         sp = ivf_flat.SearchParams(n_probes=8, scan_order="list")
         before = obs.snapshot()
         p = plan.warmup(flat_index, q, 8, sp)
@@ -662,7 +657,6 @@ class TestPlanRoutesFused:
         from raft_tpu.serve.ladder import PlanLadder
         _, q = flat_data
         monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
         sp = ivf_flat.SearchParams(n_probes=8, scan_order="list")
         ladder = PlanLadder.build(flat_index, q, 8, sp, shapes=(16, 80))
         before = obs.snapshot()
@@ -689,13 +683,243 @@ class TestCoarseFallbackCounter:
                       "raft.ivf_scan.coarse.fallback") == 1
 
 
-class TestFusedModeKnob:
-    def test_env_spellings(self, monkeypatch):
-        from raft_tpu.ops.pallas_ivf_scan import fused_mode
-        monkeypatch.delenv("RAFT_TPU_IVF_FUSED", raising=False)
-        assert fused_mode()                       # default ON
-        for off in ("0", "never", "off"):
-            monkeypatch.setenv("RAFT_TPU_IVF_FUSED", off)
-            assert not fused_mode()
-        monkeypatch.setenv("RAFT_TPU_IVF_FUSED", "1")
-        assert fused_mode()
+class TestLcVariants:
+    """The list-scan formulations agree: Pallas at auto/pinned lists per
+    grid cell and the XLA inverted scan (kernel tiers run under the
+    Pallas interpreter on the test mesh)."""
+
+    def _index(self):
+        rng = np.random.default_rng(11)
+        x = rng.standard_normal((3000, 32), np.float32)
+        return ivf_flat.build(
+            x, ivf_flat.IndexParams(n_lists=16, kmeans_n_iters=4)), x
+
+    def test_lc_variants_and_xla_agree(self, monkeypatch):
+        idx, x = self._index()
+        q = jnp.asarray(x[:64])
+        cap = _ivf_scan.resolve_cap(idx.cap_cache, q, idx.centers,
+                                    ivf_flat.SearchParams(), 8,
+                                    idx.n_lists, use_pallas=True)
+
+        def run(use_pallas, lc):
+            return _ivf_scan.fused_list_search(
+                q, idx.centers, idx.lists_data, idx.lists_norms,
+                idx.lists_indices, jnp.float32(1.0), k=10, n_probes=8,
+                cap=cap, bins=-1, sqrt=False, kind="l2",
+                use_pallas=use_pallas, lc=lc)
+
+        monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
+        d_auto, i_auto = run(True, 0)
+        d_lc1, i_lc1 = run(True, 1)
+        d_lc4, i_lc4 = run(True, 4)
+        d_xla, i_xla = run(False, 0)
+        # exact bins (-1): all four formulations are exact → identical
+        np.testing.assert_array_equal(np.asarray(i_auto),
+                                      np.asarray(i_lc1))
+        np.testing.assert_array_equal(np.asarray(i_auto),
+                                      np.asarray(i_lc4))
+        np.testing.assert_array_equal(np.asarray(i_auto),
+                                      np.asarray(i_xla))
+        np.testing.assert_allclose(np.asarray(d_auto),
+                                   np.asarray(d_lc1), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(d_auto),
+                                   np.asarray(d_xla), rtol=1e-4,
+                                   atol=1e-4)
+
+    def test_lc_env_threads_through_search(self, monkeypatch):
+        """RAFT_TPU_IVF_LC is resolved per call: results stay correct
+        whichever value the env pins."""
+        from raft_tpu.ops.pallas_ivf_scan import lc_mode
+
+        idx, x = self._index()
+        q = x[:32]
+        monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
+        sp = ivf_flat.SearchParams(n_probes=8, scan_order="list")
+        monkeypatch.setenv("RAFT_TPU_IVF_LC", "2")
+        assert lc_mode() == 2
+        d2, i2 = ivf_flat.search(idx, q, 10, sp)
+        monkeypatch.setenv("RAFT_TPU_IVF_LC", "1")
+        assert lc_mode() == 1  # env flip takes effect (static arg)
+        d1, i1 = ivf_flat.search(idx, q, 10, sp)
+        np.testing.assert_array_equal(np.asarray(i2), np.asarray(i1))
+
+
+def _shaped_index(family, metric):
+    """An index of bare shapes (64 lists of 1000 rows, dim 16): enough
+    for ``plan.route``, which reads shapes only — any device work on it
+    would fail."""
+    from raft_tpu.distance.distance_types import DistanceType
+    S = jax.ShapeDtypeStruct
+    m = (DistanceType.InnerProduct if metric == "ip"
+         else DistanceType.L2Expanded)
+    n_lists, ml, dim = 64, 1000, 16
+    ids = S((n_lists, ml), jnp.int32)
+    sizes = S((n_lists,), jnp.int32)
+    center = S((n_lists, dim), jnp.float32)
+    if family == "ivf_flat":
+        return ivf_flat.Index(
+            centers=center, lists_data=S((n_lists, ml, dim), jnp.float32),
+            lists_indices=ids, lists_norms=S((n_lists, ml), jnp.float32),
+            list_sizes=sizes, metric=m, size=50000)
+    rot = S((dim, dim), jnp.float32)
+    if family == "ivf_pq":
+        return ivf_pq.Index(
+            centers=center, centers_rot=center, rotation_matrix=rot,
+            pq_centers=S((4, 256, 4), jnp.float32),
+            codes=S((n_lists, ml, 4), jnp.uint8), lists_indices=ids,
+            list_sizes=sizes, metric=m, pq_bits=8, size=50000)
+    return ivf_bq.Index(
+        centers=center, centers_rot=center, rotation_matrix=rot,
+        bits=S((n_lists, ml, 1), jnp.uint32),
+        norms2=S((n_lists, ml), jnp.float32),
+        scales=S((n_lists, ml), jnp.float32), lists_indices=ids,
+        list_sizes=sizes, metric=m, size=50000)
+
+
+class TestRouteTable:
+    """The one kernel choice, per family × Pallas on/off × kk ≤/> 256 ×
+    l2/ip × small/large batch, read off ``plan.route`` over an index of
+    bare shapes (nothing is traced or compiled)."""
+
+    @pytest.mark.parametrize("nq", [8, 512])
+    @pytest.mark.parametrize("metric", ["l2", "ip"])
+    @pytest.mark.parametrize("big_kk", [False, True])
+    @pytest.mark.parametrize("pallas", [True, False])
+    @pytest.mark.parametrize("family", ["ivf_flat", "ivf_pq", "ivf_bq"])
+    def test_route(self, family, pallas, big_kk, metric, nq, monkeypatch):
+        monkeypatch.setenv("RAFT_TPU_PALLAS",
+                           "always" if pallas else "never")
+        index = _shaped_index(family, metric)
+        if family == "ivf_flat":
+            k, kk = (300, 300) if big_kk else (32, 32)
+            params = ivf_flat.SearchParams(n_probes=16)
+        else:
+            k, kk = 32, (512 if big_kk else 256)
+            mod = ivf_pq if family == "ivf_pq" else ivf_bq
+            params = mod.SearchParams(n_probes=16,
+                                      rescore_factor=kk // k)
+        r = plan.route(index, nq, k, params)
+        # list-major: BQ always, the PQ code scan always; otherwise a
+        # batch that reuses lists (512 × 16 probes over 64 lists), on a
+        # scan that has the metric's core (XLA: l2 only)
+        listed = (family == "ivf_bq" or (family == "ivf_pq" and pallas)
+                  or (nq == 512 and (metric == "l2"
+                                     or (pallas and family == "ivf_flat"))))
+        assert r.order == ("list" if listed else "probe")
+        assert r.pallas == (pallas and listed)
+        assert r.fused == (r.pallas and not big_kk)
+        assert r.scan_mode == {"ivf_pq": "codes" if pallas
+                               else "reconstruct"}.get(family, "")
+        assert (r.k, r.kk, r.n_probes, r.kind) == (k, kk, 16, metric)
+        # PQ/BQ spread a 32·kk candidate pool over the probes (floor
+        # 128 a list); the flat scan resolves its own 4·k bins
+        pool = min(max(128, 32 * kk // 16), 1000)
+        assert r.bins == (pool if listed and family != "ivf_flat" else 0)
+        assert r.gather == "rows" and r.cap == 0
+        assert not r.rescoring and not r.host_tail
+
+
+class TestPlanRejects:
+    """A plan refuses exactly what the eager IVF-PQ search refuses."""
+
+    @pytest.fixture(scope="class")
+    def pq_index(self):
+        x, _ = make_blobs(n_samples=2000, n_features=16, centers=10,
+                          seed=5)
+        return ivf_pq.build(jnp.asarray(np.asarray(x)), ivf_pq.IndexParams(
+            n_lists=8, kmeans_n_iters=2, pq_dim=4)), np.asarray(x)[:16]
+
+    @pytest.mark.parametrize("bad", [
+        dict(lut_dtype=jnp.float8_e4m3fn, scan_mode="reconstruct"),
+        dict(scan_mode="nope"),
+        dict(rescore_factor=2, rescore_on_device="bogus")])
+    def test_build_plan_rejects(self, pq_index, bad):
+        from raft_tpu.core.error import LogicError
+        idx, q = pq_index
+        sp = ivf_pq.SearchParams(n_probes=4, **bad)
+        with pytest.raises(LogicError):
+            ivf_pq.search(idx, q, 4, sp)
+        with pytest.raises(LogicError):
+            plan.build_plan(idx, q, 4, sp, warm=False)
+
+
+class TestEagerRouteCounters:
+    """The eager searches note their route like a plan build does."""
+
+    def test_flat_ragged_tail(self, flat_index, flat_data, monkeypatch):
+        if not obs.enabled():
+            pytest.skip("metrics disabled (RAFT_TPU_METRICS=0)")
+        _, q = flat_data
+        monkeypatch.setenv("RAFT_TPU_PALLAS", "always")
+        # 432-row lists at auto bins (64 at k 8): a 48-row tail
+        sp = ivf_flat.SearchParams(n_probes=8, scan_order="list")
+        name = "raft.ivf_scan.ragged_tail.total{family=ivf_flat}"
+        before = obs.snapshot()
+        ivf_flat.search(flat_index, q, 8, sp)
+        assert _cdiff(before, obs.snapshot(), name) == 1
+
+    def test_pq_decoded_share(self, monkeypatch):
+        if not obs.enabled():
+            pytest.skip("metrics disabled (RAFT_TPU_METRICS=0)")
+        from raft_tpu.ops import pallas_ivf_scan as pis
+        x, _ = make_blobs(n_samples=3000, n_features=32, centers=20,
+                          cluster_std=3.0, seed=4)
+        idx = ivf_pq.build(jnp.asarray(np.asarray(x)), ivf_pq.IndexParams(
+            n_lists=16, kmeans_n_iters=4, pq_dim=8))
+        q = jnp.asarray(np.asarray(x)[:16])
+        sp = ivf_pq.SearchParams(n_probes=4, scan_mode="codes")
+        gauge = "raft.ivf_scan.pq.decoded_share"
+        obs.gauge(gauge).set(-1.0)
+        ivf_pq.search(idx, q, 8, sp)
+        r = plan.resolve_cap(plan.route(idx, 16, 8, sp), idx, q, sp)
+        want = pis.pq_decoded_share(idx.lists_indices, 8, r.cap, r.bins,
+                                    8, idx.pq_centers.shape[1],
+                                    idx.rot_dim, sp.lut_dtype)
+        assert 0.0 < want <= 1.0
+        assert obs.snapshot()["gauges"][gauge] == want
+
+
+class TestEagerSharesThePlanRoute:
+    @pytest.mark.parametrize("metric", ["l2", "ip"])
+    def test_lut_plan_matches_eager(self, metric):
+        """``scan_mode="lut"`` has a plan, and it answers what the eager
+        LUT-gather search answers."""
+        from raft_tpu.distance.distance_types import DistanceType
+        x, _ = make_blobs(n_samples=3000, n_features=16, centers=20,
+                          seed=6)
+        x = np.asarray(x)
+        m = (DistanceType.InnerProduct if metric == "ip"
+             else DistanceType.L2Expanded)
+        idx = ivf_pq.build(jnp.asarray(x), ivf_pq.IndexParams(
+            n_lists=16, kmeans_n_iters=3, pq_dim=4, metric=m))
+        q = x[:24]
+        sp = ivf_pq.SearchParams(n_probes=4, scan_mode="lut")
+        d0, i0 = ivf_pq.search(idx, q, 6, sp)
+        p = plan.build_plan(idx, q, 6, sp)
+        d1, i1 = p.search(q, block=True)
+        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+        np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+
+    @pytest.mark.parametrize("family", ["ivf_flat", "ivf_pq", "ivf_bq"])
+    def test_eager_adds_no_plan_build(self, family, flat_data):
+        """An eager search at a shape a plan was built for measures
+        nothing, builds nothing, and answers what the plan answers."""
+        if not obs.enabled():
+            pytest.skip("metrics disabled (RAFT_TPU_METRICS=0)")
+        x, q = flat_data
+        mod = {"ivf_flat": ivf_flat, "ivf_pq": ivf_pq,
+               "ivf_bq": ivf_bq}[family]
+        kw = dict(pq_dim=8) if family == "ivf_pq" else {}
+        idx = mod.build(x, mod.IndexParams(n_lists=16, kmeans_n_iters=3,
+                                           **kw))
+        sp = mod.SearchParams(n_probes=4)
+        p = plan.build_plan(idx, q, 8, sp)
+        before = obs.snapshot()
+        d0, i0 = mod.search(idx, q, 8, sp)
+        after = obs.snapshot()
+        for name in ("raft.plan.build.total", "raft.plan.cache.misses",
+                     "raft.ivf_scan.resolve_cap.syncs"):
+            assert _cdiff(before, after, name) == 0, name
+        d1, i1 = p.search(q, block=True)
+        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+        np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))

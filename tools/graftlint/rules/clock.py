@@ -2,10 +2,8 @@
 
 ``time.time()`` steps under NTP slew/adjustment and DST/admin changes;
 any *duration* or *expiry* computed from it can jump backwards or
-forwards.  The concrete instance this rule was written for:
-``ops/compile_budget.py`` stamped tier poisoning with ``time.time()``,
-so an NTP step could silently stretch or shrink a poison window on the
-serving path.  The tree's convention is:
+forwards — an NTP step can silently stretch or shrink an expiry
+window on the serving path.  The tree's convention is:
 
 * ``time.perf_counter()`` — durations measured within one thread
   (latency histograms, span timing);
